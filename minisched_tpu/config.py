@@ -177,11 +177,6 @@ class SchedulerConfig:
     # (MINISCHED_LOOP_DEPTH). The overload tuner steps the effective
     # depth down (halved per tune step) under the ``tuned`` rung.
     loop_depth: int = 8
-    # Persistent XLA compilation cache directory
-    # (MINISCHED_COMPILE_CACHE; ops/pipeline.enable_compile_cache):
-    # compiled step/loop executables survive process restarts — the
-    # first slice of the ROADMAP cold-start item. "" = off.
-    compile_cache: str = ""
     # Maintained arbitration index (MINISCHED_INDEX; ops/index.py +
     # engine/scheduler._ArbIndex): per-pod-class score rows live on
     # device ACROSS batches in a (C,N) matrix and the sparse delta
@@ -277,7 +272,6 @@ def config_from_env() -> SchedulerConfig:
             _req("MINISCHED_SHORTLIST_CHECK_EVERY", "0")),
         device_loop=_req("MINISCHED_DEVICE_LOOP", "0") == "1",
         loop_depth=int(_req("MINISCHED_LOOP_DEPTH", "8")),
-        compile_cache=os.environ.get("MINISCHED_COMPILE_CACHE", ""),
         index=_req("MINISCHED_INDEX", "0") == "1",
         index_k=int(_req("MINISCHED_INDEX_K", "128")),
         index_classes=int(_req("MINISCHED_INDEX_CLASSES", "64")),

@@ -47,9 +47,10 @@ claimable by ANYONE once the directive is gone. Spec grammar rides
 any lease — a cold replica never owns work), a replica pre-warms the
 bucket ladder: a throwaway engine over a private in-process store pushes
 one small batch through the full dispatch so the jit traces land in the
-persistent compile cache (``MINISCHED_COMPILE_CACHE``), which every
-process shares. The replica's sidecar apiserver keeps its admission gate
-(the PR 10 429 path) closed until warm. ``time_to_first_slo_s`` —
+persistent compile cache (``ops/pipeline.arm_compile_cache``), which
+every process shares through the inherited JAX_COMPILATION_CACHE_DIR or
+the fixed in-checkout path. The replica's sidecar apiserver keeps its
+admission gate (the PR 10 429 path) closed until warm. ``time_to_first_slo_s`` —
 SIGKILL to the adopter's first post-takeover bind — is the bench metric
 this buys (tools/bench_fleet_proc.py pins warm ≤ cold/2).
 

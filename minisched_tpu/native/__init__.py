@@ -8,8 +8,9 @@ behind the store's copy-on-read/ingestion isolation
 10k-pod submission on the create→bound critical path.
 
 Build model: no pybind11, no pip — plain CPython C API compiled with the
-system ``g++``/``cc`` into a per-Python-version cache next to this file
-on first import (one ``-O2 -shared -fPIC`` invocation, ~1 s). Any
+system ``g++``/``cc`` into a cache next to this file, named by the
+Python version and a hash of ``fastclone.c``, on first import (one
+``-O2 -shared -fPIC`` invocation, ~1 s). Any
 failure (no toolchain, sandboxed FS, exotic platform) degrades silently
 to the pure-Python implementation; ``load()`` returns None then and
 callers keep their fallback. MINISCHED_NO_NATIVE=1 disables the native
@@ -17,6 +18,7 @@ path outright (tests use it to pin the fallback).
 """
 from __future__ import annotations
 
+import hashlib
 import logging
 import os
 import subprocess
@@ -36,14 +38,23 @@ def _build_dir() -> str:
                         "_build")
 
 
+def _src_path() -> str:
+    return os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "fastclone.c")
+
+
 def _so_path() -> str:
+    """Named by a hash of the source, not judged by file times: a copied
+    tree can carry a stale ``_build/`` whose mtimes look fresh, and what
+    loads must always be built from the source beside it."""
+    with open(_src_path(), "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
     suffix = sysconfig.get_config_var("EXT_SUFFIX") or ".so"
-    return os.path.join(_build_dir(), f"_fastclone{suffix}")
+    return os.path.join(_build_dir(), f"_fastclone_{digest}{suffix}")
 
 
 def _compile() -> bool:
-    src = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                       "fastclone.c")
+    src = _src_path()
     out = _so_path()
     os.makedirs(_build_dir(), exist_ok=True)
     include = sysconfig.get_paths()["include"]
@@ -92,19 +103,16 @@ def _load_locked():
     _tried = True
     if os.environ.get("MINISCHED_NO_NATIVE"):
         return None
-    so, src = _so_path(), os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), "fastclone.c")
+    so = _so_path()
     # Two attempts: a cached .so that fails to load or smoke-test (e.g.
     # written by a pre-atomic-rename build, or ABI drift) is rebuilt
     # once and retried instead of latching this process to the Python
     # fallback — silently losing the native speedup for its lifetime.
     for attempt in range(2):
         try:
-            # Rebuild when the source is newer: _build/ is a per-machine
-            # cache — a stale binary must not silently outlive a source
-            # fix. Second attempt always rebuilds.
-            stale = (attempt > 0 or not os.path.exists(so)
-                     or os.path.getmtime(so) < os.path.getmtime(src))
+            # The name carries the source hash, so an existing file was
+            # built from this source. Second attempt always rebuilds.
+            stale = attempt > 0 or not os.path.exists(so)
             if stale and not _compile():
                 return None
             import importlib.util

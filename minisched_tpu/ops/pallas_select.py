@@ -30,8 +30,9 @@ with the free-capacity matrix resident in VMEM:
 Measured on one v5e core (P=10240, N=50176, R=9): 87 ms vs 981 ms for the
 lax.scan path — 11.3x, bitwise-identical outputs. CPU tests run it under
 interpret=True for exact equivalence checks against the scan
-(tests/test_pallas_select.py); bench.py asserts the same equality on real
-TPU hardware.
+(tests/test_pallas_select.py); chip_smoke.py (phase 4) and bench.py
+assert the same equality on the chip, and tests/test_tpu_compile.py
+compiles it for a described v5e at this shape.
 
 SHORTLIST GATE: with shortlist-compressed arbitration on (the default,
 MINISCHED_SHORTLIST=1), build_step does NOT auto-select this kernel —
@@ -179,6 +180,17 @@ def greedy_assign_pallas(scores: jnp.ndarray, requests: jnp.ndarray,
     return AssignResult(chosen=chosen[:P, 0],
                         assigned=ok[:P, 0].astype(bool),
                         free_after=free_t_after[:, :N].T)
+
+
+def greedy_assign_kernel(scores: jnp.ndarray, requests: jnp.ndarray,
+                         free0: jnp.ndarray, key: jax.Array) -> AssignResult:
+    """greedy_assign_pallas for the platform the step is lowered for: the
+    Mosaic kernel on TPU, the same kernel interpreted anywhere else (the
+    CPU rehearsal of an explicit ``pallas=True`` step). Chosen at
+    lowering, so a step compiled for a described TPU holds the kernel."""
+    return jax.lax.platform_dependent(
+        scores, requests, free0, key, tpu=greedy_assign_pallas,
+        default=functools.partial(greedy_assign_pallas, interpret=True))
 
 
 def pallas_supported(n_nodes: int, backend: str | None = None) -> bool:

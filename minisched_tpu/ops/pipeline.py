@@ -19,6 +19,8 @@ full matrix, fixing the in-loop quirk at minisched.go:178-183.
 """
 from __future__ import annotations
 
+import os
+import threading
 from typing import List, NamedTuple, Optional
 
 import jax
@@ -154,8 +156,10 @@ def build_step(plugin_set: PluginSet, *, explain: bool = False,
     explain) so scheduler restarts and equivalent profiles reuse compiles.
 
     ``pallas``: use the pallas greedy-assignment kernel (ops/pallas_select).
-    None = auto: on TPU when the node axis is lane-tiled. The sharded
-    builder passes False — a Mosaic kernel can't be GSPMD-partitioned.
+    None = auto: on TPU when the node axis is lane-tiled. True off the TPU
+    runs the same kernel interpreted (pallas_select.greedy_assign_kernel).
+    The sharded builder passes False — a Mosaic kernel can't be GSPMD-
+    partitioned.
 
     ``assignment``: "greedy" (default; priority-faithful sequential
     semantics, scan or pallas) or "auction" (ops/auction.py — parallel
@@ -496,7 +500,7 @@ def build_step(plugin_set: PluginSet, *, explain: bool = False,
                     else:
                         greedy_fn = sl_fn
                 elif use_pallas:
-                    from .pallas_select import greedy_assign_pallas
+                    from .pallas_select import greedy_assign_kernel
 
                     if caps is not None:
                         # The kernel can't carry domain counts; batches
@@ -511,10 +515,10 @@ def build_step(plugin_set: PluginSet, *, explain: bool = False,
                             return jax.lax.cond(
                                 _caps.any_enforced,
                                 lambda a: _ga(*a, caps=_caps),
-                                lambda a: greedy_assign_pallas(*a),
+                                lambda a: greedy_assign_kernel(*a),
                                 (sc, rq, fr, k))
                     else:
-                        greedy_fn = greedy_assign_pallas
+                        greedy_fn = greedy_assign_kernel
                 elif caps is not None:
                     import functools
 
@@ -611,87 +615,11 @@ def build_step(plugin_set: PluginSet, *, explain: bool = False,
 
     if _raw:
         return step
+    # A step that fails to lower raises at its first call: no slower
+    # path may stand in for it unseen.
     jitted = jax.jit(step)
-    if pallas is not None or assign_fn is not None or assignment != "greedy":
-        # An EXPLICIT pallas choice must fail loudly (bench.py's
-        # pallas-vs-scan comparison depends on it to surface kernel
-        # breakage); only the auto-selected pallas path degrades. Auction
-        # mode never auto-selects the kernel, so it has nothing to guard.
-        _STEP_CACHE[cache_key] = jitted
-        return jitted
-
-    # pallas=None may auto-select the pallas kernel at trace time. A
-    # lowering/compile failure on an unexpected toolchain must degrade to
-    # the lax.scan assignment (identical results), not poison every
-    # scheduling cycle — and the fallback lives HERE so every consumer
-    # (engine, bench, graft entry) inherits it, not just one call site.
-    # Cost of the broad catch: a non-pallas first-call error pays one
-    # doomed scan-step retrace before propagating.
-    state = {"fn": jitted, "fell_back": False, "ok_shapes": set()}
-
-    def guarded(eb, nf, af, key):
-        # Success is tracked PER SHAPE BUCKET: each bucket retraces (and
-        # may pick the pallas kernel for the first time, e.g. when node
-        # growth crosses the lane-tile threshold), so an any-success latch
-        # would wrongly disable the fallback exactly where a fresh
-        # lowering can first fail.
-        shape = (eb.pf.valid.shape[0], nf.valid.shape[0])
-        try:
-            out = state["fn"](eb, nf, af, key)
-            state["ok_shapes"].add(shape)
-            return out
-        except Exception as e:
-            if (isinstance(e, ValueError)
-                    and "buffers but compiled program expected" in str(e)):
-                # jax 0.9 cpp-pjit dispatch anomaly (regression-pinned in
-                # tests/test_spreadcap.py): a call whose trace-level
-                # jaxpr is IDENTICAL to an already-compiled signature is
-                # handed an executable with a different kept-argument
-                # count. Clearing the jit cache forces a clean recompile
-                # for every bucket — expensive but rare, and strictly
-                # better than failing the scheduling cycle. Checked
-                # INSIDE the generic handler so every other first-call
-                # exception still reaches the pallas fallback below.
-                import logging
-
-                logging.getLogger(__name__).warning(
-                    "jit dispatch buffer mismatch (%s); clearing the "
-                    "step cache and retrying", e)
-                state["fn"].clear_cache()
-                try:
-                    out = state["fn"](eb, nf, af, key)
-                    state["ok_shapes"].add(shape)
-                    return out
-                except Exception:
-                    # recovery failed — fall THROUGH to the never-run-
-                    # bucket pallas->scan fallback below rather than
-                    # failing the scheduling cycle here
-                    pass
-            # Only a bucket that has NEVER run falls back — that's the
-            # lowering/compile-failure case this guard exists for. Once
-            # this bucket has produced a batch, an exception is a
-            # transient runtime error (preempted chip, HBM pressure):
-            # latching onto the ~11x slower scan for the process
-            # lifetime would be the wrong trade — propagate instead.
-            if state["fell_back"] or shape in state["ok_shapes"]:
-                raise
-            import logging
-
-            logging.getLogger(__name__).exception(
-                "scheduling step failed on first call (pallas lowering?); "
-                "retrying with the lax.scan assignment")
-            # assignment is always "greedy" here (other modes take the
-            # unguarded early return above) — passed through anyway so a
-            # future guard extension can't silently switch strategies.
-            state["fn"] = build_step(plugin_set, explain=explain, cfg=cfg,
-                                     pallas=False, assignment=assignment,
-                                     sample_nodes=sample_nodes,
-                                     shortlist=shortlist)
-            state["fell_back"] = True
-            return state["fn"](eb, nf, af, key)
-
-    _STEP_CACHE[cache_key] = guarded
-    return guarded
+    _STEP_CACHE[cache_key] = jitted
+    return jitted
 
 
 _LOOP_CACHE: dict = {}
@@ -912,60 +840,46 @@ def build_tenant_index_step(k_eff: int):
     return fused
 
 
-_COMPILE_CACHE: dict = {"dir": None}
+#: Where the persistent compilation cache lives when
+#: JAX_COMPILATION_CACHE_DIR is unset: one fixed path inside the checkout
+#: (listed in .gitignore). The path is part of each entry's key, so a
+#: directory that moved between runs would never hit.
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+_ARM_LOCK = threading.Lock()
 
 
-def enable_compile_cache(path: str) -> bool:
-    """Arm jax's persistent compilation cache at ``path`` (the
-    MINISCHED_COMPILE_CACHE knob — first slice of the ROADMAP cold-start
-    item): compiled executables for the engine's step/loop shape buckets
-    survive process restarts, so a restarted scheduler serves its first
-    batches without re-paying XLA compiles. Idempotent and process-wide
-    (one latch — engines share the jit caches anyway); returns True when
-    the cache is armed, False when this toolchain lacks the API (the
-    knob degrades to a no-op, never an engine failure)."""
-    if not path:
-        return False
-    if _COMPILE_CACHE["dir"] == path:
-        return True
-    try:
-        import os
+def arm_compile_cache() -> str:
+    """Arm jax's persistent compilation cache and return its directory.
 
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        # Persist even the sub-second CPU-shape compiles: the cold-start
-        # item's unit of progress is "compiles survive restarts", and
-        # the default 1s/64KB floors would skip every test-shape entry.
-        for knob, val in (("jax_persistent_cache_min_compile_time_secs",
-                           0.0),
-                          ("jax_persistent_cache_min_entry_size_bytes",
-                           0)):
-            try:
-                jax.config.update(knob, val)
-            except Exception:  # knob absent on this jax — keep the dir
-                pass
-        # jax's cache module latches a disabled/uninitialized verdict at
-        # its first consult — which backend probing during import can
-        # trigger BEFORE the dir is configured here. Without the reset
-        # every later compile logs "cache is disabled/not initialized"
-        # and writes nothing (observed on jax 0.4.37 CPU; caught by the
-        # bench_coldstart cross-process proof).
-        try:
-            from jax._src import compilation_cache as _cc
+    The one rule: JAX_COMPILATION_CACHE_DIR when it is set (jax reads it
+    at import; it is set here only if the variable came later), else
+    DEFAULT_COMPILE_CACHE_DIR.
+    Child processes share the cache by inheriting the variable or the
+    fixed path. Raises RuntimeError when the cache cannot be armed — a
+    run never continues silently without it."""
+    from jax._src import compilation_cache as cc
 
-            _cc.reset_cache()
-        except Exception:
-            pass
-        _COMPILE_CACHE["dir"] = path
-        return True
-    except Exception:
-        import logging
-
-        logging.getLogger(__name__).warning(
-            "MINISCHED_COMPILE_CACHE=%s: compilation cache unavailable "
-            "on this toolchain; continuing without it", path,
-            exc_info=True)
-        return False
+    path = os.path.abspath(os.environ.get("JAX_COMPILATION_CACHE_DIR")
+                           or DEFAULT_COMPILE_CACHE_DIR)
+    with _ARM_LOCK:
+        if jax.config.jax_compilation_cache_dir != path:
+            jax.config.update("jax_compilation_cache_dir", path)
+        if cc._cache is not None and str(cc._cache.path) == path:
+            return path  # already armed here
+        if not jax.config.jax_enable_compilation_cache:
+            raise RuntimeError(
+                "persistent compilation cache is disabled "
+                "(jax_enable_compilation_cache=False)")
+        # jax latches "no cache" at its first consult, which an import-
+        # time backend probe can trigger before the directory is known.
+        cc.reset_cache()
+        cc._initialize_cache()
+        if cc._cache is None:
+            raise RuntimeError(
+                f"persistent compilation cache could not be armed at {path}")
+    return path
 
 
 def max_normalize_100(scores: jnp.ndarray, feasible: jnp.ndarray) -> jnp.ndarray:

@@ -448,24 +448,38 @@ def test_timeline_rows_keep_per_batch_cadence_under_loop():
 
 # ---- compile-cache bootstrap (cold-start satellite) ---------------------
 
-def test_compile_cache_bootstrap(tmp_path):
-    """MINISCHED_COMPILE_CACHE=<dir> arms jax's persistent compilation
-    cache at engine init (process-wide latch, idempotent) and the
-    engine schedules normally with it armed; an empty knob stays off."""
+def test_compile_cache_bootstrap(tmp_path, monkeypatch):
+    """The one compile-cache rule: the engine arms jax's persistent
+    cache at init, in JAX_COMPILATION_CACHE_DIR when set, else at the
+    fixed in-checkout path; re-arming is idempotent, and a cache that
+    cannot be armed raises instead of running without it."""
     import jax
 
-    from minisched_tpu.ops.pipeline import enable_compile_cache
+    from minisched_tpu.ops import pipeline
 
-    assert enable_compile_cache("") is False
     cache_dir = str(tmp_path / "xla-cache")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache_dir)
     pods = _plain_pods(16)
-    _placements, m = _run_burst(
-        _config(True, compile_cache=cache_dir), pods)
-    assert m["compile_cache_on"] == 1
-    assert jax.config.jax_compilation_cache_dir == cache_dir
-    assert os.path.isdir(cache_dir)
-    # idempotent re-arm (second engine in the same process)
-    assert enable_compile_cache(cache_dir) is True
+    try:
+        _placements, m = _run_burst(_config(True), pods)
+        assert m["compile_cache_dir"] == cache_dir
+        assert jax.config.jax_compilation_cache_dir == cache_dir
+        assert os.path.isdir(cache_dir)
+        # idempotent re-arm (second engine in the same process)
+        assert pipeline.arm_compile_cache() == cache_dir
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert pipeline.arm_compile_cache() == \
+            pipeline.DEFAULT_COMPILE_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == \
+            pipeline.DEFAULT_COMPILE_CACHE_DIR
+        jax.config.update("jax_enable_compilation_cache", False)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache_dir)
+        with pytest.raises(RuntimeError, match="disabled"):
+            pipeline.arm_compile_cache()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        pipeline.arm_compile_cache()
 
 
 # ---- op-level loop equality ---------------------------------------------

@@ -142,6 +142,32 @@ def test_resident_bit_identical_to_fallback(pipeline):
     assert base_m["residency_resyncs"] == 0
 
 
+def test_slim_readback_reversion_is_counted(monkeypatch):
+    """A slim readback that fails its first-batch cross-check reverts
+    to the i32 fetch, still commits the right placements, and is
+    COUNTED in metrics() so a chip run can fail on it (chip_smoke.py)
+    instead of absorbing it in a log line."""
+    from minisched_tpu.engine import scheduler as sched_mod
+
+    real = sched_mod.unpack_decision_slim
+    calls = []
+
+    def first_call_scribbled(buf, p, f):
+        out = real(buf, p, f)
+        if not calls:
+            out[0][:] = -1  # every chosen row lost: the check must trip
+        calls.append(1)
+        return out
+
+    base, base_m = _run_burst(resident=True)
+    assert base_m["slim_readback_reversions"] == 0
+    monkeypatch.setattr(sched_mod, "unpack_decision_slim",
+                        first_call_scribbled)
+    got, m = _run_burst(resident=True)
+    assert m["slim_readback_reversions"] == 1
+    assert got == base
+
+
 def test_steady_state_uploads_only_deltas():
     """A clean burst (no revocation churn beyond arbitration, no node
     events) performs exactly ONE full dynamic-leaf upload — the

@@ -674,10 +674,13 @@ def test_class_pad_crossing_rebuilds_with_pinned_cause():
     cause == "class-pad", not "cold"/"invalidated"/"node-pad"."""
     from minisched_tpu.obs import journal as journal_mod
 
-    # burst 0: 10 classes (class pad 16); burst 1: +12 disjoint classes
-    # → 22 total crosses to pad 32 partway through the burst, so BOTH
-    # the in-bucket append path and the crossing rebuild fire.
-    bursts = [_pods(10, shapes=10), _pods(12, shapes=12, cpu0=4000)]
+    # burst 0: 11 classes (class pad 16); burst 1: +12 disjoint classes
+    # → its FIRST batch crosses to pad 32, so BOTH the in-bucket append
+    # path and the crossing rebuild fire. (Crossing on a later batch of
+    # the burst is timing-fragile: that batch can race the previous
+    # batch's bind confirmations — a counted index_races fallback that
+    # registers no classes — and then never cross.)
+    bursts = [_pods(11, shapes=11), _pods(12, shapes=12, cpu0=4000)]
     for i, b in enumerate(bursts):
         for p in b:
             p.metadata.name = f"b{i}{p.metadata.name}"

@@ -137,3 +137,19 @@ def test_deep_nesting_raises_instead_of_crashing():
         cur = nxt
     with pytest.raises(RecursionError):
         mod.clone(deep)
+
+
+def test_native_build_is_named_by_source_hash(tmp_path, monkeypatch):
+    """The built file's name carries a hash of fastclone.c, so a copied
+    tree's stale _build/ (fresh-looking mtimes) can never be loaded for
+    a changed source."""
+    from minisched_tpu import native
+
+    src = tmp_path / "fastclone.c"
+    src.write_text("/* a */")
+    monkeypatch.setattr(native, "_src_path", lambda: str(src))
+    first = native._so_path()
+    src.write_text("/* b */")
+    assert native._so_path() != first
+    src.write_text("/* a */")
+    assert native._so_path() == first

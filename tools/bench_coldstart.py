@@ -1,7 +1,7 @@
 """Cross-process compile-cache proof (ROADMAP cold-start item).
 
-PR 11 armed jax's persistent compilation cache behind
-MINISCHED_COMPILE_CACHE and exported per-run warmup compile seconds
+The engine arms jax's persistent compilation cache at init
+(ops/pipeline.arm_compile_cache) and bench.py exports per-run warmup compile seconds
 (``*_warmup_compile_s``), but nothing ever proved the cache works
 ACROSS PROCESSES — the cold-start claim is precisely that a restarted
 scheduler's first batches skip XLA compilation. This harness runs the
@@ -49,7 +49,7 @@ LEDGER_KEYS = ("coldstart_cold_compile_s", "coldstart_warm_compile_s",
 def _child() -> None:
     """One engine burst in THIS process (invoked via --child): warmup
     pass (compiles land here) + measured pass, keys on stdout's last
-    line. MINISCHED_COMPILE_CACHE comes from the parent's env."""
+    line. JAX_COMPILATION_CACHE_DIR comes from the parent's env."""
     import bench
     from bench_workload import BENCH_PLUGINS, make_workload
 
@@ -63,7 +63,9 @@ def _child() -> None:
 def run_child(n: int, p: int, cache_dir: str) -> dict:
     env = dict(os.environ,
                JAX_PLATFORMS="cpu",
-               MINISCHED_COMPILE_CACHE=cache_dir,
+               JAX_COMPILATION_CACHE_DIR=cache_dir,
+               # persist the sub-second CPU compiles of this small shape
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
                MINISCHED_BENCH_NODES=str(n),
                MINISCHED_BENCH_PODS=str(p))
     proc = subprocess.run(
@@ -99,13 +101,14 @@ def capture(n: int, p: int) -> dict:
         "coldstart_cold_total_s": float(cold.get("cold_warmup_s") or 0.0),
         "coldstart_warm_total_s": float(warm.get("cold_warmup_s") or 0.0),
         "cache_entries_after_cold": entries,
-        "compile_cache_armed": bool(cold.get("cold_compile_cache_on")),
+        "compile_cache_armed":
+            cold.get("cold_compile_cache_dir") == cache_dir,
         "warm_over_cold_ratio": (round(warm_s / cold_s, 4)
                                  if cold_s else None),
     }
     bad = []
     if not doc["compile_cache_armed"]:
-        bad.append("MINISCHED_COMPILE_CACHE did not arm in the child")
+        bad.append("the child did not arm JAX_COMPILATION_CACHE_DIR")
     if entries < 1:
         bad.append("cold run left an empty compilation cache")
     if cold_s < 1.0:

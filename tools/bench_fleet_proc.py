@@ -99,15 +99,14 @@ def _fleet(store, api, replicas, *, prewarm, cache_dir, backoff0_s=0.1):
     from minisched_tpu.fleet.procfleet import ProcFleetSupervisor
     from minisched_tpu.service.defaultconfig import Profile
 
-    cfg = dict(ENGINE)
-    if cache_dir:
-        cfg["compile_cache"] = cache_dir
     return ProcFleetSupervisor(
         store, api.address, replicas=replicas,
         lease_ttl_s=FAILOVER_TTL_S, prewarm=prewarm,
         respawn=True, backoff0_s=backoff0_s, backoff_cap_s=3.0,
         stable_s=5.0,
-        config_overrides=cfg, profile=Profile(plugins=PLUGINS))
+        config_overrides=dict(ENGINE), profile=Profile(plugins=PLUGINS),
+        extra_env=({"JAX_COMPILATION_CACHE_DIR": cache_dir}
+                   if cache_dir else None))
 
 
 
