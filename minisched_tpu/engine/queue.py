@@ -171,6 +171,11 @@ class QueuedPodInfo:
     # describe its SUCCESSFUL attempt; create→bound spans everything.
     gathered_at: float = 0.0
     decided_at: float = 0.0
+    # Wall clock (time.time(), the store's creation_timestamp clock) at
+    # the pod's first entry into this queue — the informer's ADDED
+    # delivery. created → enqueued is the informer lag; a requeue keeps
+    # the stamp.
+    enqueued_unix: float = 0.0
     # Which sub-queue holds the pod ("active" | "backoff" | "unsched" |
     # "shed" | "popped") — lets update/delete be O(1) dict lookups
     # instead of the linear scans the round-1 design used (quadratic
@@ -293,7 +298,7 @@ class SchedulingQueue:
             if pod.key in self._known or self._closed:
                 return
             self._known.add(pod.key)
-            qpi = QueuedPodInfo(pod=pod)
+            qpi = QueuedPodInfo(pod=pod, enqueued_unix=time.time())
             if forced or not self._admits(pod):
                 self._push_shed(qpi)
                 shed = True
@@ -318,11 +323,12 @@ class SchedulingQueue:
             if self._closed:
                 return
             added = False
+            now = time.time()
             for pod in pods:
                 if pod.key in self._known:
                     continue
                 self._known.add(pod.key)
-                qpi = QueuedPodInfo(pod=pod)
+                qpi = QueuedPodInfo(pod=pod, enqueued_unix=now)
                 if forced or not self._admits(pod):
                     self._push_shed(qpi)
                     shed_n += 1

@@ -34,7 +34,8 @@ from ..encode.cache import bucket_for, step_bucket
 from ..encode.features import NodeFeatures
 from ..errors import ConflictError, NotFoundError
 from ..faults import FAULTS, FaultWorkerDeath
-from ..obs import Histogram, instant, span
+from ..obs import (Histogram, batch_step, gc_pause_s_total, instant, span,
+                   watch_gc)
 from ..obs import bundle as bundle_mod
 from ..obs import slo as slo_mod
 from ..obs.journal import JOURNAL, ProvenanceStore
@@ -212,10 +213,10 @@ class _InflightBatch:
                  "shapes", "seq", "t0", "t_encode", "t_dispatch",
                  "t_fetch_start", "t_step", "t_resolved", "commit_t0",
                  "commit_t1", "res_carried", "assumed", "detached",
-                 "h2d0", "fetch0", "h2d1", "fetch1", "sl_repairs", "gap",
-                 "step_share", "index_packed_dev", "index_free_after",
-                 "index_served", "scored_rows", "loop_slot",
-                 "index_mode", "tenant_ticket", "nom_reserved")
+                 "sl_repairs", "step_share", "index_packed_dev",
+                 "index_free_after", "index_served", "scored_rows",
+                 "loop_slot", "index_mode", "tenant_ticket",
+                 "nom_reserved")
 
     def __init__(self):
         self.failures: List[tuple] = []  # (qpi, plugins, message, retryable)
@@ -236,18 +237,7 @@ class _InflightBatch:
         self.decision: Optional[Decision] = None
         self.spread_dev = None
         self.sample_k = None
-        # Per-batch transfer/repair attribution (the series the bench
-        # exports): byte-counter snapshots at prepare start / resolve
-        # end — prepare..resolve of one batch is contiguous on the
-        # scheduling thread even in pipelined mode, so the deltas are
-        # exactly this batch's traffic — and the shortlist repair count.
-        self.h2d0 = self.fetch0 = 0.0
-        self.h2d1 = self.fetch1 = 0.0
-        self.sl_repairs = 0
-        # Inter-batch gap glue attributed to THIS batch (component →
-        # seconds; Scheduler._book_gap accumulates between prepares and
-        # _prepare_batch adopts the pending dict here).
-        self.gap: Dict[str, float] = {}
+        self.sl_repairs = 0  # shortlist repairs (folded at commit)
         # This batch's free/used_ports input is the device-resident
         # chain (_DeviceResidency) — its free_after must be carried and
         # its debits replayed into the host mirror at resolve time.
@@ -1126,6 +1116,7 @@ class Scheduler:
         # private instance, so direct construction keeps working.
         self._shared = shared or SharedClusterState(store)
         self._owns_shared = shared is None
+        watch_gc()  # process-wide collection pauses (gc_pause_s_total)
         self.cache = self._shared.cache
         self._shared.register(self)
         self.broadcaster = EventBroadcaster(store)
@@ -1411,9 +1402,6 @@ class Scheduler:
         # the NEXT batch takes.
         self._last_committed_seq = -1
         self._prep_window: tuple = (0.0, 0.0)
-        # Pending inter-batch gap components (scheduling thread only);
-        # adopted into each _InflightBatch at prepare — see _book_gap.
-        self._gap_pending: Dict[str, float] = {}
         # Per-pod lifecycle latency histograms (obs.Histogram), fed from
         # the QueuedPodInfo stamps (queued=added_at, gathered_at,
         # decided_at) and observed exactly where pods_bound increments,
@@ -1421,6 +1409,9 @@ class Scheduler:
         # Always on: the cost is a bisect per bound pod, off the device
         # path — the MINISCHED_TRACE knob gates only the span stream.
         self._hists: Dict[str, Histogram] = {
+            # created → first enqueued (the informer's lag): with the
+            # three below it splits created → bound.
+            "pod_informer_lag_s": Histogram(),
             "pod_queue_wait_s": Histogram(),
             "pod_decide_s": Histogram(),
             "pod_bind_s": Histogram(),
@@ -1448,8 +1439,7 @@ class Scheduler:
             # window; fetch = the dispatch→fetch turnaround (pipeline
             # hand-off before the decision readback blocks); commit =
             # the scheduling thread's blocking wait on the previous
-            # batch's commit flush. The per-batch series twin lives in
-            # batch_series (gap_*_s).
+            # batch's commit flush.
             "gap_gather_s_total": 0.0, "gap_encode_s_total": 0.0,
             "gap_fetch_s_total": 0.0, "gap_commit_s_total": 0.0,
             # Pipelined-cycle overlap accounting (_run_pipelined): host
@@ -1535,7 +1525,7 @@ class Scheduler:
             # index_cooldowns counts fallback-storm parks (the
             # full-rescore rung). scored_rows_total is the engine-wide
             # plugin-evaluation ledger in pod-row × node-row units —
-            # the per-batch twin lives in batch_series.scored_rows.
+            # last_scored_rows is the newest batch's share.
             "index_hits": 0, "index_fallbacks": 0,
             "index_repair_rows": 0, "index_rebuilds": 0,
             "index_uncertified": 0, "index_checks": 0,
@@ -1603,16 +1593,12 @@ class Scheduler:
     def _book_gap(self, component: str, dt: float) -> None:
         """Book inter-batch glue into gap_s_total, tagged with its
         component (gather/encode/fetch/commit — see the metric-dict
-        comment). Scheduling-thread only: the pending dict is adopted by
-        the next _prepare_batch so the per-batch series line up with the
-        batch each wait preceded."""
+        comment)."""
         if dt <= 0.0:
             return
         with self._metrics_lock:
             self._metrics["gap_s_total"] += dt
             self._metrics[f"gap_{component}_s_total"] += dt
-        self._gap_pending[component] = (
-            self._gap_pending.get(component, 0.0) + dt)
 
     def _res_count(self, *, resync: bool, h2d: int) -> None:
         with self._metrics_lock:
@@ -2002,7 +1988,7 @@ class Scheduler:
                         batches=self._index_cooldown)
                 jnote("index.cooldown", profile=self.profile, replica=self.replica,
                       batches=self._index_cooldown, batch=inf.seq)
-        with span("step.dispatch"):
+        with batch_step(inf.seq), span("step.dispatch", seq=inf.seq):
             decision = self._step(inf.eb, inf.nf, inf.af, inf.key)
         self._sup_count("steps_dispatched")
         inf.decision = decision
@@ -3197,7 +3183,7 @@ class Scheduler:
             res.pending_prows = res.pending_ppre = None
 
     def _encode_batch(self, batch: List[QueuedPodInfo], pods: List[Pod],
-                      P_pad: int, *, loop_slot: bool = False):
+                      P_pad: int, *, seq: int, loop_slot: bool = False):
         """The encode block shared by per-batch prepare and ring-slot
         staging (``batch`` already priority-sorted, ``pods`` its pod
         list). One store pass per pod resolves every volume-derived
@@ -3247,7 +3233,7 @@ class Scheduler:
                 return pairs
 
         encode_hard: Dict[int, tuple] = {}
-        with span("encode.pods", pods=len(pods),
+        with span("encode.pods", pods=len(pods), seq=seq,
                   **({"loop_slot": 1} if loop_slot else {})):
             eb = encode_pods(pods, P_pad, cfg=self.cache.cfg,
                              registry=self.cache.registry,
@@ -3276,16 +3262,14 @@ class Scheduler:
         self._prep_step0 = self._step_counter
         self._step_counter += 1
         inf = _InflightBatch()
-        with self._metrics_lock:
-            inf.h2d0 = self._metrics["h2d_bytes_total"]
-            inf.fetch0 = self._metrics["fetch_bytes_total"]
+        self._batch_seq += 1
+        inf.seq = self._batch_seq
         batch = sorted(batch, key=lambda q: -q.pod.spec.priority)
         pods = [q.pod for q in batch]
         t0 = time.perf_counter()
         self._book_gap("encode", t0 - t_in)
-        inf.gap, self._gap_pending = self._gap_pending, {}
         vol_memo, fail_closed, eb = self._encode_batch(
-            batch, pods, P_ring, loop_slot=True)
+            batch, pods, P_ring, seq=inf.seq, loop_slot=True)
         if fail_closed:
             # Loop-safe pods cannot trip slot constraints by
             # construction; a symmetric anti-affinity overflow from
@@ -3306,8 +3290,6 @@ class Scheduler:
         inf.spread_dev = None
         inf.t0, inf.t_encode = t0, time.perf_counter()
         inf.t_dispatch = inf.t_encode
-        self._batch_seq += 1
-        inf.seq = self._batch_seq
         with self._metrics_lock:
             self._prep_window = (t0, inf.t_dispatch)
         return inf
@@ -3366,14 +3348,18 @@ class Scheduler:
 
     def _prepare_batch(self, batch: List[QueuedPodInfo]) -> "_InflightBatch":
         """Flight-recorded wrapper: the ``prepare`` span covers gang
-        pull → encode → snapshot → dispatch on the scheduling thread."""
-        with span("prepare") as sp:
-            inf = self._prepare_batch_impl(batch)
-            sp.set(pods=len(inf.batch), seq=inf.seq)
+        pull → encode → snapshot → dispatch on the scheduling thread.
+        The batch id (``seq``) is assigned here, first, so every span of
+        the batch can carry it."""
+        self._batch_seq += 1
+        seq = self._batch_seq
+        with span("prepare", seq=seq) as sp:
+            inf = self._prepare_batch_impl(batch, seq)
+            sp.set(pods=len(inf.batch))
             return inf
 
-    def _prepare_batch_impl(self,
-                            batch: List[QueuedPodInfo]) -> "_InflightBatch":
+    def _prepare_batch_impl(self, batch: List[QueuedPodInfo],
+                            seq: int) -> "_InflightBatch":
         """PREPARE: gang pull → encode → snapshot → async step dispatch.
         Returns with the device executing the batch (JAX async dispatch;
         nothing here blocks on device results), so the pipelined loop can
@@ -3388,10 +3374,8 @@ class Scheduler:
         # fault-free run (tie-breaks fold in the step counter).
         self._prep_step0 = self._step_counter
         inf = _InflightBatch()
+        inf.seq = seq
         cfg = self.config
-        with self._metrics_lock:
-            inf.h2d0 = self._metrics["h2d_bytes_total"]
-            inf.fetch0 = self._metrics["fetch_bytes_total"]
         # Pull queued gang-mates so no batch boundary splits a gang (the
         # step would reject the partial group for missing quorum). This may
         # push the batch past max_batch_size — a split gang can never meet
@@ -3411,11 +3395,8 @@ class Scheduler:
         t0 = time.perf_counter()
         # Batch-formation glue (gang pull + priority sort + per-batch
         # setup) between the pop and the metered encode window — the
-        # encode slot of the gap decomposition — then adopt every gap
-        # component booked since the previous prepare, so the per-batch
-        # series attribute each wait to the batch it preceded.
+        # encode slot of the gap decomposition.
         self._book_gap("encode", t0 - t_in)
-        inf.gap, self._gap_pending = self._gap_pending, {}
         with self._metrics_lock:
             # prepare STARTED; end published when dispatch returns (None
             # end = still encoding — the commit worker's encode-overlap
@@ -3431,7 +3412,8 @@ class Scheduler:
             # real rows' decisions are unchanged — the invariant the
             # device loop's _stage_slot already leans on.
             p_req = max(p_req, self._tenant_mux.round_pods)
-        vol_memo, fail_closed, eb = self._encode_batch(batch, pods, p_req)
+        vol_memo, fail_closed, eb = self._encode_batch(batch, pods, p_req,
+                                                       seq=seq)
         if self._index is not None:
             # Baseline-drain the index listener BEFORE the snapshot the
             # refresh evaluates against (encode/cache.drain_index_rows
@@ -3640,12 +3622,12 @@ class Scheduler:
             packed_dev = None
             spread_dev = None
         else:
-            with span("step.dispatch"):
+            with batch_step(seq), span("step.dispatch", seq=seq):
                 decision = step_fn(eb, nf, af, key)
             self._sup_count("steps_dispatched")
             # Scored-rows ledger (pod-row × node-row plugin-evaluation
-            # units — batch_series.scored_rows): the full step pays the
-            # whole (P_pad, N) matrix; sampling narrows N to its K.
+            # units — scored_rows_total): the full step pays the whole
+            # (P_pad, N) matrix; sampling narrows N to its K.
             inf.scored_rows += int(eb.pf.valid.shape[0]) * int(
                 sample_k if sample_k is not None else nf.valid.shape[0])
             # Pack every per-pod output into ONE device buffer before
@@ -3682,8 +3664,6 @@ class Scheduler:
         inf.packed_dev, inf.spread_dev = packed_dev, spread_dev
         inf.t0, inf.t_encode = t0, t_encode
         inf.t_dispatch = time.perf_counter()
-        self._batch_seq += 1
-        inf.seq = self._batch_seq
         with self._metrics_lock:
             # published for the commit worker's encode-overlap booking
             self._prep_window = (t0, inf.t_dispatch)
@@ -3716,9 +3696,6 @@ class Scheduler:
             self._track = None
             self._prov_batch = None
         inf.t_resolved = time.perf_counter()
-        with self._metrics_lock:
-            inf.h2d1 = self._metrics["h2d_bytes_total"]
-            inf.fetch1 = self._metrics["fetch_bytes_total"]
         self._watchdog_check(inf)
         self._sup.note_clean()
         if self._loop_cooldown > 0:
@@ -4165,81 +4142,83 @@ class Scheduler:
             # attributable.
             self.recorder.record_batch(pods, names, decision, self.plugin_set)
 
-        revoked, parked_gangs = (
-            arbitrate_rwo(batch, assigned, chosen, vol_memo)
-            if self._rwo_enabled else (set(), set()))
-        for i in revoked:
-            if gang_key(batch[i].pod) in parked_gangs:
-                self._handle_failure(
-                    batch[i], {COSCHEDULING},
-                    "gang members demand the same RWO claim on different "
-                    "nodes", retryable=False)
-            else:
-                self._handle_failure(
-                    batch[i], {BATCH_CAPACITY},
-                    "RWO claim pinned by an earlier pod in this batch",
-                    retryable=True)
-
-        if fail_closed:
-            # BEFORE the spread arbitration: fail-closed revocations (and
-            # their gang cascades) must be in its dead set — their scan-
-            # counted admissions otherwise leave a later placement
-            # committed over max_skew (the assume-miss staleness class,
-            # reachable with no node deletion at all). This order also
-            # guarantees fail-closed pods park TERMINALLY: the old
-            # post-arbitration placement let a spread-revoked fail-closed
-            # pod be requeued retryable first and skipped here.
-            # Gang atomicity: failing one member closed parks its whole
-            # gang — peers binding at sub-quorum is the partial-allocation
-            # deadlock gang scheduling exists to prevent.
-            dead_gangs = {gang_key(q.pod) for q in batch
-                          if q.pod.key in fail_closed
-                          and q.pod.spec.pod_group}
-            for i, qpi in enumerate(batch):
-                if i in revoked:
-                    continue
-                info = fail_closed.get(qpi.pod.key)
-                gk = gang_key(qpi.pod)
-                if info is None and gk not in dead_gangs:
-                    continue
-                if info is not None:
-                    plugins, reason = {info[0]}, info[1]
+        with span("resolve.arbitrate", seq=inf.seq):
+            revoked, parked_gangs = (
+                arbitrate_rwo(batch, assigned, chosen, vol_memo)
+                if self._rwo_enabled else (set(), set()))
+            for i in revoked:
+                if gang_key(batch[i].pod) in parked_gangs:
+                    self._handle_failure(
+                        batch[i], {COSCHEDULING},
+                        "gang members demand the same RWO claim on different "
+                        "nodes", retryable=False)
                 else:
-                    plugins = set()
-                    reason = (f"gang {qpi.pod.spec.pod_group} member "
-                              "failed closed on an unrepresentable hard "
-                              "constraint")
-                if gk in dead_gangs:
-                    plugins.add(COSCHEDULING)
-                self._handle_failure(qpi, plugins, reason, retryable=False)
-                revoked = revoked | {i}
+                    self._handle_failure(
+                        batch[i], {BATCH_CAPACITY},
+                        "RWO claim pinned by an earlier pod in this batch",
+                        retryable=True)
 
-        repair_rows: List[int] = []
-        if self._spread_enabled and sp is not None:
-            s_revoked = self._arbitrate_packed(
-                batch, assigned, eb, decision, sp, dead=revoked)
-            from ..state.objects import CLAIM_UNUSED
-            for i in sorted(s_revoked):
-                qpi = batch[i]
-                st = vol_memo.get(qpi.pod.key)
-                # In-cycle repair candidates: re-placed against refreshed
-                # counts after the survivors are assumed (_repair_spread)
-                # instead of paying a full queue round-trip + backoff per
-                # tranche. Excluded: gang members (repairing one member
-                # alone breaks gang atomicity) and pods holding unused RWO
-                # claims (a repair could move them off the node their
-                # claim was arbitrated against). Fail-closed pods never
-                # appear here — they were parked terminally above and are
-                # in the arbitration's dead set.
-                if (self.config.spread_repair_iters
-                        and not qpi.pod.spec.pod_group
-                        and not (st is not None
-                                 and CLAIM_UNUSED in st[1])):
-                    repair_rows.append(i)
-                else:
-                    self._handle_failure(qpi, {BATCH_CAPACITY},
-                                         _SPREAD_REVOKE_MSG, retryable=True)
-            revoked = revoked | s_revoked
+            if fail_closed:
+                # BEFORE the spread arbitration: fail-closed revocations (and
+                # their gang cascades) must be in its dead set — their scan-
+                # counted admissions otherwise leave a later placement
+                # committed over max_skew (the assume-miss staleness class,
+                # reachable with no node deletion at all). This order also
+                # guarantees fail-closed pods park TERMINALLY: the old
+                # post-arbitration placement let a spread-revoked fail-closed
+                # pod be requeued retryable first and skipped here.
+                # Gang atomicity: failing one member closed parks its whole
+                # gang — peers binding at sub-quorum is the partial-
+                # allocation deadlock gang scheduling exists to prevent.
+                dead_gangs = {gang_key(q.pod) for q in batch
+                              if q.pod.key in fail_closed
+                              and q.pod.spec.pod_group}
+                for i, qpi in enumerate(batch):
+                    if i in revoked:
+                        continue
+                    info = fail_closed.get(qpi.pod.key)
+                    gk = gang_key(qpi.pod)
+                    if info is None and gk not in dead_gangs:
+                        continue
+                    if info is not None:
+                        plugins, reason = {info[0]}, info[1]
+                    else:
+                        plugins = set()
+                        reason = (f"gang {qpi.pod.spec.pod_group} member "
+                                  "failed closed on an unrepresentable hard "
+                                  "constraint")
+                    if gk in dead_gangs:
+                        plugins.add(COSCHEDULING)
+                    self._handle_failure(qpi, plugins, reason, retryable=False)
+                    revoked = revoked | {i}
+
+            repair_rows: List[int] = []
+            if self._spread_enabled and sp is not None:
+                s_revoked = self._arbitrate_packed(
+                    batch, assigned, eb, decision, sp, dead=revoked)
+                from ..state.objects import CLAIM_UNUSED
+                for i in sorted(s_revoked):
+                    qpi = batch[i]
+                    st = vol_memo.get(qpi.pod.key)
+                    # In-cycle repair candidates: re-placed against refreshed
+                    # counts after the survivors are assumed (_repair_spread)
+                    # instead of paying a full queue round-trip + backoff per
+                    # tranche. Excluded: gang members (repairing one member
+                    # alone breaks gang atomicity) and pods holding unused RWO
+                    # claims (a repair could move them off the node their
+                    # claim was arbitrated against). Fail-closed pods never
+                    # appear here — they were parked terminally above and are
+                    # in the arbitration's dead set.
+                    if (self.config.spread_repair_iters
+                            and not qpi.pod.spec.pod_group
+                            and not (st is not None
+                                     and CLAIM_UNUSED in st[1])):
+                        repair_rows.append(i)
+                    else:
+                        self._handle_failure(qpi, {BATCH_CAPACITY},
+                                             _SPREAD_REVOKE_MSG,
+                                             retryable=True)
+                revoked = revoked | s_revoked
 
         to_bind: List[tuple] = []  # permit-free (qpi, node_name) pairs
         # With no permit plugins in the profile (the common case) the
@@ -4260,188 +4239,193 @@ class Scheduler:
         preempt_plugins: Dict[int, Set[str]] = {}
         # Python-int views: per-element numpy scalar indexing inside a
         # 10k-iteration loop costs real milliseconds on the commit path.
-        chosen_l = chosen[:len(batch)].tolist()
-        assigned_l = assigned[:len(batch)].tolist()
-        gang_rejected_l = gang_rejected[:len(batch)].tolist()
-        feasible_l = feasible[:len(batch)].tolist()
-        static_l = feasible_static[:len(batch)].tolist()
-        n_ghost = 0  # assigned rows lost to a mid-cycle node deletion
-        for i, qpi in enumerate(batch):
-            if i in revoked:
-                continue
-            gk = gang_key(qpi.pod) if parked_gangs else None
-            if gk and gk in parked_gangs:
-                # Unassigned members of a parked gang would otherwise fall
-                # through to the retryable BATCH_CAPACITY path and thrash
-                # one extra cycle before being gang-rejected — park the
-                # whole gang in one cycle (assigned members are already in
-                # ``revoked`` via gang atomicity).
-                self._handle_failure(
-                    qpi, {COSCHEDULING},
-                    "gang members demand the same RWO claim on different "
-                    "nodes", retryable=False)
-                continue
-            if assigned_l[i]:
-                node_name = names[chosen_l[i]]
-                if self._prov_batch is not None:
-                    self._prov_stamp(qpi, node_name,
-                                     repaired=bool(sl_repaired[i]))
-                if bulk_assume:
-                    assume_items.append((qpi.pod, node_name))
-                    assume_rows.append(i)
-                    assume_incs.append(int(row_incs[chosen_l[i]]))
-                    to_bind.append((qpi, node_name))
-                else:
-                    pair, ghost, rej = self._start_binding_cycle(
-                        qpi, node_name,
-                        expected_inc=int(row_incs[chosen_l[i]]))
-                    if ghost:
-                        n_ghost += 1
-                        lost_rows.append(i)
-                    elif rej:
-                        lost_rows.append(i)
-                    if pair is not None:
-                        to_bind.append(pair)
-            elif gang_rejected_l[i]:
-                # The pod's gang missed quorum — park the whole member set
-                # under Coscheduling (plus any real filter rejections, for
-                # precise event gating) until a new member or capacity event.
-                plugins = {COSCHEDULING}
-                if feasible_l[i] == 0:
-                    plugins |= {self.filter_names[f]
-                                for f in range(rejects.shape[0])
-                                if rejects[f, i] > 0}
-                self._handle_failure(
-                    qpi, plugins,
-                    f"gang {qpi.pod.spec.pod_group} missed quorum "
-                    f"{qpi.pod.spec.pod_group_min}", retryable=False)
-            elif feasible_l[i] > 0 and static_l[i] > 0:
-                # Nodes were feasible but earlier pods in the batch took the
-                # capacity — retryable, not unschedulable (SURVEY §7
-                # "batch-internal causality").
-                self._handle_failure(
-                    qpi, {BATCH_CAPACITY},
-                    "ran out of capacity within scheduling batch",
-                    retryable=True)
-            else:
-                plugins = {self.filter_names[f] for f in range(rejects.shape[0])
-                           if rejects[f, i] > 0} or {BATCH_CAPACITY}
-                if feasible_l[i] > 0:
-                    # The in-scan caps deferred the static skew check, so
-                    # the filter passed nodes the scan then refused under
-                    # the SAME pre-batch counts (feasible_static == 0):
-                    # the pod is statically over-skew everywhere, not
-                    # batch-contended — a terminal PodTopologySpread
-                    # verdict (which preemption below may cure by
-                    # evicting matching pods), never an endless
-                    # BATCH_CAPACITY retry loop.
-                    plugins = {"PodTopologySpread"}
-                # PostFilter (DefaultPreemption): defer the terminal
-                # verdict — a batched victim-candidate search may free
-                # capacity by evicting lower-priority pods. Gang members
-                # never preempt (group-level victim math is out of scope;
-                # plugins/preemption.py docstring).
-                if (self._preempt_enabled
-                        and not qpi.pod.spec.pod_group):
-                    preempt_rows.append(i)
-                    preempt_plugins[i] = plugins
+        with span("resolve.verdicts", seq=inf.seq):
+            chosen_l = chosen[:len(batch)].tolist()
+            assigned_l = assigned[:len(batch)].tolist()
+            gang_rejected_l = gang_rejected[:len(batch)].tolist()
+            feasible_l = feasible[:len(batch)].tolist()
+            static_l = feasible_static[:len(batch)].tolist()
+            n_ghost = 0  # assigned rows lost to a mid-cycle node deletion
+            for i, qpi in enumerate(batch):
+                if i in revoked:
                     continue
-                self._handle_failure(
-                    qpi, plugins,
-                    f"0/{self.cache.node_count()} nodes are available: "
-                    f"rejected by {sorted(plugins)}",
-                    retryable=False)
-
-        if assume_items:
-            missed = self.cache.account_bind_bulk(
-                assume_items, req_rows=eb.pf.requests[assume_rows],
-                expected_inc=assume_incs)
-            if missed:
-                # The chosen node's cache row vanished between the cycle's
-                # snapshot and this assume (node deleted mid-cycle). Bind
-                # would commit the pod to a ghost node the model can never
-                # account — and if a same-named node later returned, the
-                # pod would silently distort its capacity AND its topology
-                # domain counts (observed as a hard-skew violation under
-                # node churn). Requeue instead; next cycle's snapshot has
-                # live nodes only.
-                n_ghost += len(missed)
-                dead_keys = set()
-                for m in missed:
-                    pod, node_name = assume_items[m]
-                    dead_keys.add(pod.key)
+                gk = gang_key(qpi.pod) if parked_gangs else None
+                if gk and gk in parked_gangs:
+                    # Unassigned members of a parked gang would otherwise fall
+                    # through to the retryable BATCH_CAPACITY path and thrash
+                    # one extra cycle before being gang-rejected — park the
+                    # whole gang in one cycle (assigned members are already in
+                    # ``revoked`` via gang atomicity).
                     self._handle_failure(
-                        batch[assume_rows[m]], {BATCH_CAPACITY},
-                        f"chosen node {node_name} was deleted during the "
-                        "scheduling cycle", retryable=True)
-                lost_rows.extend(assume_rows[m] for m in missed)
-                to_bind = [(q, n) for q, n in to_bind
-                           if q.pod.key not in dead_keys]
-            missed_set = set(missed) if missed else ()
-            for j in range(len(assume_items)):
-                if j not in missed_set:
-                    self._note_assumed(batch[assume_rows[j]])
+                        qpi, {COSCHEDULING},
+                        "gang members demand the same RWO claim on different "
+                        "nodes", retryable=False)
+                    continue
+                if assigned_l[i]:
+                    node_name = names[chosen_l[i]]
+                    if self._prov_batch is not None:
+                        self._prov_stamp(qpi, node_name,
+                                         repaired=bool(sl_repaired[i]))
+                    if bulk_assume:
+                        assume_items.append((qpi.pod, node_name))
+                        assume_rows.append(i)
+                        assume_incs.append(int(row_incs[chosen_l[i]]))
+                        to_bind.append((qpi, node_name))
+                    else:
+                        pair, ghost, rej = self._start_binding_cycle(
+                            qpi, node_name,
+                            expected_inc=int(row_incs[chosen_l[i]]))
+                        if ghost:
+                            n_ghost += 1
+                            lost_rows.append(i)
+                        elif rej:
+                            lost_rows.append(i)
+                        if pair is not None:
+                            to_bind.append(pair)
+                elif gang_rejected_l[i]:
+                    # The pod's gang missed quorum — park the whole member
+                    # set under Coscheduling (plus any real filter
+                    # rejections, for precise event gating) until a new
+                    # member or capacity event.
+                    plugins = {COSCHEDULING}
+                    if feasible_l[i] == 0:
+                        plugins |= {self.filter_names[f]
+                                    for f in range(rejects.shape[0])
+                                    if rejects[f, i] > 0}
+                    self._handle_failure(
+                        qpi, plugins,
+                        f"gang {qpi.pod.spec.pod_group} missed quorum "
+                        f"{qpi.pod.spec.pod_group_min}", retryable=False)
+                elif feasible_l[i] > 0 and static_l[i] > 0:
+                    # Nodes were feasible but earlier pods in the batch took
+                    # the capacity — retryable, not unschedulable (SURVEY §7
+                    # "batch-internal causality").
+                    self._handle_failure(
+                        qpi, {BATCH_CAPACITY},
+                        "ran out of capacity within scheduling batch",
+                        retryable=True)
+                else:
+                    plugins = {self.filter_names[f]
+                               for f in range(rejects.shape[0])
+                               if rejects[f, i] > 0} or {BATCH_CAPACITY}
+                    if feasible_l[i] > 0:
+                        # The in-scan caps deferred the static skew check, so
+                        # the filter passed nodes the scan then refused under
+                        # the SAME pre-batch counts (feasible_static == 0):
+                        # the pod is statically over-skew everywhere, not
+                        # batch-contended — a terminal PodTopologySpread
+                        # verdict (which preemption below may cure by
+                        # evicting matching pods), never an endless
+                        # BATCH_CAPACITY retry loop.
+                        plugins = {"PodTopologySpread"}
+                    # PostFilter (DefaultPreemption): defer the terminal
+                    # verdict — a batched victim-candidate search may free
+                    # capacity by evicting lower-priority pods. Gang members
+                    # never preempt (group-level victim math is out of scope;
+                    # plugins/preemption.py docstring).
+                    if (self._preempt_enabled
+                            and not qpi.pod.spec.pod_group):
+                        preempt_rows.append(i)
+                        preempt_plugins[i] = plugins
+                        continue
+                    self._handle_failure(
+                        qpi, plugins,
+                        f"0/{self.cache.node_count()} nodes are available: "
+                        f"rejected by {sorted(plugins)}",
+                        retryable=False)
+
+        with span("resolve.assume", seq=inf.seq):
+            if assume_items:
+                missed = self.cache.account_bind_bulk(
+                    assume_items, req_rows=eb.pf.requests[assume_rows],
+                    expected_inc=assume_incs)
+                if missed:
+                    # The chosen node's cache row vanished between the cycle's
+                    # snapshot and this assume (node deleted mid-cycle). Bind
+                    # would commit the pod to a ghost node the model can never
+                    # account — and if a same-named node later returned, the
+                    # pod would silently distort its capacity AND its topology
+                    # domain counts (observed as a hard-skew violation under
+                    # node churn). Requeue instead; next cycle's snapshot has
+                    # live nodes only.
+                    n_ghost += len(missed)
+                    dead_keys = set()
+                    for m in missed:
+                        pod, node_name = assume_items[m]
+                        dead_keys.add(pod.key)
+                        self._handle_failure(
+                            batch[assume_rows[m]], {BATCH_CAPACITY},
+                            f"chosen node {node_name} was deleted during the "
+                            "scheduling cycle", retryable=True)
+                    lost_rows.extend(assume_rows[m] for m in missed)
+                    to_bind = [(q, n) for q, n in to_bind
+                               if q.pod.key not in dead_keys]
+                missed_set = set(missed) if missed else ()
+                for j in range(len(assume_items)):
+                    if j not in missed_set:
+                        self._note_assumed(batch[assume_rows[j]])
 
         if lost_rows:
-            # Post-assume staleness: the scan (and the host replay)
-            # COUNTED the lost rows' admissions — assume misses and
-            # synchronous permit rejections alike — so a later
-            # same-batch placement may be legal only because of a
-            # contribution that just vanished. Two consequences:
-            #   * gang atomicity — a lost member's siblings must not
-            #     bind at sub-quorum;
-            #   * hard-spread exactness — re-arbitrate with the lost
-            #     rows dead; a newly violating survivor is revoked
-            #     (into the in-cycle repair pass when eligible).
-            # Revocations go through _revoke_post_assume, which also
-            # aborts an in-flight permit wait (non-bulk path); to_bind
-            # has not been submitted yet, so dropped pairs never bind.
-            from ..state.objects import CLAIM_UNUSED
-            g_set = set(lost_rows)
-            bind_keys = {q.pod.key for q, _ in to_bind}
-            drop_keys: Set[str] = set()
-            lost_gangs = {gang_key(batch[i].pod) for i in g_set
-                          if batch[i].pod.spec.pod_group}
-            if lost_gangs:
-                for j, qpi in enumerate(batch):
-                    if (j in g_set or j in revoked or not assigned_l[j]
-                            or gang_key(qpi.pod) not in lost_gangs):
-                        continue
-                    if self._revoke_post_assume(
-                            qpi, {COSCHEDULING, BATCH_CAPACITY},
-                            f"gang {qpi.pod.spec.pod_group} member lost "
-                            "its placement during the scheduling cycle",
-                            in_bind=qpi.pod.key in bind_keys):
-                        drop_keys.add(qpi.pod.key)
-                        revoked = revoked | {j}
-            if sp is not None:
-                # re_rev includes gang siblings of any member it revokes
-                # (arbitrate_spread's internal gang-atomicity fixpoint)
-                re_rev = self._arbitrate_packed(
-                    batch, assigned, eb, decision, sp,
-                    dead=revoked | g_set)
-                for i in sorted(re_rev):
-                    qpi = batch[i]
-                    st = vol_memo.get(qpi.pod.key)
-                    if (self.config.spread_repair_iters
-                            and not qpi.pod.spec.pod_group
-                            and qpi.pod.key in bind_keys
-                            and not (st is not None
-                                     and CLAIM_UNUSED in st[1])):
-                        # same in-cycle repair offer the first-pass
-                        # revocations get — no queue round-trip
-                        self._unassume(qpi)
-                        drop_keys.add(qpi.pod.key)
-                        repair_rows.append(i)
-                        revoked = revoked | {i}
-                    elif self._revoke_post_assume(
-                            qpi, {BATCH_CAPACITY}, _SPREAD_REVOKE_MSG,
-                            in_bind=qpi.pod.key in bind_keys):
-                        drop_keys.add(qpi.pod.key)
-                        revoked = revoked | {i}
-            if drop_keys:
-                to_bind = [(q, n) for q, n in to_bind
-                           if q.pod.key not in drop_keys]
+            with span("resolve.arbitrate", seq=inf.seq):
+                # Post-assume staleness: the scan (and the host replay)
+                # COUNTED the lost rows' admissions — assume misses and
+                # synchronous permit rejections alike — so a later
+                # same-batch placement may be legal only because of a
+                # contribution that just vanished. Two consequences:
+                #   * gang atomicity — a lost member's siblings must not
+                #     bind at sub-quorum;
+                #   * hard-spread exactness — re-arbitrate with the lost
+                #     rows dead; a newly violating survivor is revoked
+                #     (into the in-cycle repair pass when eligible).
+                # Revocations go through _revoke_post_assume, which also
+                # aborts an in-flight permit wait (non-bulk path); to_bind
+                # has not been submitted yet, so dropped pairs never bind.
+                from ..state.objects import CLAIM_UNUSED
+                g_set = set(lost_rows)
+                bind_keys = {q.pod.key for q, _ in to_bind}
+                drop_keys: Set[str] = set()
+                lost_gangs = {gang_key(batch[i].pod) for i in g_set
+                              if batch[i].pod.spec.pod_group}
+                if lost_gangs:
+                    for j, qpi in enumerate(batch):
+                        if (j in g_set or j in revoked or not assigned_l[j]
+                                or gang_key(qpi.pod) not in lost_gangs):
+                            continue
+                        if self._revoke_post_assume(
+                                qpi, {COSCHEDULING, BATCH_CAPACITY},
+                                f"gang {qpi.pod.spec.pod_group} member lost "
+                                "its placement during the scheduling cycle",
+                                in_bind=qpi.pod.key in bind_keys):
+                            drop_keys.add(qpi.pod.key)
+                            revoked = revoked | {j}
+                if sp is not None:
+                    # re_rev includes gang siblings of any member it revokes
+                    # (arbitrate_spread's internal gang-atomicity fixpoint)
+                    re_rev = self._arbitrate_packed(
+                        batch, assigned, eb, decision, sp,
+                        dead=revoked | g_set)
+                    for i in sorted(re_rev):
+                        qpi = batch[i]
+                        st = vol_memo.get(qpi.pod.key)
+                        if (self.config.spread_repair_iters
+                                and not qpi.pod.spec.pod_group
+                                and qpi.pod.key in bind_keys
+                                and not (st is not None
+                                         and CLAIM_UNUSED in st[1])):
+                            # same in-cycle repair offer the first-pass
+                            # revocations get — no queue round-trip
+                            self._unassume(qpi)
+                            drop_keys.add(qpi.pod.key)
+                            repair_rows.append(i)
+                            revoked = revoked | {i}
+                        elif self._revoke_post_assume(
+                                qpi, {BATCH_CAPACITY}, _SPREAD_REVOKE_MSG,
+                                in_bind=qpi.pod.key in bind_keys):
+                            drop_keys.add(qpi.pod.key)
+                            revoked = revoked | {i}
+                if drop_keys:
+                    to_bind = [(q, n) for q, n in to_bind
+                               if q.pod.key not in drop_keys]
 
         n_repaired = 0
         if repair_rows:
@@ -4490,7 +4474,7 @@ class Scheduler:
             # binding goroutine (minisched.go:96-112).
             for q, _n in to_bind:
                 self._note_detached(q.pod.key)
-            self._binder.submit(self._bind_many, to_bind)
+            self._binder.submit(self._bind_many, to_bind, inf.seq)
 
         inf.t_step = t_step
         inf.n_assigned = (int(assigned[:len(batch)].sum())
@@ -4557,7 +4541,6 @@ class Scheduler:
         else:
             gather_gap = max(0.0, inf.t_fetch_start - inf.t_dispatch)
             step_s = (t_step - inf.t_encode) - gather_gap
-        gap = inf.gap
         with self._metrics_lock:
             m = self._metrics
             m["batches"] += 1
@@ -4582,31 +4565,6 @@ class Scheduler:
             # full step's P_pad·N, a refresh's C_pad·R_bucket, a
             # rebuild's C_pad·N, a fallback's sum of both.
             m["scored_rows_total"] += inf.scored_rows
-            # Per-batch series for the next TPU capture (ROADMAP ask):
-            # device window, uploaded/fetched bytes, and shortlist
-            # repairs PER BATCH, not just totals — bounded like the
-            # batch_sizes trail. The byte deltas are exact: one batch's
-            # prepare→resolve is contiguous on the scheduling thread
-            # even in pipelined mode.
-            ser = m.setdefault("batch_series", {
-                "device_s": [], "h2d_bytes": [], "fetch_bytes": [],
-                "shortlist_repairs": [], "scored_rows": [],
-                "gap_gather_s": [], "gap_encode_s": [],
-                "gap_fetch_s": [], "gap_commit_s": []})
-            if len(ser["device_s"]) < 64:
-                ser["device_s"].append(round(step_s, 6))
-                ser["h2d_bytes"].append(int(inf.h2d1 - inf.h2d0))
-                ser["fetch_bytes"].append(int(inf.fetch1 - inf.fetch0))
-                ser["shortlist_repairs"].append(int(inf.sl_repairs))
-                ser["scored_rows"].append(int(inf.scored_rows))
-                # engine_gap_s decomposition per batch: the components
-                # _book_gap attributed to this batch, plus this batch's
-                # dispatch→fetch window in the fetch slot.
-                ser["gap_gather_s"].append(round(gap.get("gather", 0.0), 6))
-                ser["gap_encode_s"].append(round(gap.get("encode", 0.0), 6))
-                ser["gap_fetch_s"].append(
-                    round(gap.get("fetch", 0.0) + gather_gap, 6))
-                ser["gap_commit_s"].append(round(gap.get("commit", 0.0), 6))
             if inf.failures:
                 # Encode-vs-flush overlap, booked HERE where the flush
                 # window is known: the NEXT batch's prepare may take
@@ -5462,9 +5420,6 @@ class Scheduler:
             if "batch_sizes" in out:
                 # dict() is shallow; the live list must not escape the lock
                 out["batch_sizes"] = list(out["batch_sizes"])
-            if "batch_series" in out:
-                out["batch_series"] = {k: list(v) for k, v
-                                       in out["batch_series"].items()}
         out.update({f"queue_{k}": v for k, v in self.queue.stats().items()})
         out["waiting_pods"] = len(self.waiting_pods)
         # Per-pod lifecycle latency histograms (obs.Histogram snapshots:
@@ -5495,6 +5450,17 @@ class Scheduler:
                                            if idx is not None else 0)
         out["index_cooldown_left"] = int(self._index_cooldown)
         out["compile_cache_dir"] = self._compile_cache_dir
+        # Where pods wait before the queue: seconds the informer thread
+        # spent delivering bursts, seconds callers waited for the store
+        # lock while the flight recorder was armed (an in-process store
+        # only; a RemoteStore has no such lock), and the process's
+        # collection pauses.
+        out["informer_busy_s_total"] = (
+            self._shared.informer_factory.busy_s_total)
+        lock_wait = getattr(self.store, "lock_wait_s_total", None)
+        if callable(lock_wait):
+            out["store_lock_wait_s_total"] = lock_wait()
+        out["gc_pause_s_total"] = gc_pause_s_total()
         # Supervisor state: the ladder rung as a gauge (0 = full fast
         # path; exposed on /metrics via the service provider) plus its
         # name for humans/tests (non-numeric — dropped from exposition).
@@ -5788,10 +5754,11 @@ class Scheduler:
         QueuedPodInfo stamps (queued=added_at → gathered_at →
         decided_at → now); create→bound pairs the store's wall-clock
         creation stamp with wall-clock now, the same definition the
-        bench's sampled windows use."""
+        bench's sampled windows use, and create→enqueued (the informer
+        lag) pairs it with the queue's first-entry stamp."""
         now_m = time.monotonic()
         now_w = time.time()
-        qw, dec, bnd, c2b = [], [], [], []
+        lag, qw, dec, bnd, c2b = [], [], [], [], []
         for qpi in qpis:
             if qpi.gathered_at:
                 qw.append(max(0.0, qpi.gathered_at - qpi.added_at))
@@ -5802,7 +5769,9 @@ class Scheduler:
             created = getattr(qpi.pod.metadata, "creation_timestamp",
                               0.0) or now_w
             c2b.append(max(0.0, now_w - created))
+            lag.append(max(0.0, (qpi.enqueued_unix or created) - created))
         h = self._hists
+        h["pod_informer_lag_s"].observe_many(lag)
         if qw:
             h["pod_queue_wait_s"].observe_many(qw)
         if dec:
@@ -5876,7 +5845,7 @@ class Scheduler:
         self.broadcaster.scheduled(bound, node_name)
         log.info("bound %s to %s", pod.key, node_name)
 
-    def _bind_many(self, items: List[tuple]) -> None:
+    def _bind_many(self, items: List[tuple], seq: int) -> None:
         """Bulk binding commit with failure containment: the task runs on
         the binder pool, where an unhandled exception would silently
         swallow the whole tranche — pods popped, assumed, never bound,
@@ -5888,7 +5857,7 @@ class Scheduler:
             live = self._fence_binds(items)
             if live:
                 FAULTS.hit("bind")  # fault gate: bulk binding task
-                with span("bind.bulk", pods=len(live)):
+                with span("bind.bulk", pods=len(live), seq=seq):
                     self._bind_many_impl(live)
         except Exception:
             log.exception("bulk bind task failed; reconciling %d "

@@ -1,16 +1,12 @@
-"""Engine flight recorder — process-wide span/instant tracer + fixed-
-bucket latency histograms.
+"""Engine flight recorder — process-wide span/instant tracer, fixed-
+bucket latency histograms, and the process's collection pauses.
 
-The bench ledger's standing verdict (BENCH_TPU.json r05, ROADMAP
-"Standing TPU goal") is that the engine is latency/overhead-bound:
-host-side ``engine_gap_s`` rivals ``engine_step_s``, and nothing could
-attribute that gap to gather vs encode vs h2d vs fetch vs commit. This
-module is the instrument: a lock-light per-thread ring-buffer tracer in
-the mold of ``faults.py`` (env-gated; unset = a single attribute test on
-the hot path) recording **spans** (monotonic-ns begin/end, nested per
-thread) and **instants** at the engine's real seams, exported as Chrome
-trace-event JSON (``Scheduler.dump_trace`` / ``tools/trace_view.py``,
-Perfetto-loadable).
+A lock-light per-thread ring-buffer tracer in the mold of ``faults.py``
+(env-gated; unset = a single attribute test on the hot path) recording
+**spans** (monotonic-ns begin/end, nested per thread) and **instants**
+at the engine's real seams, exported as Chrome trace-event JSON
+(``Scheduler.dump_trace`` / ``tools/trace_view.py``, Perfetto-loadable)
+and read by the benchmark's per-layer metrics (``benchmark/metrics``).
 
 Arming:
 
@@ -21,36 +17,63 @@ Arming:
                              newest events, and reports what it dropped)
 
 Seam catalog (the span names the engine emits; ARCHITECTURE.md
-"Observability & flight recorder" is the authoritative table):
+"Observability & flight recorder" is the authoritative table). ``seq``
+is the batch id, assigned when a batch's prepare starts; every span of
+one batch carries the same one, from the prepare to the bind:
 
     queue.pop        batch gather (engine/queue.py; gather worker thread
                      in pipelined mode)
-    prepare          encode → snapshot → dispatch (scheduling thread)
-    encode.pods      pod-feature encode
+    prepare          encode → snapshot → dispatch (scheduling thread; seq)
+    encode.pods      pod-feature encode (seq)
     cache.snapshot / cache.snapshot_resident / cache.snapshot_assigned
                      node/assigned-corpus snapshot + delta collection
     h2d.static / h2d.dyn
                      device uploads (static-leaf cache miss; residency
                      attach corrections)
     step.dispatch    jitted step dispatch + decision/spread pack staging
-    resolve          fetch → arbitration → assume → bind submit
+                     (seq)
+    resolve          fetch → arbitration → assume → bind submit (seq)
     fetch.decision / fetch.spread
                      blocking device readbacks (+ decode/unpack)
+    resolve.arbitrate
+                     RWO + fail-closed + hard-spread arbitration, and the
+                     re-arbitration after a lost assume (seq)
+    resolve.verdicts the per-pod verdict loop (seq)
+    resolve.assume   bulk assume accounting (seq)
     commit / commit.flush
-                     metrics fold / bulk failure flush (commit worker)
+                     metrics fold / bulk failure flush (commit worker;
+                     seq on commit)
     bind.bulk / bind.pod
-                     binder-pool store commits
+                     binder-pool store commits (seq on bind.bulk)
+    gc               a full (generation-2) collection, on the thread that
+                     triggered it (args gen, collected): every thread
+                     stops for it
     explain.ingest / explain.flush
                      resultstore worker (explain/resultstore.py)
+
+The resolve children never start with ``fetch.``: a reader that takes
+``resolve`` without its ``fetch.*`` children reads the same time it did
+before they existed. The informer thread records no span (thousands of
+events a second would make it the busiest lane); its work is the
+``informer_busy_s_total`` counter instead.
 
 Instants: ``fault.<gate>`` (every fault-gate fire, faults.py),
 ``supervisor.escalate`` / ``supervisor.recover`` (ladder transitions),
 ``watchdog.trip``, ``residency.desync``, ``shortlist.desync`` — so a
 faulted run's timeline shows *where* the ladder moved.
 
-When a jax profiler capture is running, every span also enters a
-``jax.profiler.TraceAnnotation`` of the same name, so a TPU profile
-lines up with the engine spans by name.
+Profiler mirror and the shared clock: while armed, every span also
+enters a ``jax.profiler.TraceAnnotation`` of the bare span name (no
+args: trace readers match host events by exact name), and each step
+dispatch sits inside ``jax.profiler.StepTraceAnnotation("batch",
+step_num=seq)`` (:func:`batch_step`), which ties the device ops it
+launches to the batch. A recorder stamp (``ts_ns``, monotonic) and the
+start of its profiler copy differ by one constant offset per process.
+The join rule: estimate that offset from matched spans (same name, in
+order: the median of ``xplane start − ts_ns``), then add it to any
+recorder event to place it on the device trace's clock.
+tests/test_obs.py pins the offset's spread under 0.5 ms, ``gc``
+included.
 
 The tracer never touches decisions, PRNG state, or any engine input —
 decisions are bit-identical with the recorder on or off
@@ -59,15 +82,22 @@ modes).
 
 Histograms: :class:`Histogram` is the fixed-bucket latency histogram
 the engine feeds from per-pod lifecycle stamps
-(created→queued→gathered→decided→bound), exposed through
+(created→enqueued→gathered→decided→bound), exposed through
 ``Scheduler.metrics()["histograms"]`` and the apiserver's native
 Prometheus histogram exposition. Always on (per-POD cost is a bisect at
 bind time, off the device path); the tracer knob gates only the
 span/instant stream.
+
+Collections: :func:`watch_gc` registers one ``gc.callbacks`` hook per
+process (however many engines call it) that sums the pause of every
+collection into :func:`gc_pause_s_total` — always on, two clock reads
+a collection — and, while armed, records each full collection as a
+``gc`` span.
 """
 from __future__ import annotations
 
 import functools
+import gc
 import json
 import os
 import threading
@@ -76,8 +106,8 @@ from bisect import bisect_left
 from typing import Dict, List, Optional
 
 __all__ = ["TRACE", "TraceRecorder", "Histogram", "LATENCY_BUCKETS",
-           "configure", "span", "instant", "traced", "hist_quantile",
-           "ring_tail"]
+           "configure", "span", "instant", "traced", "batch_step",
+           "hist_quantile", "ring_tail", "watch_gc", "gc_pause_s_total"]
 
 
 def ring_tail(buf: list, n: int, cap: int) -> list:
@@ -197,7 +227,10 @@ class TraceRecorder:
     configuration can never leak events across runs)."""
 
     def __init__(self, enabled: bool = False, buf: int = 65536):
-        self._lock = threading.Lock()
+        # Re-entrant: a collection can start while this thread holds the
+        # lock (allocating a ring), and its ``gc`` span then appends on
+        # the same thread.
+        self._lock = threading.RLock()
         self._local = threading.local()
         self._epoch = 0
         self.configure(enabled, buf)
@@ -211,16 +244,18 @@ class TraceRecorder:
             # t0 anchors exported timestamps near zero (Perfetto handles
             # absolute ns fine; small numbers are just friendlier).
             self._t0 = time.monotonic_ns()
-            self._ann = None
+            self._ann = self._step_ann = None
             if enabled:
                 # Optional: mirror spans into the jax profiler so a TPU
                 # capture lines up by name. Lazy + guarded — the tracer
                 # must work (and the off path must import) without jax.
                 try:
-                    from jax.profiler import TraceAnnotation
+                    from jax.profiler import (StepTraceAnnotation,
+                                              TraceAnnotation)
                     self._ann = TraceAnnotation
+                    self._step_ann = StepTraceAnnotation
                 except Exception:
-                    self._ann = None
+                    self._ann = self._step_ann = None
             # Written LAST: a racing span() sees enabled only after the
             # ring registry above is consistent.
             self.enabled = bool(enabled)
@@ -342,6 +377,15 @@ def instant(name: str, **args) -> None:
         rec.instant(name, args or None)
 
 
+def batch_step(seq: int):
+    """Armed: ``jax.profiler.StepTraceAnnotation("batch", step_num=seq)``
+    around a step dispatch, so a profile ties the device ops it launches
+    to batch ``seq``. Unarmed: the shared no-op span."""
+    rec = TRACE
+    step = rec._step_ann if rec.enabled else None
+    return _NULL if step is None else step("batch", step_num=seq)
+
+
 def traced(name: str):
     """Decorator form of :func:`span` for whole-function seams (cache
     snapshots, resultstore ingest). Off path: one extra call frame + the
@@ -359,6 +403,59 @@ def traced(name: str):
         return wrapper
 
     return deco
+
+
+# ---------------------------------------------------------------------------
+# Collections
+# ---------------------------------------------------------------------------
+
+
+class _GcWatch:
+    """The process's one ``gc.callbacks`` hook. Collections never
+    overlap (the interpreter runs one at a time), so the start stamp and
+    the open span need no lock."""
+
+    def __init__(self):
+        self.pause_s = 0.0
+        self._t0 = 0.0
+        self._span: Optional[_Span] = None
+        self._installed = False
+        self._lock = threading.Lock()
+
+    def install(self) -> None:
+        with self._lock:
+            if not self._installed:
+                gc.callbacks.append(self._on_gc)
+                self._installed = True
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            rec = TRACE
+            if info["generation"] == 2 and rec.enabled:
+                sp = _Span(rec, "gc", {"gen": 2})
+                sp.__enter__()
+                self._span = sp
+            self._t0 = time.perf_counter()
+            return
+        self.pause_s += time.perf_counter() - self._t0
+        sp, self._span = self._span, None
+        if sp is not None:
+            sp.set(collected=info.get("collected", 0))
+            sp.__exit__(None, None, None)
+
+
+_GC = _GcWatch()
+
+
+def watch_gc() -> None:
+    """Register the collection hook (idempotent: once per process)."""
+    _GC.install()
+
+
+def gc_pause_s_total() -> float:
+    """Seconds every collection since :func:`watch_gc` has paused the
+    process, all generations."""
+    return _GC.pause_s
 
 
 # ---------------------------------------------------------------------------

@@ -24,6 +24,7 @@ import time
 from typing import Any, Dict, Iterator, List, NamedTuple, Optional
 
 from ..errors import AlreadyExistsError, ConflictError, NotFoundError
+from ..obs import TRACE
 from . import objects as obj
 from .objects import deepcopy_obj, kind_of
 
@@ -43,6 +44,33 @@ class WatchEvent(NamedTuple):
     object: Any  # snapshot of the object after (or, for DELETED, at) mutation
     old_object: Any = None  # snapshot before mutation (MODIFIED/DELETED)
     resource_version: int = 0
+
+
+class _TimedLock:
+    """The store lock (its ``_cond``) as a context manager that, while
+    the flight recorder is armed, adds how long each acquisition waited
+    to ``wait_s``. The sum is updated under the lock it measures.
+    Waiting inside ``Condition.wait`` (a watcher idle for events) is not
+    an acquisition and is not counted."""
+
+    __slots__ = ("_cond", "wait_s")
+
+    def __init__(self, cond: threading.Condition):
+        self._cond = cond
+        self.wait_s = 0.0
+
+    def __enter__(self):
+        if TRACE.enabled:
+            t0 = time.perf_counter()
+            self._cond.acquire()
+            self.wait_s += time.perf_counter() - t0
+        else:
+            self._cond.acquire()
+        return self._cond
+
+    def __exit__(self, *exc):
+        self._cond.release()
+        return False
 
 
 class Watcher:
@@ -67,7 +95,7 @@ class Watcher:
     def next_event(self, timeout: Optional[float] = None) -> Optional[WatchEvent]:
         """Next matching event after the cursor, or None on timeout/stop."""
         deadline = None if timeout is None else time.monotonic() + timeout
-        with self._store._cond:
+        with self._store._locked:
             while not self._stopped.is_set():
                 ev, scanned_to = self._store._next_after(self._cursor, self._kinds)
                 # Advance past non-matching events too, so a kind-filtered
@@ -92,7 +120,7 @@ class Watcher:
         handful). Blocks like ``next_event`` until at least one event
         matches, the timeout lapses (→ []), or the watcher stops."""
         deadline = None if timeout is None else time.monotonic() + timeout
-        with self._store._cond:
+        with self._store._locked:
             while not self._stopped.is_set():
                 evs, scanned_to = self._store._drain_after(
                     self._cursor, self._kinds, max_n)
@@ -116,7 +144,7 @@ class Watcher:
 
     def stop(self) -> None:
         self._stopped.set()
-        with self._store._cond:
+        with self._store._locked:
             self._store._cond.notify_all()
 
 
@@ -129,6 +157,7 @@ class ClusterStore:
 
     def __init__(self, max_log: int = 100_000):
         self._cond = threading.Condition()
+        self._locked = _TimedLock(self._cond)
         self._rv = 0
         self._objects: Dict[str, Dict[str, Any]] = {k: {} for k in self.KINDS}
         self._log: List[WatchEvent] = []
@@ -149,7 +178,7 @@ class ClusterStore:
 
     def create(self, o: Any) -> Any:
         kind = kind_of(o)
-        with self._cond:
+        with self._locked:
             key = o.key
             if key in self._objects[kind]:
                 raise AlreadyExistsError(f"{kind} {key!r} already exists")
@@ -172,7 +201,7 @@ class ClusterStore:
         the first mutation, so a failed call leaves no partial state."""
         objs = list(objs)  # two passes below — an iterator must not exhaust
         now = time.time()
-        with self._cond:
+        with self._locked:
             seen = set()
             for o in objs:
                 kind, key = kind_of(o), o.key
@@ -195,7 +224,7 @@ class ClusterStore:
     def get(self, kind: str, key: str) -> Any:
         # Stored objects are replacement-only (update/bind deep-copy before
         # storing), so copying can happen outside the lock.
-        with self._cond:
+        with self._locked:
             try:
                 o = self._objects[kind][key]
             except KeyError:
@@ -203,7 +232,7 @@ class ClusterStore:
         return deepcopy_obj(o)
 
     def list(self, kind: str) -> List[Any]:
-        with self._cond:
+        with self._locked:
             refs = list(self._objects[kind].values())
         return [deepcopy_obj(o) for o in refs]
 
@@ -211,21 +240,27 @@ class ClusterStore:
         """One consistent reading of the store's observable state for
         the apiserver's /metrics endpoint: per-kind object counts, the
         current resource version, and the watch log's retained depth."""
-        with self._cond:
+        with self._locked:
             return {
                 "objects": {k: len(v) for k, v in self._objects.items()},
                 "resource_version": self._rv,
                 "watch_log_depth": len(self._log),
                 "watch_log_capacity": self._max_log,
+                "lock_wait_s_total": self._locked.wait_s,
             }
 
+    def lock_wait_s_total(self) -> float:
+        """Seconds callers waited to take the store lock while the flight
+        recorder was armed (obs.TRACE)."""
+        return self._locked.wait_s
+
     def count(self, kind: str) -> int:
-        with self._cond:
+        with self._locked:
             return len(self._objects[kind])
 
     def update(self, o: Any, *, check_version: bool = False) -> Any:
         kind = kind_of(o)
-        with self._cond:
+        with self._locked:
             key = o.key
             old = self._objects[kind].get(key)
             if old is None:
@@ -253,7 +288,7 @@ class ClusterStore:
             return o
 
     def delete(self, kind: str, key: str) -> None:
-        with self._cond:
+        with self._locked:
             old = self._objects[kind].pop(key, None)
             if old is None:
                 raise NotFoundError(f"{kind} {key!r} not found")
@@ -267,7 +302,7 @@ class ClusterStore:
         """Commit a binding (reference minisched/minisched.go:266-277 POSTs a
         v1.Binding; here the binding subresource is a store-level CAS that
         fails if the pod is already bound or the node is gone)."""
-        with self._cond:
+        with self._locked:
             pod = self._objects["Pod"].get(pod_key)
             if pod is None:
                 raise NotFoundError(f"Pod {pod_key!r} not found")
@@ -299,7 +334,7 @@ class ClusterStore:
         evolve = obj.shallow_evolve
         bound: List[str] = []
         now = time.time()
-        with self._cond:
+        with self._locked:
             pods_map = self._objects["Pod"]
             nodes_map = self._objects["Node"]
             for pod_key, node_name in assignments:
@@ -338,7 +373,7 @@ class ClusterStore:
         broadcast per revocation."""
         evolve = obj.shallow_evolve
         missing: List[str] = []
-        with self._cond:
+        with self._locked:
             pods_map = self._objects["Pod"]
             dirty = False
             for pod_key, plugins, message in verdicts:
@@ -367,7 +402,7 @@ class ClusterStore:
 
     def watch(self, kinds: Optional[List[str]] = None,
               from_version: Optional[int] = None) -> Watcher:
-        with self._cond:
+        with self._locked:
             start = self._rv if from_version is None else from_version
             if start < self._log_base:
                 raise ValueError(
@@ -383,14 +418,14 @@ class ClusterStore:
         The returned lists SHARE the stored snapshots (read-only, like the
         watch events they are delivered alongside) — a 50k-node initial
         sync must not clone the whole cluster before the first cycle."""
-        with self._cond:
+        with self._locked:
             lists = {k: list(self._objects[k].values())
                      for k in (kinds or self.KINDS)}
             watcher = Watcher(self, kinds, self._rv)
         return lists, watcher
 
     def resource_version(self) -> int:
-        with self._cond:
+        with self._locked:
             return self._rv
 
     def _append(self, ev: WatchEvent, notify: bool = True) -> None:
@@ -446,7 +481,7 @@ class ClusterStore:
         deep copy would dominate. ``fn`` runs under the store lock and
         MUST NOT mutate or retain the objects (the read-only contract
         watch/list_and_watch snapshots already carry)."""
-        with self._cond:
+        with self._locked:
             for o in self._objects[kind].values():
                 fn(o)
 
@@ -456,7 +491,7 @@ class ClusterStore:
         # replacement-only, so the references are immutable snapshots) —
         # an interval checkpoint at 50k nodes must not stall every
         # scheduling-cycle read for the whole serialization.
-        with self._cond:
+        with self._locked:
             rv = self._rv
             cols = {kind: dict(col) for kind, col in self._objects.items()}
         return {

@@ -1,0 +1,96 @@
+"""The benchmark's readers of the engine's starvation counters and the
+resolve phase spans, on hand-built runs: each reads its number from the
+engine's counters, histograms and spans, and reads nothing (None, no
+error) from a program that lacks them."""
+import os
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+from benchmark.run import read_metric  # noqa: E402
+from benchmark.session import Run  # noqa: E402
+from benchmark.trace_reduce import Trace  # noqa: E402
+
+BOUNDS = [0.001, 0.01, 0.1]
+
+
+def _hist(total, count):
+    return {"bounds": BOUNDS, "counts": [0, count, 0, 0], "sum": total,
+            "count": count}
+
+
+def _span(name, ts_ms, dur_ms, tid=1, **args):
+    return {"ph": "X", "name": name, "ts_ns": int(ts_ms * 1e6),
+            "dur_ns": int(dur_ms * 1e6), "tid": tid, "thread": "loop",
+            "args": args or None}
+
+
+def _run():
+    """Window 0..50 s, traced part the first 6 s with 10 batches."""
+    run = Run()
+    run.t0, run.t1 = 0.0, 50.0
+    run.engine0 = {"batches": 10, "informer_busy_s_total": 1.0,
+                   "store_lock_wait_s_total": 0.5, "gc_pause_s_total": 2.0,
+                   "histograms": {"pod_informer_lag_s": _hist(1.0, 100)}}
+    run.traced1 = {"batches": 20, "informer_busy_s_total": 2.5,
+                   "store_lock_wait_s_total": 0.55, "gc_pause_s_total": 2.1,
+                   "histograms": {"pod_informer_lag_s": _hist(3.0, 300)}}
+    run.engine1 = {"batches": 100, "informer_busy_s_total": 9.0,
+                   "store_lock_wait_s_total": 0.55, "gc_pause_s_total": 7.1,
+                   "histograms": {"pod_informer_lag_s": _hist(9.0, 900)}}
+    run.trace = Trace(window_s=6.0, busy_s=0.5, devices=1)
+    run.spans = [
+        _span("resolve", 0.0, 20.0, seq=1, pods=8),
+        _span("fetch.decision", 0.5, 2.0),
+        _span("resolve.arbitrate", 3.0, 4.0, seq=1),
+        _span("resolve.verdicts", 8.0, 2.0, seq=1),
+        _span("resolve.assume", 11.0, 3.0, seq=1),
+        _span("resolve", 100.0, 30.0, seq=2, pods=8),
+        _span("resolve.arbitrate", 101.0, 6.0, seq=2),
+        _span("resolve.verdicts", 108.0, 2.0, seq=2),
+        _span("resolve.assume", 111.0, 5.0, seq=2),
+    ]
+    return run
+
+
+def _parent_run():
+    """What a program without these counters and spans gives."""
+    run = _run()
+    for snap in (run.engine0, run.traced1, run.engine1):
+        for k in ("informer_busy_s_total", "store_lock_wait_s_total",
+                  "gc_pause_s_total"):
+            del snap[k]
+        snap["histograms"] = {"pod_queue_wait_s": _hist(1.0, 10)}
+    run.spans = [e for e in run.spans if not e["name"].startswith(
+        "resolve.")]
+    return run
+
+
+READINGS = [
+    # Δsum / Δcount over the traced part: (3.0 − 1.0) / 200 s
+    ("informer_lag_ms.drain", 10.0),
+    ("informer_lag_ms.rate", 10.0),
+    # (2.5 − 1.0) s of a 6 s traced window
+    ("informer_busy_pct.drain", 25.0),
+    # (0.55 − 0.5) s over 10 traced batches
+    ("store_lock_wait_ms.drain", 5.0),
+    # (7.1 − 2.0) s of the whole 50 s window
+    ("gc_pause_pct.drain", 10.2),
+    ("gc_pause_pct.rate", 10.2),
+    # (3 + 5) ms of resolve.assume over 10 batches
+    ("assume_ms.drain", 0.8),
+    # (4 + 6) ms of resolve.arbitrate over 10 batches
+    ("arbitrate_ms.rate", 1.0),
+]
+
+
+@pytest.mark.parametrize("name,want", READINGS)
+def test_reader_reads_the_engine(name, want):
+    assert read_metric(name, _run()) == pytest.approx(want, rel=1e-9)
+
+
+@pytest.mark.parametrize("name", [n for n, _v in READINGS])
+def test_reader_is_silent_without_the_counter(name):
+    assert read_metric(name, _parent_run()) is None

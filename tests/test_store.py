@@ -2,6 +2,7 @@
 binding CAS, snapshot/restore (reference capability: apiserver+etcd,
 k8sapiserver/k8sapiserver.go:43-105)."""
 import threading
+import time
 
 import pytest
 
@@ -192,3 +193,89 @@ def test_next_events_batch_drain():
     rest = w.next_events(100, timeout=1.0)
     assert [e.object.metadata.name for e in rest] == ["p3", "p4", "p5", "p6"]
     assert w.next_events(10, timeout=0.05) == []
+
+
+def _hold_lock(store, seconds):
+    """Hold the store lock on another thread for `seconds`; returns
+    once it is held."""
+    held = threading.Event()
+
+    def hold():
+        with store._cond:
+            held.set()
+            time.sleep(seconds)
+
+    t = threading.Thread(target=hold)
+    t.start()
+    held.wait()
+    return t
+
+
+def test_lock_wait_counted_only_while_armed():
+    """A bind that waits 50 ms behind another holder of the store lock
+    adds that wait to lock_wait_s_total while the flight recorder is
+    armed, and nothing while it is not."""
+    from minisched_tpu import obs
+
+    store = ClusterStore()
+    store.create(make_node("n1"))
+    store.create_many([make_pod("a"), make_pod("b")])
+    try:
+        obs.configure(True, buf=64)
+        t = _hold_lock(store, 0.05)
+        w0 = store.lock_wait_s_total()
+        assert store.bind_pods([("default/a", "n1")]) == ["default/a"]
+        t.join()
+        assert store.lock_wait_s_total() - w0 >= 0.04
+        obs.configure(False)
+        w1 = store.lock_wait_s_total()
+        t = _hold_lock(store, 0.05)
+        assert store.bind_pods([("default/b", "n1")]) == ["default/b"]
+        t.join()
+        assert store.lock_wait_s_total() == w1
+        assert store.stats()["lock_wait_s_total"] == w1
+    finally:
+        obs.configure(False)
+
+
+def test_lock_wait_sum_loses_no_update_under_contention(monkeypatch):
+    """Many threads taking the store lock at once, each acquisition
+    reading a wait of exactly one tick from a per-thread clock: the
+    armed sum must count every one (it is updated under the lock)."""
+    import sys
+    import types
+
+    from minisched_tpu import obs
+    from minisched_tpu.state import store as store_mod
+
+    local = threading.local()
+
+    def ticks():
+        local.t = getattr(local, "t", 0.0) + 1.0
+        return local.t
+
+    monkeypatch.setattr(store_mod, "time", types.SimpleNamespace(
+        perf_counter=ticks, time=time.time, monotonic=time.monotonic))
+    store = ClusterStore()
+    n_threads, per_thread = 16, 300
+    start = threading.Barrier(n_threads)
+
+    def work():
+        start.wait()
+        for _ in range(per_thread):
+            store.count("Pod")
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    obs.configure(True, buf=64)
+    try:
+        threads = [threading.Thread(target=work) for _ in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        obs.configure(False)
+        sys.setswitchinterval(old)
+    assert store.lock_wait_s_total() == float(n_threads * per_thread)

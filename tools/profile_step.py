@@ -123,8 +123,7 @@ def main() -> None:
     # of the engine's live health counters (metrics(): index_hits /
     # index_fallbacks = hit fraction, index_repair_rows = in-place
     # repairs, index_rebuilds = certified-stale rebuilds, and the
-    # per-batch scored-rows series in batch_series.scored_rows, which
-    # bench.engine_bench exports as *_batch_scored_rows).
+    # scored-rows ledger scored_rows_total).
     from minisched_tpu.ops.index import build_index_ops, index_eligible
     idx_eligible = index_eligible(pset)
     if not cfg_env.index:
