@@ -5452,14 +5452,16 @@ class Scheduler:
         out["compile_cache_dir"] = self._compile_cache_dir
         # Where pods wait before the queue: seconds the informer thread
         # spent delivering bursts, seconds callers waited for the store
-        # lock while the flight recorder was armed (an in-process store
-        # only; a RemoteStore has no such lock), and the process's
-        # collection pauses.
+        # lock and how often they took it while the flight recorder was
+        # armed (an in-process store only; a RemoteStore has no such
+        # lock), and the process's collection pauses.
         out["informer_busy_s_total"] = (
             self._shared.informer_factory.busy_s_total)
         lock_wait = getattr(self.store, "lock_wait_s_total", None)
         if callable(lock_wait):
             out["store_lock_wait_s_total"] = lock_wait()
+            out["store_lock_acquisitions_total"] = (
+                self.store.lock_acquisitions_total())
         out["gc_pause_s_total"] = gc_pause_s_total()
         # Supervisor state: the ladder rung as a gauge (0 = full fast
         # path; exposed on /metrics via the service provider) plus its

@@ -31,6 +31,10 @@ class PVController:
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         self._prov_seq = itertools.count(1)
+        # Written by the controller thread only; stats() reads them.
+        self._events = 0
+        self._syncs = 0
+        self._pods_watched = False
 
     def start(self) -> None:
         self._thread = threading.Thread(target=self._run, daemon=True,
@@ -43,32 +47,68 @@ class PVController:
             self._thread.join(timeout=5)
             self._thread = None
 
+    def stats(self) -> dict:
+        """Events taken off the watch, syncs run, and whether the watch
+        follows pods now (a WaitForFirstConsumer claim waits)."""
+        return {"events_total": self._events, "syncs_total": self._syncs,
+                "pods_watched": self._pods_watched}
+
     # ---- sync loop ------------------------------------------------------
 
     ZONE_KEY = "topology.kubernetes.io/zone"
+    # What the controller works on. Pods join the watch only while a
+    # WaitForFirstConsumer claim waits for its consumer to be scheduled
+    # (upstream late binding), so volumeless pod churn never reaches it.
+    KINDS = ("PersistentVolumeClaim", "PersistentVolume")
+    BURST = 1024  # events drained per store-lock acquisition
 
     def _run(self) -> None:
-        # Pods are watched too: a WaitForFirstConsumer claim binds only
-        # once its consuming pod is scheduled (upstream late binding).
-        watcher = self._store.watch(
-            kinds=["PersistentVolumeClaim", "PersistentVolume", "Pod"])
-        self._sync_once()
+        watcher = self._store.watch(kinds=list(self.KINDS))
+        follow = self._sync_once()
         while not self._stop.is_set():
-            ev = watcher.next_event(timeout=self._sync)
-            if ev is not None and ev.kind == "Pod" and not (
-                    ev.object is not None and obj.claim_keys(ev.object)):
-                continue  # volumeless pod churn: nothing to (late-)bind
-            self._sync_once()
+            kinds = list(self.KINDS) + (["Pod"] if follow else [])
+            try:
+                if follow != self._pods_watched:
+                    # Reopen at the old cursor: one stream, no gap, and
+                    # no event delivered twice.
+                    watcher.stop()
+                    watcher = self._store.watch(
+                        kinds, from_version=watcher.cursor)
+                    self._pods_watched = follow
+                evs = watcher.next_events(self.BURST, timeout=self._sync)
+            except ValueError:
+                # The cursor left the retained log. Each sync re-lists
+                # what it needs, so a watch from now misses nothing.
+                watcher.stop()
+                watcher = self._store.watch(kinds)
+                self._pods_watched = follow
+                evs = []
+            self._events += len(evs)
+            if evs and not any(map(self._prompts_sync, evs)):
+                continue  # no claim's consumer newly scheduled
+            # a burst worth a sync, or a quiet period (the backstop)
+            follow = self._sync_once()
         watcher.stop()
 
-    def _sync_once(self) -> None:
+    @staticmethod
+    def _prompts_sync(ev) -> bool:
+        """A claim or volume changed, or a pod with claims is bound."""
+        if ev.kind != "Pod":
+            return True
+        return bool(ev.object.spec.node_name and obj.claim_keys(ev.object))
+
+    def _sync_once(self) -> bool:
+        """Bind every pending claim that can be bound. True while a
+        WaitForFirstConsumer claim still waits for a scheduled consumer."""
+        self._syncs += 1
         try:
             pvcs = self._store.list("PersistentVolumeClaim")
             pvs = self._store.list("PersistentVolume")
         except Exception:
-            return
+            return self._pods_watched
         available = [pv for pv in pvs if pv.phase == "Available"]
         consumer_zones = None  # lazy: only listed when a WFFC claim pends
+        waiting = False
         for pvc in pvcs:
             if pvc.phase == "Bound":
                 continue
@@ -77,7 +117,8 @@ class PVController:
                 if consumer_zones is None:
                     consumer_zones = self._scheduled_consumer_zones()
                 if pvc.key not in consumer_zones:
-                    continue  # no scheduled consumer yet: wait
+                    waiting = True  # no scheduled consumer yet: wait
+                    continue
                 zone = consumer_zones[pvc.key]
             match = self._find_match(pvc, available, zone=zone)
             if match is None and self._dynamic:
@@ -85,6 +126,7 @@ class PVController:
             if match is not None:
                 self._bind(pvc, match)
                 available = [pv for pv in available if pv.key != match.key]
+        return waiting
 
     def _scheduled_consumer_zones(self):
         """PVC key → zone of the node its scheduled consumer landed on

@@ -49,21 +49,23 @@ class WatchEvent(NamedTuple):
 class _TimedLock:
     """The store lock (its ``_cond``) as a context manager that, while
     the flight recorder is armed, adds how long each acquisition waited
-    to ``wait_s``. The sum is updated under the lock it measures.
-    Waiting inside ``Condition.wait`` (a watcher idle for events) is not
-    an acquisition and is not counted."""
+    to ``wait_s`` and counts it in ``acquisitions``. Both are updated
+    under the lock they measure. Waiting inside ``Condition.wait`` (a
+    watcher idle for events) is not an acquisition and is not counted."""
 
-    __slots__ = ("_cond", "wait_s")
+    __slots__ = ("_cond", "wait_s", "acquisitions")
 
     def __init__(self, cond: threading.Condition):
         self._cond = cond
         self.wait_s = 0.0
+        self.acquisitions = 0
 
     def __enter__(self):
         if TRACE.enabled:
             t0 = time.perf_counter()
             self._cond.acquire()
             self.wait_s += time.perf_counter() - t0
+            self.acquisitions += 1
         else:
             self._cond.acquire()
         return self._cond
@@ -247,12 +249,18 @@ class ClusterStore:
                 "watch_log_depth": len(self._log),
                 "watch_log_capacity": self._max_log,
                 "lock_wait_s_total": self._locked.wait_s,
+                "lock_acquisitions_total": self._locked.acquisitions,
             }
 
     def lock_wait_s_total(self) -> float:
         """Seconds callers waited to take the store lock while the flight
         recorder was armed (obs.TRACE)."""
         return self._locked.wait_s
+
+    def lock_acquisitions_total(self) -> int:
+        """Times callers took the store lock while the flight recorder
+        was armed (obs.TRACE)."""
+        return self._locked.acquisitions
 
     def count(self, kind: str) -> int:
         with self._locked:

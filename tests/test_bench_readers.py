@@ -33,12 +33,15 @@ def _run():
     run.t0, run.t1 = 0.0, 50.0
     run.engine0 = {"batches": 10, "informer_busy_s_total": 1.0,
                    "store_lock_wait_s_total": 0.5, "gc_pause_s_total": 2.0,
+                   "store_lock_acquisitions_total": 1000,
                    "histograms": {"pod_informer_lag_s": _hist(1.0, 100)}}
     run.traced1 = {"batches": 20, "informer_busy_s_total": 2.5,
                    "store_lock_wait_s_total": 0.55, "gc_pause_s_total": 2.1,
+                   "store_lock_acquisitions_total": 3000,
                    "histograms": {"pod_informer_lag_s": _hist(3.0, 300)}}
     run.engine1 = {"batches": 100, "informer_busy_s_total": 9.0,
                    "store_lock_wait_s_total": 0.55, "gc_pause_s_total": 7.1,
+                   "store_lock_acquisitions_total": 9000,
                    "histograms": {"pod_informer_lag_s": _hist(9.0, 900)}}
     run.trace = Trace(window_s=6.0, busy_s=0.5, devices=1)
     run.spans = [
@@ -60,7 +63,7 @@ def _parent_run():
     run = _run()
     for snap in (run.engine0, run.traced1, run.engine1):
         for k in ("informer_busy_s_total", "store_lock_wait_s_total",
-                  "gc_pause_s_total"):
+                  "gc_pause_s_total", "store_lock_acquisitions_total"):
             del snap[k]
         snap["histograms"] = {"pod_queue_wait_s": _hist(1.0, 10)}
     run.spans = [e for e in run.spans if not e["name"].startswith(
@@ -76,6 +79,8 @@ READINGS = [
     ("informer_busy_pct.drain", 25.0),
     # (0.55 − 0.5) s over 10 traced batches
     ("store_lock_wait_ms.drain", 5.0),
+    # (3000 − 1000) acquisitions over 10 traced batches
+    ("store_lock_acq.drain", 200.0),
     # (7.1 − 2.0) s of the whole 50 s window
     ("gc_pause_pct.drain", 10.2),
     ("gc_pause_pct.rate", 10.2),

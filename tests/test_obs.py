@@ -194,6 +194,8 @@ def test_decisions_bit_identical_trace_on_off(mode):
     assert set(RESOLVE_PHASES) <= names, sorted(names)
     assert m0["store_lock_wait_s_total"] == 0.0
     assert m1["store_lock_wait_s_total"] > 0.0
+    assert m0["store_lock_acquisitions_total"] == 0
+    assert m1["store_lock_acquisitions_total"] > 0
 
 
 def test_span_nesting_and_ordering_under_pipeline():
