@@ -16,7 +16,13 @@ is freed, on the answers it gave:
   (limit from the configuration's `check` section);
 - `zone_skew` (configurations that state a zone-skew guarantee): the
   largest count of matching bound pods a zone surely held above the least
-  zone right after a batch bound (limit: the configuration's maxSkew).
+  zone right after a batch bound (limit: the configuration's maxSkew);
+- `anti_affinity` (configurations that state an anti-affinity
+  guarantee): the largest number of mutually exclusive pods alive at
+  once in one domain, read at each judged pod's bind stamp from every
+  pod's [bind, delete) stamps (limit 1). Two pods are mutually exclusive
+  under a key where a required anti-affinity term of either matches the
+  other. Exact from the stamps.
 
 A judged pod is one the traffic created and the scheduler bound at or
 after the window opened. Pods that the store stamped with one bind time
@@ -32,6 +38,20 @@ the skew verdict are the same for both). So a gap is never the product
 of not knowing what the engine saw, only of a placement the reference
 would not make. The zone skew is exact at the end of the run while no
 matching pod was deleted; otherwise only what the stamps make sure.
+
+Inter-pod affinity is judged from the same two states: every term's
+matching pods per domain lie between their counts under A and under B.
+A placement surely fails where it fails the filter under every state in
+between (a matching pod under A in an anti term's domain, no matching
+pod under B in an affinity term's, a pod under A whose anti term
+forbids the domain) or shares a domain with a pod of its own batch that
+it excludes or that excludes it. A node is surely better only where it
+surely passes the filter and no pod of the batch that excludes the
+pod's kind sits in its domain. The InterPodAffinity score is bounded per
+node by taking each term's positive contributions from A and negative
+ones from B (and the reverse), then through upstream's normalisation
+with the least and largest raw scores each bounded over the nodes that
+surely and that possibly pass, so a correct engine is never charged.
 
 The control (reported with `--control`) puts each judged pod on a node drawn at random
 from those that surely had room and passed every filter: the reference
@@ -78,16 +98,18 @@ class Universe:
         names = sorted(cl.templates)
         self.templates = [cl.templates[n] for n in names]
         tid = {n: i for i, n in enumerate(names)}
+        by_id = {id(t): i for i, t in enumerate(self.templates)}
         keys, node, tmpl, t_bind = [], [], [], []
-        for k, r in zip(cl.standing_keys, cl.standing_node):
+        for k, r, t in zip(cl.standing_keys, cl.standing_node,
+                           cl.standing_template):
             keys.append(k)
             node.append(int(r))
-            tmpl.append(tid[cl.standing_template])
+            tmpl.append(tid[t])
             t_bind.append(-math.inf)
         for k, (n, stamp) in run.put_back.items():
             keys.append(k)
             node.append(row[n])
-            tmpl.append(tid[cl.standing_template])
+            tmpl.append(by_id[id(run.template_of[k])])
             t_bind.append(stamp)
         incoming = tid[run.template]
         for k, (n, stamp) in run.binds.items():
@@ -109,6 +131,12 @@ class Universe:
                                      minlength=self.n_nodes)
                          for r in range(self.req.shape[1])], axis=1)
 
+    def view(self, mask: np.ndarray) -> list:
+        """The pods of `mask` as the reference's view: (template, node
+        rows) per template."""
+        return [(t, self.node[mask & (self.tmpl == i)])
+                for i, t in enumerate(self.templates)]
+
 
 def judge(run, cfg: dict) -> tuple:
     """(the run's verdict, the control's verdict on the same batches)."""
@@ -125,6 +153,8 @@ def judge(run, cfg: dict) -> tuple:
     zs = cfg["guarantees"].get("zone_skew")
     if zs:
         v.add("zone_skew", skew, zs["max_skew"])
+    if cfg["guarantees"].get("anti_affinity"):
+        v.add("anti_affinity", _anti_affinity(run, nodes, u), 1)
     ctl = Verdict()
     ctl.add("score_gap", ctl_gap, limits["score_gap"])
     return v, ctl
@@ -161,6 +191,7 @@ def _placements(run, cfg, nodes: ref.Nodes, u: Universe, limits) -> tuple:
     z_dom = nodes.domains(zs["key"]) if zs else None
     z_match = (np.array([ref.matches(t.get("labels", {}), zs["match_labels"])
                          for t in u.templates])[u.tmpl] if zs else None)
+    aff = any(t.get("affinity") for t in u.templates)
     worst_gap = worst_ctl = 0.0
     bad = 0
     worst_skew = -math.inf if zs else 0
@@ -168,6 +199,8 @@ def _placements(run, cfg, nodes: ref.Nodes, u: Universe, limits) -> tuple:
         group = u.t_bind == T
         certain = (u.t_bind < T - wb) & (u.t_del > T) & ~group
         possible = (u.t_bind <= T + wb) & (u.t_del >= T - wd) & ~group
+        views = ((u.view(certain), u.view(possible), u.view(group))
+                 if aff else None)
         used_a, used_b = u.used(certain), u.used(possible)
         placed = u.used(group)
         if zs:
@@ -183,7 +216,7 @@ def _placements(run, cfg, nodes: ref.Nodes, u: Universe, limits) -> tuple:
             rows = u.node[group & (u.tmpl == t_i)]
             g, c, b = _judge_template(t, nodes, u, certain, possible,
                                       used_a, used_b, placed, rows,
-                                      weights, plugins, rng)
+                                      weights, plugins, rng, views)
             worst_gap, worst_ctl = max(worst_gap, g), max(worst_ctl, c)
             bad += b
     if zs:
@@ -200,21 +233,47 @@ def _placements(run, cfg, nodes: ref.Nodes, u: Universe, limits) -> tuple:
 
 
 def _judge_template(t, nodes, u, certain, possible, used_a, used_b,
-                    placed, rows, weights, plugins, rng) -> tuple:
+                    placed, rows, weights, plugins, rng, views) -> tuple:
     """(widest gap, the control's widest gap, surely-failing placements)
     for the pods of one template in one batch, placed on `rows`.
 
     A pod under topology-spread constraints is compared only with nodes
     in its node's domains: there the spread score and the domain's skew
     verdict are the same for both nodes, whatever the unknown counts, so
-    only the node's own scores (resources, images) can differ."""
+    only the node's own scores (resources, images) can differ. `views`
+    (certain, possible, the batch) as the reference's views, where a
+    template of the run carries affinity terms, else None."""
     req = ref.request(t, nodes.resources)
     static = ref.static_filter(t, nodes, plugins)
-    local = plugins - {"PodTopologySpread"}
+    local = plugins - {"PodTopologySpread", "InterPodAffinity"}
     s_a = ref.scores(t, nodes, used_a, {}, static, weights, local)
     s_b = ref.scores(t, nodes, used_b, {}, static, weights, local)
     lo, hi = np.minimum(s_a, s_b), np.maximum(s_a, s_b)
     valid = static & ref.fits(req, used_b + placed, nodes.alloc)
+    # surely fails: a static filter, or no room even under A with the
+    # whole batch placed
+    over = ~ref.fits(np.zeros_like(req), used_a + placed, nodes.alloc)
+    failing = ~static[rows] | over[rows]
+    if views is not None and "InterPodAffinity" in plugins:
+        a, b, batch = views
+        sure_fail = (~ref.affinity_ok(t, nodes, b, anywhere=a)
+                     | ~ref.anti_affinity_ok(t, nodes, a))
+        sure_pass = (ref.affinity_ok(t, nodes, a, anywhere=b)
+                     & ref.anti_affinity_ok(t, nodes, b))
+        block = _excluding(t, nodes, batch)
+        # each pod of the batch counts itself once per term by which its
+        # kind excludes itself
+        others = block[rows] - len(_exclusions(t, t))
+        failing |= sure_fail[rows] | (others > 0)
+        valid &= sure_pass & (block == 0)
+        maybe = static & ref.fits(req, used_a, nodes.alloc) & ~sure_fail
+        maybe[rows] = True
+        plus_a, minus_a = ref.affinity_parts(t, nodes, a)
+        plus_b, minus_b = ref.affinity_parts(t, nodes, b)
+        n_lo, n_hi = _normalized_bounds(plus_a - minus_b, plus_b - minus_a,
+                                        valid, maybe)
+        w = weights.get("InterPodAffinity", 1.0)
+        lo, hi = lo + w * n_lo, hi + w * n_hi
     dom = np.zeros(len(nodes.labels), dtype=np.int64)
     for c in t.get("topology_spread_constraints", []):
         d = nodes.domains(c["topology_key"])
@@ -222,12 +281,91 @@ def _judge_template(t, nodes, u, certain, possible, used_a, used_b,
     best = np.full(int(dom.max()) + 1, -math.inf)
     np.maximum.at(best, dom[valid], lo[valid])
     gap = float(np.max(best[dom[rows]] - hi[rows], initial=0.0))
-    # surely fails: a static filter, or no room even under A with the
-    # whole batch placed
-    over = ~ref.fits(np.zeros_like(req), used_a + placed, nodes.alloc)
-    bad = int((~static[rows] | over[rows]).sum())
+    bad = int(failing.sum())
     ctl = 0.0
     if valid.any():
         pick = rng.choice(np.flatnonzero(valid), size=len(rows))
         ctl = float(np.max(best[dom[pick]] - hi[pick], initial=0.0))
     return max(gap, 0.0), max(ctl, 0.0), bad
+
+
+def _exclusions(t, pod):
+    """The topology keys under which a pod of template `t` and one of
+    `pod` exclude each other: a required anti term of either that
+    matches the other, once per term."""
+    keys = [x["topology_key"] for x, _w in ref.terms(t, "pod_anti_affinity",
+                                                     True)
+            if ref.term_matches(x, t["namespace"], pod)]
+    keys += [x["topology_key"] for x, _w in ref.terms(
+        pod, "pod_anti_affinity", True)
+        if ref.term_matches(x, pod["namespace"], t)]
+    return keys
+
+
+def _excluding(t, nodes, view) -> np.ndarray:
+    """(N,) pods of `view` in each node's domain that a pod of `t`
+    excludes or is excluded by, summed over the terms."""
+    out = np.zeros(len(nodes.labels))
+    for pod, rows in view:
+        for key in _exclusions(t, pod):
+            out += ref.in_domain(nodes, key, rows)
+    return out
+
+
+def _normalized_bounds(raw_lo, raw_hi, sure, maybe) -> tuple:
+    """Bounds on upstream's normalised InterPodAffinity score, 100 x (s -
+    m) / (M - m) with m = min(0, min s), M = max(0, max s) over the
+    feasible nodes, where each node's raw score s lies in [raw_lo,
+    raw_hi] and the feasible nodes include `sure` and lie in `maybe`."""
+    def least(x, mask):
+        return min(0.0, float(x[mask].min())) if mask.any() else 0.0
+
+    def most(x, mask):
+        return max(0.0, float(x[mask].max())) if mask.any() else 0.0
+
+    m_lo, m_hi = least(raw_lo, maybe), least(raw_hi, sure)
+    big_lo, big_hi = most(raw_lo, sure), most(raw_hi, maybe)
+    span_hi, span_lo = big_hi - m_lo, big_lo - m_hi
+    lo = (np.clip(raw_lo - m_hi, 0.0, None) / span_hi if span_hi > 0
+          else np.zeros_like(raw_lo))
+    top = raw_hi - m_lo
+    hi = np.where(top <= 0, 0.0,
+                  np.minimum(100.0, 100.0 * top / span_lo) if span_lo > 0
+                  else 100.0)
+    return 100.0 * lo, hi
+
+
+def _anti_affinity(run, nodes: ref.Nodes, u: Universe) -> int:
+    """The largest number of mutually exclusive pods alive at once in one
+    domain, read at each judged pod's bind stamp."""
+    judged = (u.t_bind >= run.t0) & np.isfinite(u.t_bind)
+    worst = 0
+    for i in np.unique(u.tmpl[judged]):
+        t = u.templates[i]
+        p = np.flatnonzero(judged & (u.tmpl == i))
+        worst = max(worst, 1)
+        for j, pod in enumerate(u.templates):
+            for key in _exclusions(t, pod):
+                dom = nodes.domains(key)
+                q = np.flatnonzero(u.tmpl == j)
+                alive = _alive(dom[u.node[p]], u.t_bind[p], dom[u.node[q]],
+                               u.t_bind[q], u.t_del[q])
+                if i == j:   # a pod alive at its own bind counts itself
+                    alive -= 1
+                alive = np.where(dom[u.node[p]] >= 0, alive, 0)
+                worst = max(worst, 1 + int(alive.max(initial=0)))
+    return worst
+
+
+def _alive(p_dom, p_t, q_dom, q_bind, q_del) -> np.ndarray:
+    """For each p, the q in p's domain with q_bind <= p_t < q_del."""
+    times = np.unique(np.concatenate([p_t, q_bind, q_del]))
+
+    def key(d, x):   # one sorted axis: domain first, then time
+        return d * (len(times) + 1) + np.searchsorted(times, x)
+
+    bound = np.sort(key(q_dom, q_bind))
+    gone = np.sort(key(q_dom, q_del))
+    at = key(p_dom, p_t)
+    return (np.searchsorted(bound, at, side="right")
+            - np.searchsorted(gone, at, side="right"))

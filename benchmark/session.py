@@ -48,6 +48,7 @@ class Run:
         self.created: Dict[str, tuple] = {}
         self.deleted: Dict[str, float] = {}
         self.put_back: Dict[str, tuple] = {}  # key -> (node, created)
+        self.template_of: Dict[str, dict] = {}  # standing, put back
         self.rebinds = 0
         self.late: List[float] = []
         self.engine0: dict = {}
@@ -260,7 +261,8 @@ def measure(cfg: dict, traffic: dict, args, scale: int, t_process: float,
     drv = Driver(store, cfg["pod_templates"][run.template], traffic,
                  {k: cl.node_names[r] for k, r in
                   zip(cl.standing_keys, cl.standing_node)},
-                 cl.templates.get(cl.standing_template))
+                 {k: cl.templates[t] for k, t in
+                  zip(cl.standing_keys, cl.standing_template)})
     drv.start()
     try:
         if not _warm_up(drv, traffic, args, scale, sched):
@@ -293,7 +295,7 @@ def measure(cfg: dict, traffic: dict, args, scale: int, t_process: float,
     finally:
         drv.stop()
     run.binds, run.created, run.deleted = drv.binds, drv.created, drv.deleted
-    run.put_back = drv.put_back
+    run.put_back, run.template_of = drv.put_back, drv.template_of
     run.rebinds, run.late = drv.rebinds, drv.late
     store.for_each("Pod", lambda p: run.store_pods.append(
         (p.key, p.spec.node_name, p.spec.requests)))

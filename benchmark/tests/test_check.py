@@ -2,6 +2,7 @@
 import copy
 
 import numpy as np
+import pytest
 
 from benchmark import check
 from benchmark.session import Run
@@ -112,3 +113,125 @@ def test_the_control_reads_a_gap():
     assert rows(v)["score_gap"] == 0.0
     assert rows(v)["control_score_gap"] > 1.0
     assert np.isfinite(rows(v)["control_score_gap"])
+
+
+# ---- inter-pod affinity ---------------------------------------------------
+
+HOST = "kubernetes.io/hostname"
+
+
+def _term(**labels):
+    return {"match_labels": labels, "match_expressions": [],
+            "topology_key": HOST, "namespaces": []}
+
+
+AFF = copy.deepcopy(CFG)
+AFF["pod_templates"].update({
+    "g": {"name_prefix": "g-", "namespace": "default",
+          "requests": {"cpu": 100, "memory": GI},
+          "labels": {"color": "green"}, "topology_spread_constraints": [],
+          "affinity": {"pod_anti_affinity": {
+              "required": [_term(color="green")]}}},
+    "foo": {"name_prefix": "foo-", "namespace": "default",
+            "requests": {"cpu": 100, "memory": GI}, "labels": {"foo": ""},
+            "topology_spread_constraints": []},
+    "f": {"name_prefix": "f-", "namespace": "default",
+          "requests": {"cpu": 100, "memory": GI}, "labels": {},
+          "topology_spread_constraints": [],
+          "affinity": {"pod_affinity": {"preferred": [
+              {"weight": 1, "term": _term(foo="")}]}}},
+})
+AFF["profile"]["plugins"].append("InterPodAffinity")
+AFF["profile"]["weights"]["InterPodAffinity"] = 1.0
+AFF["guarantees"] = {"zone_skew": None,
+                     "anti_affinity": {"text": "no two green pods on a node"}}
+
+
+def affinity_run(incoming, binds, deleted=None, standing=None):
+    cfg = copy.deepcopy(AFF)
+    cfg["incoming"]["template"] = incoming
+    if standing:
+        cfg["standing"] = [cfg["standing"], standing]
+    run = make_run(binds, deleted, cfg=cfg)
+    run.template = incoming
+    return run, cfg
+
+
+def test_two_green_pods_alive_on_one_node_break_anti_affinity():
+    run, cfg = affinity_run("g", {"default/g-0": (0, 101.0),
+                                  "default/g-1": (0, 105.0)})
+    v = judge_v(run, cfg)
+    assert not v.correct
+    assert rows(v)["anti_affinity"] == 2
+    assert rows(v)["filter_fail"] > 0
+
+
+def test_two_green_pods_of_one_batch_on_one_node_surely_fail():
+    run, cfg = affinity_run("g", {"default/g-0": (1, 101.0),
+                                  "default/g-1": (1, 101.0),
+                                  "default/g-2": (2, 101.0)})
+    v = judge_v(run, cfg)
+    assert rows(v)["anti_affinity"] == 2
+    assert rows(v)["filter_fail"] == 2   # both pods of the pair
+
+
+def test_a_green_pod_bound_after_the_others_delete_stamp_passes():
+    run, cfg = affinity_run("g", {"default/g-0": (0, 101.0),
+                                  "default/g-1": (0, 105.0)},
+                            {"default/g-0": 103.0})
+    v = judge_v(run, cfg)
+    assert v.correct, v.rows
+    assert rows(v)["anti_affinity"] == 1
+
+
+def test_a_placement_against_a_preferred_affinity_pull_shows_a_gap():
+    # one foo pod stands on a node drawn from the seed; the incoming pod
+    # prefers its node (weight 1: 100 points, less 10 for the extra pod)
+    # and goes elsewhere
+    run, cfg = affinity_run("f", {}, standing={
+        "count": 1, "template": "foo", "distinct_nodes": True})
+    foo = int(run.cluster.standing_node[-1])
+    away = (foo + 1) % 4
+    run, cfg = affinity_run("f", {"default/f-0": (away, 105.0)},
+                            standing={"count": 1, "template": "foo",
+                                      "distinct_nodes": True})
+    v = judge_v(run, cfg)
+    assert rows(v)["score_gap"] == pytest.approx(90.0)
+    assert not v.correct
+    # on the foo pod's node it is correct
+    run, cfg = affinity_run("f", {"default/f-0": (foo, 105.0)},
+                            standing={"count": 1, "template": "foo",
+                                      "distinct_nodes": True})
+    assert judge_v(run, cfg).correct
+
+
+def test_a_deletion_the_engine_may_not_have_seen_is_no_affinity_fault():
+    # the foo pod went 0.1 s before the bind: the engine may still have
+    # seen it and followed its pull
+    run, cfg = affinity_run("f", {}, standing={
+        "count": 1, "template": "foo", "distinct_nodes": True})
+    foo_key, foo = run.cluster.standing_keys[-1], int(
+        run.cluster.standing_node[-1])
+    run, cfg = affinity_run("f", {"default/f-0": (foo, 105.0)},
+                            {foo_key: 104.9},
+                            standing={"count": 1, "template": "foo",
+                                      "distinct_nodes": True})
+    assert judge_v(run, cfg).correct
+    # a green pod deleted just before another lands on its node: the
+    # engine saw the deletion, or it would not have placed it there
+    run, cfg = affinity_run("g", {"default/g-0": (0, 101.0),
+                                  "default/g-1": (0, 105.0)},
+                            {"default/g-0": 104.9})
+    v = judge_v(run, cfg)
+    assert v.correct, v.rows
+
+
+def test_configurations_without_terms_judge_as_before():
+    # the same runs judged with InterPodAffinity in the profile and out of
+    # it: templates without terms add exactly 0
+    binds = {f"default/p-{i}": (i % 4, 101.0 + i // 4) for i in range(9)}
+    with_ipa = copy.deepcopy(CFG)
+    with_ipa["profile"]["plugins"].append("InterPodAffinity")
+    a = judge_v(make_run(binds), CFG, control=True)
+    b = judge_v(make_run(binds), with_ipa, control=True)
+    assert a.rows == b.rows

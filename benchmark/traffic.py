@@ -8,12 +8,17 @@ A traffic file names an arrival process and its parameters:
         open loop: exactly rate x seconds pods, due at Poisson arrival
         times (a fixed set of gaps, shuffled by the seed)
 
-and, for both, `churn` ("delete_oldest_per_bind": one bound pod, oldest
-first, is deleted per pod bound, so the population stays level) plus the
-warm-up it needs: `warmup_bursts` (pods created at once, one batch size
-each), `warmup_refresh` (bound pods on that many distinct nodes deleted
-and put back, so one batch meets that many changed nodes), then the
-traffic itself for `warmup_batches` batches and `warmup_s` seconds.
+and, for both, `churn`: "delete_oldest_per_bind" deletes one bound pod,
+oldest first (the standing population before any other), per pod bound,
+so the population stays level; "delete_oldest_incoming_per_bind"
+deletes, per pod bound, the oldest bound pod of the incoming template,
+so the standing population stays and the incoming one stays at what the
+warm-up's bursts bound (churn starts after them). Then the warm-up the
+traffic needs: `warmup_bursts` (pods created at once, one batch size
+each), `warmup_refresh` (bound standing pods on that many distinct nodes
+deleted and put back, each with its own template, so one batch meets
+that many changed nodes), then the traffic itself for `warmup_batches`
+batches and `warmup_s` seconds.
 
 The driver is one thread. It watches the store for binds (the only way
 it learns of them), deletes for churn, and creates the pods due. It
@@ -28,7 +33,7 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-from .workload import to_program_pod
+from .workload import check_template, program_pod, to_program_pod
 
 
 def poisson_offsets(rate: float, seconds: float, seed: int) -> np.ndarray:
@@ -49,18 +54,31 @@ def arrivals(traffic: dict, seconds: float, seed: int,
     return poisson_offsets(traffic["rate_per_s"] / scale, seconds, seed)
 
 
+CHURN = (None, "delete_oldest_per_bind", "delete_oldest_incoming_per_bind")
+
+
 class Driver:
     """Creates, watches and deletes pods on the cluster store."""
 
     def __init__(self, store, template: dict, traffic: dict,
-                 standing: Dict[str, str], standing_template: dict):
+                 standing: Dict[str, str], template_of: Dict[str, dict]):
         self.store = store
+        check_template(template)
         self.template = template
-        self.standing_template = standing_template
-        self.churn = traffic.get("churn") == "delete_oldest_per_bind"
+        # the template of every pod the driver binds itself (standing and
+        # put back), by key
+        self.template_of = template_of
+        churn = traffic.get("churn")
+        if churn not in CHURN:
+            raise ValueError(f"churn {churn!r}: one of {CHURN}")
+        self.churn = churn is not None
         # bound pods, oldest first: the standing population, then every
-        # pod the driver sees bound
+        # pod the driver sees bound (in `incoming` instead, where churn
+        # takes incoming pods only)
         self.fifo = collections.deque(standing)
+        self.incoming = (collections.deque()
+                         if churn == "delete_oldest_incoming_per_bind"
+                         else self.fifo)
         self.node_of = dict(standing)         # bound pod -> node
         self.binds: Dict[str, tuple] = {}     # key -> (node, bind stamp)
         self.rebinds = 0                      # bind transitions seen twice
@@ -152,7 +170,7 @@ class Driver:
 
     def _create(self, n: int, due) -> List[str]:
         t = self.template
-        pods = [to_program_pod(t, f"{t['name_prefix']}{self._seq + i}")
+        pods = [program_pod(t, f"{t['name_prefix']}{self._seq + i}")
                 for i in range(n)]
         self._seq += n
         self.store.create_many(pods)
@@ -165,7 +183,7 @@ class Driver:
 
     def _observe(self, evs) -> int:
         n = 0
-        binds, fifo, node_of = self.binds, self.fifo, self.node_of
+        binds, fifo, node_of = self.binds, self.incoming, self.node_of
         for ev in evs:
             if ev.type != "MODIFIED":
                 continue
@@ -183,7 +201,7 @@ class Driver:
         return n
 
     def _churn(self, n: int) -> None:
-        store, fifo, deleted = self.store, self.fifo, self.deleted
+        store, fifo, deleted = self.store, self.incoming, self.deleted
         for _ in range(min(n, len(fifo))):
             key = fifo.popleft()
             store.delete("Pod", key)
@@ -205,13 +223,14 @@ class Driver:
         return out
 
     def _put_back(self, gone: List[tuple]) -> None:
-        t = self.standing_template
+        ts = [self.template_of[key] for key, _node in gone]
         pods = [to_program_pod(t, "put-back-" + key.split("/", 1)[1], node)
-                for key, node in gone]
+                for t, (key, node) in zip(ts, gone)]
         self.store.create_many(pods)
         now = time.time()
-        for p in pods:
+        for p, t in zip(pods, ts):
             self.put_back[p.key] = (p.spec.node_name, now)
+            self.template_of[p.key] = t
             self.node_of[p.key] = p.spec.node_name
             self.fifo.append(p.key)
 
