@@ -40,7 +40,7 @@ class OverflowLog(list):
         super().__init__()
         self._seen: set = set()
         self._truncated = False
-        # Written from both the informer thread (account_bind → _anti_sigs)
+        # Written from both the informer thread (account_bind overflow)
         # and the scheduling thread (encode_pods) — the check-then-act
         # dedup must be atomic.
         self._applock = threading.Lock()
@@ -161,6 +161,53 @@ def step_bucket(n: int, minimum: int = 16) -> int:
     return base + step * -(-(n - base) // step)
 
 
+# Synthetic pair carrying an anti class on its owners' corpus label rows
+# (NUL-separated like features._OWNER_SPREAD_TAG: no real label pair can
+# forge it).
+_ANTI_CLASS_TAG = "minisched.io/anti\x00"
+
+
+def anti_class_key(term: "obj_mod.PodAffinityTerm", owner_ns: str) -> tuple:
+    """Identity of a required anti term's class: (topology key, namespace
+    set, selector). The namespace set defaults to the owner's namespace;
+    None (any namespace) for an owner without one, as a device group with
+    no namespace matches any."""
+    if term.namespaces:
+        ns = tuple(sorted(set(term.namespaces)))
+    else:
+        ns = (owner_ns,) if owner_ns else None
+    sel = term.label_selector
+    sel_sig = None if sel is None else (
+        tuple(sorted(sel.match_labels.items())),
+        tuple((r.key, r.operator, tuple(sorted(r.values)))
+              for r in sel.match_expressions))
+    return (term.topology_key, ns, sel_sig)
+
+
+class _AntiClass:
+    """One anti class of bound pods' required anti terms: its tag, how
+    many owner terms hold it, and how many owners found no corpus label
+    slot for the tag (the count plane under-counts those, so pods the
+    class matches fail closed)."""
+
+    __slots__ = ("tag", "topology_key", "namespaces", "selector", "refs",
+                 "untagged")
+
+    def __init__(self, key: tuple, term: "obj_mod.PodAffinityTerm"):
+        self.tag = F._h(_ANTI_CLASS_TAG + repr(key))
+        self.topology_key = key[0]
+        self.namespaces = None if key[1] is None else frozenset(key[1])
+        self.selector = term.label_selector
+        self.refs = 0
+        self.untagged = 0
+
+    def matches(self, ns: str, labels) -> bool:
+        """The class's term selects a pod in ``ns`` with ``labels``."""
+        if self.namespaces is not None and ns not in self.namespaces:
+            return False
+        return self.selector is None or self.selector.matches(labels)
+
+
 class NodeFeatureCache:
     """Thread-safe incrementally-maintained node feature arrays."""
 
@@ -212,13 +259,18 @@ class NodeFeatureCache:
         self._gang_bound: Dict[str, int] = {}
         self._key_gang: Dict[str, str] = {}
         # Required anti-affinity terms of RUNNING pods (upstream symmetric
-        # enforcement): sig=(key_idx, ns_hash, sel_pairs) → {node row:
-        # [owner priorities]} (a multiset — add/drop stay exact). Feeds
-        # anti_forbidden_for → encode.anti_forbid slots incl. the
-        # preemption-curability columns (owner row + max priority).
-        self._anti_terms: Dict[tuple, Dict[int, List[int]]] = {}
-        # pod key → (priority, sigs)
-        self._pod_anti: Dict[str, Tuple[int, List[tuple]]] = {}
+        # enforcement) as anti CLASSES: anti_class_key → _AntiClass,
+        # refcounted per owner term. Each owner's corpus label row
+        # carries its classes' tags, so the step counts a class's owners
+        # per domain as one more selector group (anti_classes_for).
+        self._anti_classes: Dict[tuple, _AntiClass] = {}
+        # owner pod key → [(class key, tagged)]
+        self._pod_anti: Dict[str, List[Tuple[tuple, bool]]] = {}
+        # bumped whenever a class appears, goes, or changes its
+        # fail-closed verdict: keys the per-signature match memo
+        self._anti_version = 0
+        self._anti_memo: Dict[tuple, tuple] = {}
+        self._anti_memo_version = 0
         # Owner-spread pairs in the assigned corpus's label rows
         # (SelectorSpread): OFF unless a profile actually runs the
         # plugin — the pair would otherwise fragment the bulk-rebuild
@@ -576,7 +628,7 @@ class NodeFeatureCache:
                     self._a_key[a] = None
                     self._a_free.append(a)
                 self._drop_gang_member(k)
-                self._anti_drop_locked(k, i)
+                self._anti_drop_locked(k)
             # Node removal is NARROWING for the index: the cleared row
             # re-evaluates to statically-infeasible (valid=False → NEG)
             # at the next refresh — an in-place repair, no rebuild.
@@ -800,7 +852,6 @@ class NodeFeatureCache:
         if group:
             self._key_gang[pod.key] = group
             self._gang_bound[group] = self._gang_bound.get(group, 0) + 1
-        self._anti_add_locked(pod, i)
 
         a = self._alloc_assigned_row()
         self._a_row[pod.key] = a
@@ -826,13 +877,16 @@ class NodeFeatureCache:
         # matches when ITS pairs are all present).
         opair = (F.owner_spread_pair(pod.metadata)
                  if self._owner_pairs else 0)
+        used = min(len(labels), self.cfg.max_labels)
         if opair:
-            if len(labels) < self.cfg.max_labels:
-                self._assigned.label_pairs[a, len(labels)] = opair
+            if used < self.cfg.max_labels:
+                self._assigned.label_pairs[a, used] = opair
+                used += 1
             else:
                 self.overflow.append(
                     f"assigned pod {pod.key}: no label slot left for the "
                     "owner spread pair; SelectorSpread under-counts it")
+        self._anti_add_locked(pod, a, used)
         return True
 
     def account_unbind(self, pod_key: str) -> None:
@@ -857,7 +911,7 @@ class NodeFeatureCache:
                 self._a_key[a] = None
                 self._a_free.append(a)
             self._drop_gang_member(pod_key)
-            self._anti_drop_locked(pod_key, i)
+            self._anti_drop_locked(pod_key)
             self.version += 1
 
     def _drop_gang_member(self, pod_key: str) -> None:
@@ -1122,79 +1176,110 @@ class NodeFeatureCache:
         with self._lock:
             return self._index.get(name)
 
-    # ---- symmetric anti-affinity table ----------------------------------
+    # ---- symmetric anti-affinity classes --------------------------------
 
     @staticmethod
     def _pod_has_anti(pod: Pod) -> bool:
         a = pod.spec.affinity
         return bool(a and a.pod_anti_affinity and a.pod_anti_affinity.required)
 
-    def _anti_sigs(self, pod: Pod) -> List[tuple]:
-        """Signatures of the pod's required anti terms, mirroring
-        encode.GroupBuilder's (key_idx, ns_hash, sorted sel pairs) —
-        the two sides must agree for symmetric matching to line up."""
+    def _anti_add_locked(self, pod: Pod, a: int, used: int) -> None:
+        """Refcount the classes of the pod's required anti terms and tag
+        its corpus row ``a`` with them, from label slot ``used`` on."""
         if not self._pod_has_anti(pod):
-            return []
-        ns_h = (F._h(pod.metadata.namespace)
-                if pod.metadata.namespace else 0)
-        sigs = []
-        for term in pod.spec.affinity.pod_anti_affinity.required:
-            key_idx = self.registry.index_of(term.topology_key, self.overflow)
-            # key_idx < 0 (registry full): the term's domains cannot be
-            # represented. Keep the signature with the sentinel key rather
-            # than dropping the term — anti_forbidden_for surfaces it as an
-            # unrepresentable (-1, -1) pair so the engine FAILS CLOSED for
-            # matching pods instead of silently permitting them.
-            # Multiple namespaces are exact here (host-side matching): one
-            # signature per namespace, each matched independently.
-            ns_list = ([F._h(n) for n in term.namespaces]
-                       if term.namespaces else [ns_h])
-            pairs: tuple = ()
-            if term.label_selector is not None:
-                raw = sorted(F.pair_hash(k, v) for k, v in
-                             term.label_selector.match_labels.items())
-                if len(raw) > self.cfg.max_term_selector_pairs:
-                    # Truncation BROADENS the match (repels more pods) —
-                    # the conservative direction for a hard constraint.
-                    self.overflow.append(
-                        f"anti-affinity term on {pod.key}: selector pairs "
-                        f"overflow ({len(raw)} > "
-                        f"{self.cfg.max_term_selector_pairs}); truncated")
-                pairs = tuple(raw[: self.cfg.max_term_selector_pairs])
-            for ns in ns_list:
-                sigs.append((key_idx, ns, pairs))
-        return sigs
-
-    def _anti_add_locked(self, pod: Pod, row: int) -> None:
-        sigs = self._anti_sigs(pod)
-        if sigs:
-            pri = int(pod.spec.priority)
-            self._pod_anti[pod.key] = (pri, sigs)
-            for sig in sigs:
-                rows = self._anti_terms.setdefault(sig, {})
-                # per-row multiset of owner priorities: O(distinct sigs)
-                # aggregation in anti_forbidden_for, exact max on drop
-                rows.setdefault(row, []).append(pri)
-
-    def _anti_drop_locked(self, pod_key: str, row: int) -> None:
-        entry = self._pod_anti.pop(pod_key, None)
-        if entry is None:
             return
-        pri, sigs = entry
-        for sig in sigs:
-            rows = self._anti_terms.get(sig)
-            if not rows:
+        entries: List[Tuple[tuple, bool]] = []
+        max_labels = self.cfg.max_labels
+        for term in pod.spec.affinity.pod_anti_affinity.required:
+            key = anti_class_key(term, pod.metadata.namespace)
+            if any(key == k for k, _t in entries):
+                continue  # a repeated term: one tag, one reference
+            cls = self._anti_classes.get(key)
+            if cls is None:
+                cls = self._anti_classes[key] = _AntiClass(key, term)
+                self._anti_version += 1
+            cls.refs += 1
+            tagged = used < max_labels
+            if tagged:
+                self._assigned.label_pairs[a, used] = cls.tag
+                used += 1
+            else:
+                cls.untagged += 1
+                self._anti_version += 1
+                self.overflow.append(
+                    f"assigned pod {pod.key}: no label slot left for an "
+                    "anti-affinity class tag; pods it repels fail closed")
+            entries.append((key, tagged))
+        self._pod_anti[pod.key] = entries
+
+    def _anti_drop_locked(self, pod_key: str) -> None:
+        entries = self._pod_anti.pop(pod_key, None)
+        if not entries:
+            return
+        for key, tagged in entries:
+            cls = self._anti_classes.get(key)
+            if cls is None:
                 continue
-            pris = rows.get(row)
-            if pris:
-                try:
-                    pris.remove(pri)
-                except ValueError:
-                    pass
-                if not pris:
-                    rows.pop(row, None)
-            if not rows:
-                self._anti_terms.pop(sig, None)
+            cls.refs -= 1
+            if not tagged:
+                cls.untagged -= 1
+                self._anti_version += 1
+            if cls.refs <= 0:
+                del self._anti_classes[key]
+                self._anti_version += 1
+
+    def anti_class_count(self) -> int:
+        """Live anti classes: bound pods hold required anti terms of
+        that many distinct classes (a lock-free read of one dict's
+        size)."""
+        return len(self._anti_classes)
+
+    def anti_classes_for(self, pod: Pod
+                         ) -> Tuple[Tuple[Tuple[int, int], ...],
+                                    Optional[str]]:
+        """The anti classes whose term matches ``pod`` (upstream
+        existing-pod anti-affinity symmetry: the pod's namespace is in
+        the class's namespace set and its labels satisfy the class's
+        selector, match expressions included), as (topology key index,
+        tag) pairs the encoder stamps as selector groups; and a
+        fail-closed reason, or None. A matched class whose topology key
+        cannot be registered, or whose owners are not all tagged, makes
+        the count plane blind to it: the pod must fail closed.
+        Memoized per (namespace, labels) until the class table changes,
+        so the matching runs once per pod signature."""
+        with self._lock:
+            if not self._anti_classes:
+                return (), None
+            if self._anti_memo_version != self._anti_version:
+                self._anti_memo.clear()
+                self._anti_memo_version = self._anti_version
+            ns, labels = pod.metadata.namespace, pod.metadata.labels
+            memo_key = (ns, tuple(labels.items()))
+            hit = self._anti_memo.get(memo_key)
+            if hit is not None:
+                return hit
+            out: List[Tuple[int, int]] = []
+            reason: Optional[str] = None
+            for cls in self._anti_classes.values():
+                if not cls.matches(ns, labels):
+                    continue
+                k_idx = self.registry.index_of(cls.topology_key,
+                                               self.overflow)
+                if k_idx < 0:
+                    reason = ("a running pod's matching anti-affinity term "
+                              "has an unrepresentable topology key "
+                              "(registry full); failing closed")
+                    continue
+                if cls.untagged:
+                    reason = ("a running pod whose anti-affinity term "
+                              "matches has no corpus slot for its class "
+                              "tag; failing closed")
+                out.append((k_idx, cls.tag))
+            res = (tuple(out), reason)
+            if len(self._anti_memo) >= 4096:
+                self._anti_memo.clear()
+            self._anti_memo[memo_key] = res
+            return res
 
     def victims_below(self, node_name: str, priority: int) -> List[tuple]:
         """Bound pods on ``node_name`` with priority STRICTLY below
@@ -1238,26 +1323,22 @@ class NodeFeatureCache:
     def repelling_owners_on(self, node_name: str, pod: Pod) -> List[str]:
         """Keys of bound pods ON ``node_name`` whose required
         anti-affinity term matches ``pod`` (the symmetric existing-pod
-        direction) — preemption's mandatory victim set for curing an
-        anti_forbid slot at that node (ops/preempt.py). Term semantics
-        mirror anti_forbidden_for."""
+        direction) — preemption's mandatory victim set for curing a
+        class the pod is stamped with (ops/preempt.py). Term semantics
+        mirror anti_classes_for."""
         with self._lock:
             i = self._index.get(node_name)
             if i is None or not self._pod_anti:
                 return []
-            ns_h = (F._h(pod.metadata.namespace)
-                    if pod.metadata.namespace else 0)
-            labels = {F.pair_hash(k, v)
-                      for k, v in pod.metadata.labels.items()}
+            ns, labels = pod.metadata.namespace, pod.metadata.labels
             out: List[str] = []
-            for owner_key, (_pri, sigs) in self._pod_anti.items():
+            for owner_key, entries in self._pod_anti.items():
                 entry = self._bound.get(owner_key)
                 if entry is None or entry[0] != i:
                     continue
-                for (_key_idx, ns, pairs) in sigs:
-                    if ns != 0 and ns != ns_h:
-                        continue
-                    if all(p in labels for p in pairs):
+                for key, _tagged in entries:
+                    cls = self._anti_classes.get(key)
+                    if cls is not None and cls.matches(ns, labels):
                         out.append(owner_key)
                         break
             return out
@@ -1267,65 +1348,6 @@ class NodeFeatureCache:
         with self._lock:
             i = self._index.get(node_name)
             return None if i is None else self._feats.free[i].copy()
-
-    def anti_forbidden_for(self, pod: Pod
-                           ) -> List[Tuple[int, int, int, int]]:
-        """(key_idx, domain, owner_row, owner_maxpri) entries the pod must
-        avoid: domains holding a RUNNING pod whose required anti-affinity
-        term matches this pod (upstream existing-pod anti-affinity
-        symmetry; term semantics mirror the device side: empty selector =
-        match-all, term namespace defaults to the owner pod's). Feeds
-        encode.anti_forbid slots via the engine's encode callback.
-
-        The two trailing fields feed preemption curability
-        (ops/preempt.py): ``owner_row`` is the single node row holding
-        EVERY owner of the (key, domain) entry, or -1 when owners span
-        nodes — upstream DefaultPreemption evicts node-local victims
-        only, so a multi-node ownership cannot be cured;
-        ``owner_maxpri`` is the highest owner priority (a preemptor must
-        outrank every owner). The sentinel entry is (-1, -1, -1, 0)."""
-        with self._lock:
-            if not self._anti_terms:
-                return []
-            self._refresh_topology_locked()
-            ns_h = (F._h(pod.metadata.namespace)
-                    if pod.metadata.namespace else 0)
-            labels = {F.pair_hash(k, v)
-                      for k, v in pod.metadata.labels.items()}
-            # (key_idx, dom) → [single_row_or_-1, max_priority]
-            agg: Dict[Tuple[int, int], list] = {}
-            sentinel = False
-            for (key_idx, ns, pairs), rows in self._anti_terms.items():
-                # ns 0 = any-namespace wildcard, mirroring the device
-                # group convention (a term owner with no namespace).
-                if ns != 0 and ns != ns_h:
-                    continue
-                if not all(p in labels for p in pairs):
-                    continue
-                if key_idx < 0:
-                    # Unrepresentable term (registry was full when its
-                    # owner bound): forbidden domains unknown — emit the
-                    # sentinel so the engine fails closed.
-                    sentinel = True
-                    continue
-                for row, pris in rows.items():
-                    dom = int(self._feats.topo_domains[key_idx, row])
-                    if dom < 0 or not pris:
-                        continue
-                    pri = max(pris)
-                    cur = agg.get((key_idx, dom))
-                    if cur is None:
-                        agg[(key_idx, dom)] = [row, pri]
-                    else:
-                        if cur[0] != row:
-                            cur[0] = -1  # owners span nodes: incurable
-                        cur[1] = max(cur[1], pri)
-            out: List[Tuple[int, int, int, int]] = []
-            if sentinel:
-                out.append((-1, -1, -1, 0))
-            for (key_idx, dom), (row, pri) in agg.items():
-                out.append((key_idx, dom, row, pri))
-            return out
 
     # ---- internals ------------------------------------------------------
 

@@ -84,10 +84,16 @@ class EncodingConfig:
     max_term_selector_pairs: int = 4  # match_labels pairs per term selector
     domain_buckets: int = 4096   # hashed domain space for non-hostname keys
     max_pod_claims: int = 4      # PVC references per pod (volume plugins)
-    # forbidden (topology key, domain) slots per pod: domains occupied by a
-    # RUNNING pod whose required anti-affinity term matches this pod
-    # (upstream existing-pod anti-affinity symmetry)
-    max_anti_forbid: int = 4
+    # namespaces per term selector group (pod-(anti-)affinity terms that
+    # name several namespaces); a batch pads to the pow2 bucket of its
+    # widest group, so single-namespace batches carry one slot
+    max_term_namespaces: int = 4
+    # anti classes per pod: distinct (topology key, namespaces, selector)
+    # of RUNNING pods' required anti terms that match this pod (upstream
+    # existing-pod anti-affinity symmetry, cache.anti_classes_for); a
+    # batch pads to the pow2 bucket of its most-repelled pod, 0 when
+    # none is repelled
+    max_anti_classes: int = 8
 
 
 # Spread when_unsatisfiable codes.
@@ -178,10 +184,10 @@ class TopologyKeyRegistry:
         self._keys = [HOSTNAME_KEY]
         self._idx = {HOSTNAME_KEY: 0}
         self.version = 1
-        # The registry is reached from both the informer thread
-        # (cache.account_bind → _anti_sigs) and the scheduling thread
-        # (encode_pods / GroupBuilder); the two-step insert below must not
-        # interleave or a key is permanently mapped to the wrong slot.
+        # The registry is reached from several threads (encode_pods /
+        # GroupBuilder and cache.anti_classes_for on the scheduling
+        # thread, snapshots on others); the two-step insert below must
+        # not interleave or a key is permanently mapped to the wrong slot.
         self._lock = threading.Lock()
 
     def index_of(self, key: str, overflow: Optional[List[str]] = None) -> int:
@@ -333,16 +339,12 @@ class PodFeatures(NamedTuple):
     anti_req_group: np.ndarray   # (P,T) i32 required anti-affinity terms
     anti_pref_group: np.ndarray  # (P,T) i32 preferred anti-affinity terms
     anti_pref_weight: np.ndarray  # (P,T) f32
-    # Symmetric existing-pod anti-affinity (upstream parity): domains this
-    # pod must avoid because a RUNNING pod's required anti term matches it.
-    anti_forbid_key: np.ndarray  # (P,S) i32 topology-key idx, -1 unused
-    anti_forbid_dom: np.ndarray  # (P,S) i32 domain id under that key
-    # Preemption curability of the slot (ops/preempt.py): the single node
-    # row holding ALL owners of the forbidding term(s), -1 when owners
-    # span nodes (then no node-local eviction can cure it), and the max
-    # owner priority (a preemptor must outrank every owner to evict).
-    anti_forbid_row: np.ndarray     # (P,S) i32
-    anti_forbid_maxpri: np.ndarray  # (P,S) i32
+    # Symmetric existing-pod anti-affinity (upstream parity): selector
+    # groups counting the owners of each anti CLASS whose term matches
+    # this pod (cache.anti_classes_for). A class group's count in a
+    # node's domain is upstream's existingAntiAffinityCounts: > 0 forbids
+    # the node. S is the batch's bucket (0 when no pod is repelled).
+    anti_cls_group: np.ndarray   # (P,S) i32 group index, -1 = unused slot
 
 
 class GroupFeatures(NamedTuple):
@@ -352,7 +354,9 @@ class GroupFeatures(NamedTuple):
 
     valid: np.ndarray      # (G,) bool
     key_idx: np.ndarray    # (G,) i32 topology-key registry index
-    ns_hash: np.ndarray    # (G,) i32 namespace restriction (0 = any)
+    ns_set: np.ndarray     # (G,NS) i32 namespace hashes, 0 = unused slot;
+    #                        a pod matches when its namespace is in the
+    #                        set, an all-zero row matches any namespace
     sel_pairs: np.ndarray  # (G,QT) i32 ANDed selector pair hashes (all-zero
     #                        with valid=True means match-all, upstream empty
     #                        selector semantics)
@@ -540,8 +544,15 @@ def _encode_term_exprs(op_row, key_row, val_row, exprs, overflow, what):
         val_row[e, :min(len(vals), v_max)] = vals[:v_max]
 
 
+def ns_set_of(hashes) -> Tuple[int, ...]:
+    """Canonical namespace set of a selector group: the sorted distinct
+    non-zero namespace hashes; () matches any namespace (a term owner
+    with no namespace, or a class group counting owners anywhere)."""
+    return tuple(sorted({int(h) for h in hashes if h}))
+
+
 class GroupBuilder:
-    """Dedupes (topology key index, namespace hash, selector pairs) tuples
+    """Dedupes (topology key index, namespace set, selector pairs) tuples
     into stable group ids for one batch."""
 
     def __init__(self, cfg: EncodingConfig = DEFAULT_ENCODING):
@@ -562,14 +573,16 @@ class GroupBuilder:
         # encoding a HARD constraint must then fail the pod closed.
         self.last_weakened = False
 
-    def group_of(self, key_idx: int, ns_hash: int, selector,
+    def group_of(self, key_idx: int, ns_set: Tuple[int, ...], selector,
                  overflow: Optional[List[str]], what: str) -> int:
+        """Group id of a (key, namespace set, selector) tuple; ``ns_set``
+        comes from ns_set_of."""
         self.last_weakened = False
         if key_idx < 0:
             return -1
         obj_key = None
         if selector is not None:
-            obj_key = (key_idx, ns_hash, id(selector))
+            obj_key = (key_idx, ns_set, id(selector))
             hit = self._by_obj.get(obj_key)
             if hit is not None:
                 gid, self.last_weakened = hit
@@ -590,7 +603,7 @@ class GroupBuilder:
                 raw = raw[: self.cfg.max_term_selector_pairs]
                 self.last_weakened = True
             pairs = tuple(raw)
-        sig = (key_idx, ns_hash, pairs)
+        sig = (key_idx, ns_set, pairs)
         gid = self._groups.get(sig)
         if gid is None:
             gid = len(self._groups)
@@ -599,15 +612,15 @@ class GroupBuilder:
             self._by_obj[obj_key] = (gid, self.last_weakened)
         return gid
 
-    def group_of_pairs(self, key_idx: int, ns_hash: int,
+    def group_of_pairs(self, key_idx: int, ns_set: Tuple[int, ...],
                        pairs: Tuple[int, ...]) -> int:
         """Group id for an already-hashed selector-pair tuple (the
-        SelectorSpread owner pair) — same dedup space as group_of, so an
-        owner group and a label-selector group with identical signatures
-        correctly share one id."""
+        SelectorSpread owner pair, an anti class's tag) — same dedup
+        space as group_of, so an owner group and a label-selector group
+        with identical signatures correctly share one id."""
         if key_idx < 0:
             return -1
-        sig = (key_idx, ns_hash, tuple(pairs))
+        sig = (key_idx, ns_set, tuple(pairs))
         gid = self._groups.get(sig)
         if gid is None:
             gid = len(self._groups)
@@ -619,16 +632,21 @@ class GroupBuilder:
         target = pad if pad is not None else max(8, _next_pow2(n))
         if n > target:
             raise ValueError(f"{n} groups > pad {target}")
+        # Namespace slots: the pow2 bucket of the widest set (callers
+        # cap a set at max_term_namespaces), so a batch of
+        # single-namespace groups compiles the one-slot compare.
+        ns_w = _next_pow2(max((len(ns) for _k, ns, _p in self._groups),
+                              default=1))
         gf = GroupFeatures(
             valid=np.zeros(target, dtype=bool),
             key_idx=np.zeros(target, dtype=np.int32),
-            ns_hash=np.zeros(target, dtype=np.int32),
+            ns_set=np.zeros((target, ns_w), dtype=np.int32),
             sel_pairs=np.zeros((target, self.cfg.max_term_selector_pairs),
                                dtype=np.int32))
-        for (key_idx, ns_hash, pairs), gid in self._groups.items():
+        for (key_idx, ns_set, pairs), gid in self._groups.items():
             gf.valid[gid] = True
             gf.key_idx[gid] = key_idx
-            gf.ns_hash[gid] = ns_hash
+            gf.ns_set[gid, :len(ns_set)] = ns_set
             gf.sel_pairs[gid, :len(pairs)] = pairs
         return gf
 
@@ -736,17 +754,24 @@ def _encode_pod_affinity_terms(i, terms, group_arr, weight_arr, builder,
                                anti: bool = False) -> bool:
     """Encode PodAffinityTerm list (plain or weighted) into group slots.
 
+    A term's ``namespaces`` list is kept exactly up to
+    EncodingConfig.max_term_namespaces (the group matches a pod whose
+    namespace is in the set); an empty list means the pod's own.
+    ``namespaceSelector`` is out of scope (Kubernetes v1.22 has it only
+    behind a feature gate).
+
     Returns True when a REQUIRED term (weight_arr is None) was weakened in
     the UNSAFE direction, so the caller can fail the pod closed rather
     than schedule it against a silently loosened hard constraint.
     Direction matters: an AFFINITY term admits placements, so any
     broadening (selector pairs truncated, match_expressions dropped) or
-    whole-term loss is unsafe, while narrowing to namespaces[0] can only
-    reject valid placements (safe). An ANTI term repels placements, so
-    broadening the selector over-repels (safe) while narrowing it —
-    multi-namespace truncation or losing the term entirely — under-repels
-    (unsafe)."""
+    whole-term loss is unsafe, while keeping only the first
+    max_term_namespaces namespaces can only reject valid placements
+    (safe). An ANTI term repels placements, so broadening the selector
+    over-repels (safe) while narrowing it — namespaces beyond the slot
+    width or losing the term entirely — under-repels (unsafe)."""
     T = group_arr.shape[1]
+    ns_max = builder.cfg.max_term_namespaces
     hard_dropped = False
     if len(terms) > T:
         if overflow is not None:
@@ -761,16 +786,19 @@ def _encode_pod_affinity_terms(i, terms, group_arr, weight_arr, builder,
             weight = None
         k_idx = registry.index_of(term.topology_key, overflow)
         if term.namespaces:
-            if len(term.namespaces) > 1:
+            names = sorted(set(term.namespaces))
+            if len(names) > ns_max:
                 if overflow is not None:
-                    overflow.append(
-                        f"{what}: multiple namespaces unsupported")
-                if weight_arr is None and anti:  # anti under-repels ns[1:]
+                    overflow.append(f"{what}: {len(names)} namespaces > "
+                                    f"{ns_max} slots")
+                if weight_arr is None and anti:  # anti under-repels
                     hard_dropped = True
-            ns = _h(term.namespaces[0])
+                names = names[:ns_max]
+            ns_set = ns_set_of(_h(n) for n in names)
         else:
-            ns = pod_ns_hash
-        group_arr[i, t] = builder.group_of(k_idx, ns, term.label_selector,
+            ns_set = ns_set_of((pod_ns_hash,))
+        group_arr[i, t] = builder.group_of(k_idx, ns_set,
+                                           term.label_selector,
                                            overflow, what)
         if weight_arr is None:
             if group_arr[i, t] < 0:
@@ -780,7 +808,7 @@ def _encode_pod_affinity_terms(i, terms, group_arr, weight_arr, builder,
         if weight is not None and group_arr[i, t] >= 0:
             weight_arr[i, t] = float(weight)
         if self_arr is not None and group_arr[i, t] >= 0:
-            self_arr[i, t] = (ns == pod_ns_hash
+            self_arr[i, t] = ((not ns_set or pod_ns_hash in ns_set)
                               and (term.label_selector is None
                                    or term.label_selector.matches(pod_labels or {})))
     return hard_dropped
@@ -899,8 +927,7 @@ _PROTO_COPY_FIELDS = (
     "spread_group", "spread_max_skew", "spread_mode", "selspread_group",
     "aff_req_group", "aff_req_self", "aff_pref_group", "aff_pref_weight",
     "anti_req_group", "anti_pref_group", "anti_pref_weight",
-    "anti_forbid_key", "anti_forbid_dom", "anti_forbid_row",
-    "anti_forbid_maxpri")
+    "anti_cls_group")
 
 
 def encode_pods(pods: List[Pod], p_pad: int,
@@ -911,7 +938,7 @@ def encode_pods(pods: List[Pod], p_pad: int,
                 group_pad: Optional[int] = None,
                 gang_bound_fn=None,
                 volume_info_fn=None,
-                anti_forbidden_fn=None,
+                anti_classes_fn=None,
                 hard_failed: Optional[Dict[int, List[Tuple[str, str]]]] = None,
                 selector_spread: bool = False):
     """Encode a batch of pending pods, padded to ``p_pad`` rows.
@@ -924,9 +951,12 @@ def encode_pods(pods: List[Pod], p_pad: int,
     ``volume_info_fn(pod) -> (claim_rows, zone_key_idx, zone_dom)`` supplies
     the VolumeRestrictions / VolumeZone inputs (engine resolves them from
     the store + node cache) — default: unrestricted, no zone requirement.
-    ``anti_forbidden_fn(pod) -> [(key_idx, dom_id), ...]`` supplies domains
-    occupied by RUNNING pods whose required anti-affinity terms match this
-    pod (cache.anti_forbidden_for) — default: none.
+    ``anti_classes_fn(pod) -> ([(key_idx, tag), ...], reason)`` supplies
+    the anti classes of RUNNING pods whose required anti-affinity terms
+    match this pod (cache.anti_classes_for), each stamped as a selector
+    group over its tag; ``reason`` (or more classes than
+    max_anti_classes) fails the pod closed — default: none. Called once
+    per pod signature.
     ``hard_failed`` (optional out-param): pod index → list of
     (plugin name, reason) — one entry per tripped constraint —
     for pods whose HARD constraint (required affinity/anti-affinity term,
@@ -986,11 +1016,10 @@ def encode_pods(pods: List[Pod], p_pad: int,
         anti_req_group=np.full((P, T), -1, dtype=np.int32),
         anti_pref_group=np.full((P, T), -1, dtype=np.int32),
         anti_pref_weight=np.zeros((P, T), dtype=np.float32),
-        anti_forbid_key=np.full((P, cfg.max_anti_forbid), -1, dtype=np.int32),
-        anti_forbid_dom=np.full((P, cfg.max_anti_forbid), -1, dtype=np.int32),
-        anti_forbid_row=np.full((P, cfg.max_anti_forbid), -1, dtype=np.int32),
-        anti_forbid_maxpri=np.zeros((P, cfg.max_anti_forbid), dtype=np.int32),
+        anti_cls_group=np.full((P, cfg.max_anti_classes), -1,
+                               dtype=np.int32),
     )
+    anti_slots = 0  # most anti classes stamped on one pod
     gang_group = np.full(P, -1, dtype=np.int32)
     gang_ids: Dict[str, int] = {}
     gang_mins: List[int] = []
@@ -1063,8 +1092,8 @@ def encode_pods(pods: List[Pod], p_pad: int,
             if selector_spread:
                 opair = owner_spread_pair(pod.metadata)
                 if opair:
-                    ns_h0 = (_h(pod.metadata.namespace)
-                             if pod.metadata.namespace else 0)
+                    ns_h0 = ns_set_of((_h(pod.metadata.namespace)
+                                       if pod.metadata.namespace else 0,))
                     # hostname is registry slot 0 by construction; the
                     # zone term only engages when the key registers
                     f.selspread_group[i, 0] = builder.group_of_pairs(
@@ -1113,7 +1142,8 @@ def encode_pods(pods: List[Pod], p_pad: int,
                            f"{C} encoder slots")
         for c, tsc in enumerate(cons[:C]):
             k_idx = registry.index_of(tsc.topology_key, overflow)
-            gid = builder.group_of(k_idx, ns_h, tsc.label_selector, overflow,
+            gid = builder.group_of(k_idx, ns_set_of((ns_h,)),
+                                   tsc.label_selector, overflow,
                                    f"pod {pod.key} spread[{c}]")
             hard = tsc.when_unsatisfiable == "DoNotSchedule"
             if gid < 0:
@@ -1145,21 +1175,21 @@ def encode_pods(pods: List[Pod], p_pad: int,
             _encode_pod_affinity_terms(
                 i, pa.preferred, f.aff_pref_group, f.aff_pref_weight, builder,
                 registry, ns_h, overflow, f"pod {pod.key} podAffinity.preferred")
-        if anti_forbidden_fn is not None:
-            pairs = anti_forbidden_fn(pod)
-            if len(pairs) > cfg.max_anti_forbid and overflow is not None:
-                overflow.append(
-                    f"pod {pod.key} anti-affinity forbidden domains: "
-                    f"{len(pairs)} > {cfg.max_anti_forbid} slots")
-            for s, entry in enumerate(pairs[:cfg.max_anti_forbid]):
-                # (key, dom) legacy pairs or (key, dom, owner_row,
-                # owner_maxpri) — the extended form feeds preemption
-                # curability (ops/preempt.py).
-                f.anti_forbid_key[i, s] = entry[0]
-                f.anti_forbid_dom[i, s] = entry[1]
-                if len(entry) >= 4:
-                    f.anti_forbid_row[i, s] = entry[2]
-                    f.anti_forbid_maxpri[i, s] = entry[3]
+        if anti_classes_fn is not None:
+            classes, reason = anti_classes_fn(pod)
+            if reason:
+                _mark_hard(i, "InterPodAffinity", reason)
+            S = cfg.max_anti_classes
+            if len(classes) > S:
+                _mark_hard(i, "InterPodAffinity",
+                           f"pod is repelled by {len(classes)} anti-affinity "
+                           f"classes of running pods, more than the {S} "
+                           "encoder slots; failing closed")
+            for s, (k_idx, tag) in enumerate(classes[:S]):
+                # a class group counts its owners anywhere: no namespace
+                f.anti_cls_group[i, s] = builder.group_of_pairs(
+                    k_idx, (), (tag,))
+            anti_slots = max(anti_slots, min(len(classes), S))
 
         anti = aff.pod_anti_affinity if aff else None
         if anti:
@@ -1192,6 +1222,11 @@ def encode_pods(pods: List[Pod], p_pad: int,
         # replacement member of a live gang can still schedule.
         for group, gid in gang_ids.items():
             gang_mins[gid] = max(0, gang_mins[gid] - int(gang_bound_fn(group)))
+    # Slot bucket: 0 keeps a batch nobody is repelled in free of the
+    # class filter; else the pow2 bucket of the most-repelled pod.
+    S_b = _next_pow2(anti_slots) if anti_slots else 0
+    f = f._replace(anti_cls_group=np.ascontiguousarray(
+        f.anti_cls_group[:, :S_b]))
     GG = _next_pow2(max(len(gang_mins), 8))
     gang = GangFeatures(
         group=gang_group,

@@ -392,8 +392,12 @@ class _DeviceResidency:
                                            eng._nf_sharding("free"))
             self.ports_dev = jax.device_put(ports_np,
                                             eng._nf_sharding("used_ports"))
-            # The snapshot copies are private — they become the mirrors.
-            self.mirror_free, self.mirror_ports = free_np, ports_np
+            # The mirrors are copies of the snapshot: on the CPU backend
+            # device_put may alias an aligned host buffer, and the host
+            # replay (note_debits / note_ports) writes the mirrors in
+            # place — into the device array too, were they shared.
+            self.mirror_free, self.mirror_ports = (free_np.copy(),
+                                                   ports_np.copy())
             self.pad = int(free_np.shape[0])
             self.epoch = self.listener.epoch
             self.pending_rows = self.pending_pre = None
@@ -783,8 +787,8 @@ def arbitrate_rwo(batch: List[QueuedPodInfo], assigned, chosen,
 def batch_group_match(batch: List[QueuedPodInfo], gf) -> np.ndarray:
     """(P_live, G) bool: batch pod i's namespace+labels match selector
     group g — the HOST twin of ops.topology.group_assigned_match (same
-    hash functions, same all-zero-selector = match-all and ns_hash 0 =
-    any-namespace semantics), evaluated over the batch pods themselves
+    hash functions, same all-zero-selector = match-all and namespace-set
+    semantics), evaluated over the batch pods themselves
     (their labels are host objects; the device only encodes groups).
     Label-pair rows are memoized per distinct signature — a deployment's
     replicas share one."""
@@ -793,7 +797,8 @@ def batch_group_match(batch: List[QueuedPodInfo], gf) -> np.ndarray:
     P, G = len(batch), gf.valid.shape[0]
     sel = np.asarray(gf.sel_pairs, dtype=np.int64)   # (G,QT)
     gvalid = np.asarray(gf.valid)
-    gns = np.asarray(gf.ns_hash, dtype=np.int64)
+    gns = np.asarray(gf.ns_set, dtype=np.int64)      # (G,NS), 0 unused
+    ns_any = (gns == 0).all(axis=1)
     ns_memo: Dict[str, int] = {}
     # per distinct label signature: the (G,) selector-match row
     sig_memo: Dict[tuple, np.ndarray] = {}
@@ -812,7 +817,8 @@ def batch_group_match(batch: List[QueuedPodInfo], gf) -> np.ndarray:
         if nsv is None:
             nsv = ns_memo[pod.metadata.namespace] = (
                 F._h(pod.metadata.namespace) if pod.metadata.namespace else 0)
-        match[i] = gvalid & ((gns == 0) | (gns == nsv)) & sel_ok
+        match[i] = gvalid & (ns_any | ((gns == nsv) & (gns != 0)).any(
+            axis=1)) & sel_ok
     return match
 
 
@@ -853,7 +859,8 @@ def arbitrate_spread(batch: List[QueuedPodInfo], assigned, pf, gf,
                      spread_pre, spread_dom, spread_min,
                      dead: Set[int], anti_enabled: bool = True,
                      exact_tables=None,
-                     scan_enforced=None) -> Set[int]:
+                     scan_enforced=None,
+                     anti_revoked: Optional[Set[int]] = None) -> Set[int]:
     """Intra-batch topology arbitration → additional revoked indices.
 
     Every batch pod was filtered/scored against PRE-batch topology counts,
@@ -897,7 +904,11 @@ def arbitrate_spread(batch: List[QueuedPodInfo], assigned, pf, gf,
     the host replay is skipped for them, and a batch whose hard groups
     are all scan-enforced never calls ``exact_tables`` at all (the
     (G,D) transfer exists solely to rebuild the running state the scan
-    already had)."""
+    already had).
+
+    ``anti_revoked`` (optional out-param) receives the revoked pods whose
+    own verdict was a required anti-affinity conflict with an earlier
+    pod of the batch (not a gang cascade or a skew violation)."""
     from ..encode import features as F
 
     if spread_pre.shape[0] == 0:
@@ -982,6 +993,7 @@ def arbitrate_spread(batch: List[QueuedPodInfo], assigned, pf, gf,
                 build_state(g)
 
         revoked: Set[int] = set()
+        anti_viol.clear()
         for i in range(P):
             if not assigned[i] or i in dead_all:
                 continue
@@ -1022,6 +1034,8 @@ def arbitrate_spread(batch: List[QueuedPodInfo], assigned, pf, gf,
                         if d >= 0 and anti_delta.get((int(g), d), 0) > 0:
                             viol = True
                             break
+                if viol:
+                    anti_viol.add(i)
             if viol:
                 revoked.add(i)
                 # this pod's admission WAS in the scan's running counts —
@@ -1056,6 +1070,7 @@ def arbitrate_spread(batch: List[QueuedPodInfo], assigned, pf, gf,
     # number of gangs; a batch with no gang revocations exits after one
     # pass, identical to the single-walk behavior).
     extra: Set[int] = set()
+    anti_viol: Set[int] = set()  # the last walk's anti verdicts
     while True:
         revoked = _walk(dead | extra) | extra
         gangs = {gang_key(batch[i].pod) for i in revoked
@@ -1064,6 +1079,8 @@ def arbitrate_spread(batch: List[QueuedPodInfo], assigned, pf, gf,
                    if (assigned[i] and i not in dead and i not in revoked
                        and gang_key(qpi.pod) in gangs)}
         if not cascade:
+            if anti_revoked is not None:
+                anti_revoked.update(anti_viol)
             return revoked
         extra = revoked | cascade
 
@@ -1228,9 +1245,11 @@ class Scheduler:
             p.name in ("PodTopologySpread", "InterPodAffinity")
             for p in plugin_set.plugins)
         # Symmetric existing-pod anti-affinity is enforced by the
-        # InterPodAffinity filter via encode.anti_forbid slots.
+        # InterPodAffinity filter over the anti-class count plane
+        # (encode.anti_cls_group, cache.anti_classes_for).
         self._anti_enabled = any(p.name == "InterPodAffinity"
                                  for p in plugin_set.plugins)
+        self._anti_class_slots = 0  # metrics gauge, set per encode
         # SelectorSpread consumes owner-derived selector groups; encoding
         # them is gated on the profile so batches never grow the group
         # axis (and the (G,N) topology tables) for a plugin nobody runs.
@@ -1420,6 +1439,9 @@ class Scheduler:
         self._metrics: Dict[str, float] = {
             "batches": 0, "pods_seen": 0, "pods_assigned": 0,
             "pods_failed": 0, "pods_bound": 0, "bind_conflicts": 0,
+            # pods arbitrate_spread revoked for a required anti-affinity
+            # conflict with an earlier pod of their own batch
+            "anti_revoked_total": 0,
             # Fleet bind fencing: commits withheld because this replica
             # lost the pod's shard lease between decision and commit
             # (the pod is handed back; the new owner re-gathers it).
@@ -2793,8 +2815,10 @@ class Scheduler:
         shares tranche-wide), no volumes (RWO arbitration + claim-table
         accounting are host-side), no host ports (the cache's bulk
         assume debits port pods out of pod order, which would break the
-        bitwise mirror-vs-truth validation), and no owner references
-        when SelectorSpread runs (owner groups read the corpus too).
+        bitwise mirror-vs-truth validation), no owner references
+        when SelectorSpread runs (owner groups read the corpus too), and
+        no pod a running pod's anti class repels (its class slots read
+        the corpus and set the pod rows' width batch by batch).
         The per-pod walk is shared with the maintained arbitration
         index (_ring_safe_pods — the same host-state-independence
         property gates both fast paths). A batch the per-batch path
@@ -2823,6 +2847,14 @@ class Scheduler:
                 return False
             if self._selspread_enabled and pod.metadata.owner_references:
                 return False
+        # A pod that running pods' anti classes repel carries class
+        # slots read off the corpus, their width set batch by batch: no
+        # fixed ring layout. Pods no class matches keep the fast paths
+        # (the match is memoized per pod signature).
+        if self._anti_enabled and self.cache.anti_class_count():
+            for q in batch:
+                if self.cache.anti_classes_for(q.pod) != ((), None):
+                    return False
         return True
 
     def _tenant_fusable(self, batch: List[QueuedPodInfo], hard_spread: bool,
@@ -3191,8 +3223,9 @@ class Scheduler:
         encode callbacks share it via the returned per-batch memo.
         ``fail_closed`` maps pod key → (plugin, reason) for pods whose
         required anti-affinity/affinity term or DoNotSchedule spread
-        constraint cannot fit the encoding slots (or whose forbidden
-        domains exceed the anti_forbid slots) — they must be rejected
+        constraint cannot fit the encoding slots (or whom running pods'
+        anti classes repel beyond what the count plane can judge) —
+        they must be rejected
         after the step rather than scheduled against a silently
         weakened constraint. Only constraints this profile's plugin set
         actually ENFORCES fail closed: a profile without
@@ -3210,27 +3243,11 @@ class Scheduler:
 
         fail_closed: Dict[str, tuple] = {}  # pod key → (plugin, reason)
         anti_fn = None
-        if self._anti_enabled:
-            max_forbid = self.cache.cfg.max_anti_forbid
-
-            def anti_fn(pod: Pod) -> List[tuple]:
-                pairs = self.cache.anti_forbidden_for(pod)
-                if any(entry[0] < 0 for entry in pairs):
-                    # (-1, -1) sentinel: a running pod's matching anti
-                    # term has an unregistrable topology key — permanent
-                    # until that pod leaves, not a domain-count problem.
-                    fail_closed.setdefault(pod.key, (
-                        "InterPodAffinity",
-                        "a running pod's matching anti-affinity term "
-                        "has an unrepresentable topology key (registry "
-                        "full); failing closed"))
-                elif len(pairs) > max_forbid:
-                    fail_closed.setdefault(pod.key, (
-                        "InterPodAffinity",
-                        f"pod is repelled by more than {max_forbid} "
-                        "distinct anti-affinity domains; failing closed "
-                        "rather than evaluating a truncated constraint"))
-                return pairs
+        if self._anti_enabled and self.cache.anti_class_count():
+            def anti_fn(pod: Pod) -> tuple:
+                # once per pod signature (encode_pods' prototype memo)
+                with span("encode.anti", seq=seq):
+                    return self.cache.anti_classes_for(pod)
 
         encode_hard: Dict[int, tuple] = {}
         with span("encode.pods", pods=len(pods), seq=seq,
@@ -3241,9 +3258,10 @@ class Scheduler:
                              volumes_ready_fn=lambda p: vol_state(p)[0],
                              gang_bound_fn=self.cache.gang_bound_count,
                              volume_info_fn=lambda p: vol_state(p)[1:],
-                             anti_forbidden_fn=anti_fn,
+                             anti_classes_fn=anti_fn,
                              hard_failed=encode_hard,
                              selector_spread=self._selspread_enabled)
+        self._anti_class_slots = int(eb.pf.anti_cls_group.shape[1])
         for idx, infos in encode_hard.items():
             for info in infos:
                 if self._fail_closed_plugins.get(info[0], True):
@@ -3270,13 +3288,16 @@ class Scheduler:
         self._book_gap("encode", t0 - t_in)
         vol_memo, fail_closed, eb = self._encode_batch(
             batch, pods, P_ring, seq=inf.seq, loop_slot=True)
-        if fail_closed:
+        if fail_closed or eb.pf.anti_cls_group.shape[1]:
             # Loop-safe pods cannot trip slot constraints by
-            # construction; a symmetric anti-affinity overflow from
-            # RUNNING pods still can. Containment replays everything
-            # per-batch, where the fail-closed machinery applies.
+            # construction, and no anti class repels them when the ring
+            # fills (_ring_safe_pods); one born or widened mid-tranche
+            # would give this slot another layout. Containment replays
+            # everything per-batch, where the fail-closed machinery
+            # applies.
             raise EngineDesync(
-                "loop slot hit a fail-closed encode verdict")
+                "loop slot hit a fail-closed encode verdict or an anti "
+                "class")
         inf.batch, inf.pods = batch, pods
         inf.vol_memo, inf.fail_closed = vol_memo, {}
         inf.eb, inf.names, inf.row_incs = eb, names, row_incs
@@ -4673,12 +4694,18 @@ class Scheduler:
             self._count_fetch(cd.nbytes + de.nbytes)
             return cd, de
 
-        return arbitrate_spread(
+        anti_revoked: Set[int] = set()
+        revoked = arbitrate_spread(
             batch, assigned, eb.pf, eb.gf,
             sp[:sp_p], sp[sp_p:2 * sp_p].astype(np.int32), sp[2 * sp_p],
             dead=dead, anti_enabled=self._anti_enabled,
             exact_tables=exact_tables,
-            scan_enforced=sp[2 * sp_p + 1].astype(bool))
+            scan_enforced=sp[2 * sp_p + 1].astype(bool),
+            anti_revoked=anti_revoked)
+        if anti_revoked:
+            with self._metrics_lock:
+                self._metrics["anti_revoked_total"] += len(anti_revoked)
+        return revoked
 
     def _node_pad(self, hw: int) -> int:
         """Node-axis pad for this engine's step shapes: the eighth-step
@@ -5449,6 +5476,12 @@ class Scheduler:
         out["index_classes_registered"] = (len(idx.rows)
                                            if idx is not None else 0)
         out["index_cooldown_left"] = int(self._index_cooldown)
+        # Existing-pod anti-affinity plane: live anti classes in the
+        # cache, and the padded class-slot width of the last batch
+        # encoded (0 = no pod of it was repelled: the filter compiled
+        # without the plane).
+        out["anti_classes"] = self.cache.anti_class_count()
+        out["anti_class_slots"] = self._anti_class_slots
         out["compile_cache_dir"] = self._compile_cache_dir
         # Where pods wait before the queue: seconds the informer thread
         # spent delivering bursts, seconds callers waited for the store

@@ -25,6 +25,10 @@ one batch carries the same one, from the prepare to the bind:
                      in pipelined mode)
     prepare          encode → snapshot → dispatch (scheduling thread; seq)
     encode.pods      pod-feature encode (seq)
+    encode.anti      inside encode.pods: one pod signature matched
+                     against the running pods' anti classes
+                     (cache.anti_classes_for; seq; only while a class
+                     lives)
     cache.snapshot / cache.snapshot_resident / cache.snapshot_assigned
                      node/assigned-corpus snapshot + delta collection
     h2d.static / h2d.dyn
@@ -56,6 +60,12 @@ The resolve children never start with ``fetch.``: a reader that takes
 before they existed. The informer thread records no span (thousands of
 events a second would make it the busiest lane); its work is the
 ``informer_busy_s_total`` counter instead.
+
+Counters beside the spans (``Scheduler.metrics()``): ``anti_revoked_total``
+(pods the intra-batch arbitration revoked for a required anti-affinity
+conflict with an earlier pod of their batch) and the gauges
+``anti_classes`` (live anti classes of bound pods' required anti terms)
+and ``anti_class_slots`` (the last batch's padded class-slot width).
 
 Instants: ``fault.<gate>`` (every fault-gate fire, faults.py),
 ``supervisor.escalate`` / ``supervisor.recover`` (ladder transitions),

@@ -18,13 +18,13 @@ the CANDIDATE NODE only). The batched formulation evaluates every
          them removes the rejection. Matching pods elsewhere in the
          domain can never be evicted by a node-local victim set, so they
          keep the node infeasible (exactly upstream's scope).
-       * symmetric existing-pod anti-affinity: the encode carries, per
-         forbidden (key, domain) slot, the single node row holding ALL
-         owners of the forbidding terms (-1 when owners span nodes) and
-         their max priority (encode.anti_forbid_row/_maxpri, stamped by
-         cache.anti_forbidden_for) — a node in the forbidden domain is
-         curable iff it IS that row and the preemptor outranks every
-         owner.
+       * symmetric existing-pod anti-affinity: each anti class stamped
+         on the preemptor (encode.anti_cls_group) is a selector group
+         counting the class's owners, so the same per-(class, domain)
+         reductions apply — a node in a domain holding owners is
+         curable iff every owner in that domain sits on the node itself
+         (a single owner node) with priority strictly below the
+         preemptor's (it outranks every owner).
        * DoNotSchedule topology spread: placing on node n is over-skew
          by ``over = count(d(n)) + 1 - min - max_skew`` pods; node-local
          eviction of ``over`` lower-priority MATCHING pods lowers
@@ -143,8 +143,13 @@ def build_preempt_op(plugin_set: PluginSet, *,
 
         if anti_cure:
             T = pf.anti_req_group.shape[1]
-            for t in range(T):
-                ag = pf.anti_req_group[:, t]                 # (Pf,)
+            # The preemptor's own required anti terms, then the anti
+            # classes of running pods' terms stamped on it (existing-pod
+            # symmetry, counting the class's owners): the same cure.
+            anti = jnp.concatenate([pf.anti_req_group, pf.anti_cls_group],
+                                   axis=1)
+            for t in range(anti.shape[1]):
+                ag = anti[:, t]                              # (Pf,)
                 acounts = gather_group_rows(ag, ctx["counts_node"])
                 adom = gather_group_rows(
                     ag, ctx["dom_valid"].astype(jnp.float32)) > 0
@@ -168,21 +173,6 @@ def build_preempt_op(plugin_set: PluginSet, *,
                 cand = cand & jnp.where(
                     (g >= 0)[:, None],
                     (dom_ok & (counts > 0)) | self_ok, True)
-            # Symmetric existing-pod anti: curable only AT the single
-            # node holding every owner, when the preemptor outranks them.
-            S = pf.anti_forbid_key.shape[1]
-            K = nf.topo_domains.shape[0]
-            col = jnp.arange(N, dtype=jnp.int32)[None, :]
-            for s in range(S):
-                k = pf.anti_forbid_key[:, s]
-                d = pf.anti_forbid_dom[:, s]
-                node_dom = nf.topo_domains[jnp.clip(k, 0, K - 1)]  # (Pf,N)
-                in_dom = node_dom == d[:, None]
-                curable = ((pf.anti_forbid_row[:, s][:, None] == col)
-                           & (pf.anti_forbid_maxpri[:, s]
-                              < pf.priority)[:, None])
-                cand = cand & jnp.where((k >= 0)[:, None],
-                                        (~in_dom) | curable, True)
 
         if spread_cure:
             for c in range(C):

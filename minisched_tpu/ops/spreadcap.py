@@ -40,6 +40,8 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from .topology import ns_in_set
+
 # Python literals, NOT module-level jnp constants: a device-resident
 # const captured by the trace becomes a hoisted executable parameter,
 # and this module's consts proved to tickle a jax-0.9 cpp-pjit dispatch
@@ -71,13 +73,12 @@ class DomainCaps(NamedTuple):
 def _pod_group_match(pf, gf, gsel: jnp.ndarray) -> jnp.ndarray:
     """(P,H) bool: batch pod p matches selected group gsel[h] — the
     batch-pod twin of ops.topology.group_assigned_match (same all-zero
-    selector = match-all and ns_hash 0 = any-namespace semantics), using
-    the pod's own encoded ns_hash/label_pairs."""
+    selector = match-all and namespace-set semantics), using the pod's
+    own encoded ns_hash/label_pairs."""
     gsafe = jnp.clip(gsel, 0, gf.valid.shape[0] - 1)
     sel = gf.sel_pairs[gsafe]                       # (H,QT)
-    gns = gf.ns_hash[gsafe]                         # (H,)
     gvalid = gf.valid[gsafe] & (gsel < BIG_GID)
-    ns_ok = (gns[None, :] == 0) | (gns[None, :] == pf.ns_hash[:, None])
+    ns_ok = ns_in_set(gf.ns_set[gsafe], pf.ns_hash).T   # (P,H)
     # (P,H,QT): each non-empty selector pair present among the pod's
     # label pairs (reduced over the pod's L label slots)
     present = (sel[None, :, :, None]

@@ -5,8 +5,12 @@ this topology domain" by walking pods per node per constraint in Go. The
 TPU formulation (BASELINE config 4 "masked psum over node-sharded mesh"):
 
   1. match (G × A): which assigned pods match each selector GROUP — exact
-     hashed-pair comparison, G = distinct (key, ns, selector) tuples in the
-     batch (deployment replicas share one), A = assigned-pod corpus.
+     hashed-pair comparison, G = distinct (key, namespace set, selector)
+     tuples in the batch (deployment replicas share one), A = assigned-pod
+     corpus. Bound pods' required anti terms are groups too: each anti
+     CLASS counts its owners through a synthetic tag pair on their corpus
+     rows (cache.anti_classes_for), so existing-pod anti-affinity is one
+     more row of the same count plane.
   2. counts_dom (G × D): segment-sum of matches over each group's domain
      ids (domain = node row for kubernetes.io/hostname, hashed label value
      otherwise). Under a node-sharded mesh this is the masked psum.
@@ -24,12 +28,20 @@ import jax
 import jax.numpy as jnp
 
 
+def ns_in_set(ns_set: jnp.ndarray, ns: jnp.ndarray) -> jnp.ndarray:
+    """(G, X) bool: namespace hash ns[x] lies in group g's namespace set
+    (ns_set (G,NS), 0 = unused slot); an all-zero set matches any
+    namespace. A multi-namespace term is one group, matched exactly."""
+    hit = ((ns_set[:, :, None] == ns[None, None, :])
+           & (ns_set[:, :, None] != 0)).any(axis=1)
+    return hit | (ns_set == 0).all(axis=1)[:, None]
+
+
 def group_assigned_match(gf, af) -> jnp.ndarray:
-    """(G, A) bool: assigned pod a matches group g's namespace + selector.
-    All-zero selector with a valid group = match-all (upstream empty
-    LabelSelector)."""
-    ns_ok = (gf.ns_hash[:, None] == 0) | (
-        gf.ns_hash[:, None] == af.ns_hash[None, :])
+    """(G, A) bool: assigned pod a matches group g's namespace set +
+    selector. All-zero selector with a valid group = match-all (upstream
+    empty LabelSelector)."""
+    ns_ok = ns_in_set(gf.ns_set, af.ns_hash)
     # (G,QT,A): each non-empty selector pair present among the pod's labels
     present = (gf.sel_pairs[:, :, None, None]
                == af.label_pairs[None, None, :, :]).any(-1)

@@ -17,11 +17,14 @@ PodTopologySpread); intra-batch required-anti-affinity conflicts — direct
 and symmetric between two pods of the SAME batch — are caught by the
 engine's priority-order arbitration (engine.scheduler.arbitrate_spread)
 and retried. The SYMMETRIC check against already-RUNNING pods (upstream's
-existing-pod anti-affinity) is enforced via per-pod forbidden-domain
-slots: the node cache tracks bound pods' required anti terms
-(cache.anti_forbidden_for), the encoder stamps matching incoming pods
-with the occupied (key, domain) pairs, and the filter masks those
-domains below.
+existing-pod anti-affinity) reads a device count plane: the node cache
+keeps bound pods' required anti terms as anti CLASSES (distinct topology
+key, namespace set and selector) and tags each owner's corpus row with
+its class, so a class is one more selector group whose per-domain count
+is upstream's existingAntiAffinityCounts. The encoder stamps each pod
+with the class groups whose terms match it (once per pod signature,
+cache.anti_classes_for), and the filter refuses a node whose domain
+holds an owner of any stamped class — however many domains that is.
 """
 from __future__ import annotations
 
@@ -65,17 +68,13 @@ class InterPodAffinity(BatchedPlugin):
             adom = gather_group_rows(ag, ctx["dom_valid"].astype(jnp.float32)) > 0
             ok = ok & jnp.where((ag >= 0)[:, None], ~(adom & (acounts > 0)), True)
 
-        # Symmetric existing-pod anti-affinity (upstream parity): mask
-        # domains a RUNNING pod's required anti term forbids for THIS pod
-        # (encode.anti_forbid slots, fed by the cache's anti-term table).
-        S = pf.anti_forbid_key.shape[1]
-        K = nf.topo_domains.shape[0]
-        for s in range(S):
-            k = pf.anti_forbid_key[:, s]                     # (P,)
-            d = pf.anti_forbid_dom[:, s]
-            node_dom = nf.topo_domains[jnp.clip(k, 0, K - 1)]  # (P,N)
-            ok = ok & jnp.where((k >= 0)[:, None],
-                                node_dom != d[:, None], True)
+        # Symmetric existing-pod anti-affinity (upstream parity): a
+        # stamped class with an owner in the node's domain forbids it.
+        # S is 0 in a batch nobody is repelled in: no work compiles.
+        for s in range(pf.anti_cls_group.shape[1]):
+            cg = pf.anti_cls_group[:, s]
+            owners = gather_group_rows(cg, ctx["counts_node"])
+            ok = ok & ~((cg >= 0)[:, None] & (owners > 0))
         return ok
 
     def score(self, pf, nf, ctx) -> jnp.ndarray:

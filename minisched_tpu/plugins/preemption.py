@@ -18,8 +18,8 @@ Anti-affinity and topology-spread rejections ARE curable by eviction
 ``SelectVictimsOnNode``): ops/preempt.py admits a candidate node when
 evicting lower-priority pods ON THAT NODE removes the rejection — the
 preemptor's own required anti-affinity matches, the symmetric
-repelling-term owners (encode.anti_forbid_row/_maxpri carry their
-location and rank), and enough spread-matching pods to bring the domain
+repelling-term owners (each anti class stamped on the preemptor counts
+its owners per domain), and enough spread-matching pods to bring the domain
 back under max_skew (``spread_evict`` counts) — and the engine's victim
 selection evicts those pods as a MANDATORY set before the
 capacity-driven top-up. Remaining documented deviations: other

@@ -34,14 +34,20 @@ def _run():
     run.engine0 = {"batches": 10, "informer_busy_s_total": 1.0,
                    "store_lock_wait_s_total": 0.5, "gc_pause_s_total": 2.0,
                    "store_lock_acquisitions_total": 1000,
+                   "pods_bound": 100, "anti_revoked_total": 4,
+                   "anti_classes": 1,
                    "histograms": {"pod_informer_lag_s": _hist(1.0, 100)}}
     run.traced1 = {"batches": 20, "informer_busy_s_total": 2.5,
                    "store_lock_wait_s_total": 0.55, "gc_pause_s_total": 2.1,
                    "store_lock_acquisitions_total": 3000,
+                   "pods_bound": 900, "anti_revoked_total": 20,
+                   "anti_classes": 1,
                    "histograms": {"pod_informer_lag_s": _hist(3.0, 300)}}
     run.engine1 = {"batches": 100, "informer_busy_s_total": 9.0,
                    "store_lock_wait_s_total": 0.55, "gc_pause_s_total": 7.1,
                    "store_lock_acquisitions_total": 9000,
+                   "pods_bound": 5000, "anti_revoked_total": 60,
+                   "anti_classes": 1,
                    "histograms": {"pod_informer_lag_s": _hist(9.0, 900)}}
     run.trace = Trace(window_s=6.0, busy_s=0.5, devices=1)
     run.spans = [
@@ -54,6 +60,9 @@ def _run():
         _span("resolve.arbitrate", 101.0, 6.0, seq=2),
         _span("resolve.verdicts", 108.0, 2.0, seq=2),
         _span("resolve.assume", 111.0, 5.0, seq=2),
+        _span("encode.pods", 200.0, 10.0, seq=3, pods=8),
+        _span("encode.anti", 201.0, 1.5, seq=3),
+        _span("encode.anti", 204.0, 0.5, seq=3),
     ]
     return run
 
@@ -63,11 +72,12 @@ def _parent_run():
     run = _run()
     for snap in (run.engine0, run.traced1, run.engine1):
         for k in ("informer_busy_s_total", "store_lock_wait_s_total",
-                  "gc_pause_s_total", "store_lock_acquisitions_total"):
+                  "gc_pause_s_total", "store_lock_acquisitions_total",
+                  "anti_revoked_total", "anti_classes"):
             del snap[k]
         snap["histograms"] = {"pod_queue_wait_s": _hist(1.0, 10)}
     run.spans = [e for e in run.spans if not e["name"].startswith(
-        "resolve.")]
+        ("resolve.", "encode.anti"))]
     return run
 
 
@@ -88,6 +98,10 @@ READINGS = [
     ("assume_ms.drain", 0.8),
     # (4 + 6) ms of resolve.arbitrate over 10 batches
     ("arbitrate_ms.rate", 1.0),
+    # (1.5 + 0.5) ms of encode.anti over 10 batches
+    ("anti_match_ms.anti", 0.2),
+    # (20 − 4) anti revocations over (900 − 100) pods bound
+    ("anti_revoked_per_bind.anti", 0.02),
 ]
 
 
@@ -99,3 +113,64 @@ def test_reader_reads_the_engine(name, want):
 @pytest.mark.parametrize("name", [n for n, _v in READINGS])
 def test_reader_is_silent_without_the_counter(name):
     assert read_metric(name, _parent_run()) is None
+
+
+def _traced_anti_run(run):
+    """`run` with 0.1 s of jit_step in its trace and the anti-affinity
+    configuration's cluster shape."""
+    from benchmark.workload import Cluster
+
+    run.trace.module_s = {"jit_step": 0.1}
+    run.device_kind = "TPU v5 lite"
+    green = {"namespace": "sched-1", "labels": {"color": "green"},
+             "requests": {"cpu": 100},
+             "affinity": {"pod_anti_affinity": {"required": [
+                 {"match_labels": {"color": "green"},
+                  "topology_key": "kubernetes.io/hostname",
+                  "namespaces": ["sched-1", "sched-0"]}]}}}
+    run.cluster = Cluster(
+        node_names=[f"n{i}" for i in range(5000)], node_labels=[],
+        node_template={}, resources=["cpu", "memory", "pods"], alloc=None,
+        templates={"green": green, "default": {"labels": {}}},
+        standing_keys=[f"s{i}" for i in range(151000)])
+    run.template = "green"
+    run.engine0["pods_seen"], run.traced1["pods_seen"] = 0, 800
+    return run
+
+
+def test_anti_step_readers_read_the_trace():
+    from benchmark.roofline_anti import least_seconds_anti
+
+    run = _traced_anti_run(_run())
+    # 0.1 s of jit_step over 10 traced batches
+    assert read_metric("step_device_ms.rate", run) == pytest.approx(10.0)
+    least, _bound = least_seconds_anti(10, 800, 5000, 3, 0, 151000, 1, 1,
+                                       1, "TPU v5 lite")
+    assert read_metric("step_roofline.anti", run) == pytest.approx(
+        least / 0.1 * 100.0)
+    assert 0 < read_metric("step_roofline.anti", run) < 100
+
+
+def test_anti_step_readers_are_silent_without_the_plane():
+    run = _traced_anti_run(_parent_run())
+    assert read_metric("step_roofline.anti", run) is None
+    run.trace = None
+    assert read_metric("step_device_ms.rate", run) is None
+
+
+def test_least_seconds_anti_extends_least_seconds():
+    """With no term, class or corpus the anti count is the plain one;
+    the corpus read makes it longer, and a class never shortens it (at
+    these shapes the bytes bound it, which classes do not add to)."""
+    from benchmark.roofline import least_seconds
+    from benchmark.roofline_anti import least_seconds_anti
+
+    base = least_seconds(10, 800, 5000, 3, 0, "TPU v5 lite")
+    assert least_seconds_anti(10, 800, 5000, 3, 0, 0, 0, 0, 0,
+                              "TPU v5 lite") == base
+    one = least_seconds_anti(10, 800, 5000, 3, 0, 151000, 1, 1, 1,
+                             "TPU v5 lite")
+    two = least_seconds_anti(10, 800, 5000, 3, 0, 151000, 1, 2, 1,
+                             "TPU v5 lite")
+    assert base[0] < one[0] <= two[0]
+    assert one[1] == "bytes"

@@ -216,6 +216,78 @@ def test_loop_declines_unsafe_batches():
     assert m["loop_iterations"] == 0
 
 
+def _run_guarded(loop: bool, app: str):
+    """A burst of plain pods labelled ``app`` after a guard pod has bound
+    whose required zone anti term selects ``app: db`` (one anti class):
+    returns the burst's placements, by node zone, and the metrics."""
+    anti = obj.Affinity(pod_anti_affinity=obj.PodAntiAffinity(
+        required=[obj.PodAffinityTerm(
+            label_selector=obj.LabelSelector(match_labels={"app": "db"}),
+            topology_key=ZONE)]))
+    profile = Profile(name="loop", plugins=["NodeUnschedulable",
+                                            "NodeResourcesFit",
+                                            "InterPodAffinity"],
+                      plugin_args={"NodeResourcesFit":
+                                   {"score_strategy": None}})
+    c = Cluster()
+    try:
+        c.start(profile=profile, config=_config(loop),
+                with_pv_controller=False)
+        for i in range(6):
+            c.create_node(f"n{i}", cpu=640000, labels={ZONE: "ab"[i % 2]})
+        c.create_objects([obj.Pod(
+            metadata=obj.ObjectMeta(name="guard", namespace="default",
+                                    labels={"app": "guard"}),
+            spec=obj.PodSpec(requests={"cpu": 100}, priority=5000,
+                             affinity=anti))])
+        c.wait_for_pod_bound("guard", timeout=30)
+        sched = c.service.scheduler
+        assert sched.cache.anti_class_count() == 1
+        pods = _plain_pods(24)
+        for p in pods:
+            p.metadata.labels = {"app": app}
+        c.create_objects(pods)
+        names = {p.metadata.name for p in pods}
+        deadline = time.monotonic() + 120
+        placements = {}
+        while time.monotonic() < deadline:
+            placements = {p.metadata.name: p.spec.node_name
+                          for p in c.list_pods()
+                          if p.spec.node_name and p.metadata.name in names}
+            if len(placements) == len(names):
+                break
+            time.sleep(0.05)
+        assert len(placements) == len(names)
+        zone = {n.metadata.name: n.metadata.labels[ZONE]
+                for n in c.list_nodes()}
+        placements["guard"] = c.get_pod("guard").spec.node_name
+        return ({k: (v, zone[v]) for k, v in placements.items()},
+                sched.metrics())
+    finally:
+        c.shutdown()
+
+
+def test_loop_keeps_pods_no_anti_class_matches():
+    """A live anti class that matches none of a burst's pods leaves them
+    on the ring, placed exactly as per-batch dispatch places them."""
+    base, m0 = _run_guarded(False, "web")
+    fused, m1 = _retry_fused(lambda: _run_guarded(True, "web"),
+                             lambda m: m["loop_tranches"] >= 1)
+    assert fused == base
+    assert m0["loop_tranches"] == 0
+    assert m1["loop_tranches"] >= 1, m1
+
+
+def test_loop_declines_pods_an_anti_class_repels():
+    """Pods a live anti class repels carry class slots: they never ride
+    the ring, and none lands in the guard's zone."""
+    placed, m = _run_guarded(True, "db")
+    guard_zone = placed.pop("guard")[1]
+    assert {z for _n, z in placed.values()} == {"ab".replace(guard_zone,
+                                                             "")}
+    assert m["loop_tranches"] == 0
+
+
 def test_loop_off_is_exact_noop():
     """MINISCHED_DEVICE_LOOP=0 (the default) must leave the per-batch
     path untouched: zero loop metrics, no loop listener registered."""

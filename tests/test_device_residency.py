@@ -514,6 +514,40 @@ def test_insert_ports_matches_host_replay_and_cache_rule():
     np.testing.assert_array_equal(mirror[0], 0)
 
 
+def test_establish_mirrors_share_no_memory_with_the_device():
+    """The host replay writes the mirrors in place. On the CPU backend
+    device_put may alias an aligned host buffer, so the mirrors an
+    establish keeps must be copies: a port insertion replayed on the
+    host then never reaches the device twice (once through the shared
+    buffer, once through insert_ports)."""
+    from types import SimpleNamespace
+
+    from minisched_tpu.engine.scheduler import _DeviceResidency
+
+    class _Eng:
+        def _nf_sharding(self, name):
+            return None
+
+        def _res_count(self, **kw):
+            pass
+
+    N, R, PORT = 8, 3, 4
+    free = np.full((N, R), 64.0, dtype=np.float32)
+    ports = np.zeros((N, PORT), dtype=np.int32)
+    nf = SimpleNamespace(free=free, used_ports=ports,
+                         _replace=lambda **kw: kw)
+    res = _DeviceResidency(SimpleNamespace(epoch=0))
+    res.attach(_Eng(), nf, None)
+    assert not np.shares_memory(res.mirror_free, free)
+    assert not np.shares_memory(res.mirror_ports, ports)
+    res.note_ports(np.array([2, 2], dtype=np.int32),
+                   np.array([[20001, 30001], [20002, 0]], dtype=np.int32))
+    np.testing.assert_array_equal(np.asarray(res.ports_dev),
+                                  res.mirror_ports)
+    np.testing.assert_array_equal(res.mirror_ports[2],
+                                  [20001, 30001, 20002, 0])
+
+
 def test_port_heavy_steady_state_keeps_residency():
     """Port-heavy workloads keep the zero-correction steady state
     (follow-up (d)): with insertion modeled on device + mirror, a burst

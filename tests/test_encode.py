@@ -220,9 +220,9 @@ def test_snapshot_versioned_static_elision_and_atomicity():
 
 
 def test_anti_term_table_bind_unbind_refcount():
-    """The cache's running-pod anti-term table must refcount per (term,
-    row): two pods with the same term on one node keep the domain
-    forbidden until BOTH leave; anti_forbidden_for matches only pods the
+    """The cache's anti-class table must refcount per class: two pods
+    with the same term share ONE class, tag both corpus rows with it, and
+    keep it live until BOTH leave; anti_classes_for matches only pods the
     selector + namespace actually cover."""
     from minisched_tpu.state.objects import (Affinity, LabelSelector,
                                              PodAffinityTerm, PodAntiAffinity)
@@ -236,21 +236,123 @@ def test_anti_term_table_bind_unbind_refcount():
     p1, p2 = pod("ap1", affinity=anti), pod("ap2", affinity=anti)
     c.account_bind(p1, node_name="an-1")
     c.account_bind(p2, node_name="an-1")
+    assert c.anti_class_count() == 1
 
     victim = pod("vic")
     victim.metadata.labels = {"a": "x"}
-    assert len(c.anti_forbidden_for(victim)) == 1
+    classes, reason = c.anti_classes_for(victim)
+    assert reason is None and len(classes) == 1
+    key_idx, tag = classes[0]
+    assert c.registry.keys()[key_idx] == zone
+    af = c.snapshot_assigned()
+    tagged = af.valid & (af.label_pairs == tag).any(axis=1)
+    assert int(tagged.sum()) == 2  # each owner's row carries the tag
     other_ns = pod("vic2", ns="other")
     other_ns.metadata.labels = {"a": "x"}
-    assert c.anti_forbidden_for(other_ns) == []  # term ns = owner's ns
+    assert c.anti_classes_for(other_ns) == ((), None)  # term ns = owner's
     nomatch = pod("vic3")
     nomatch.metadata.labels = {"a": "y"}
-    assert c.anti_forbidden_for(nomatch) == []
+    assert c.anti_classes_for(nomatch) == ((), None)
 
     c.account_unbind(p1.key)
-    assert len(c.anti_forbidden_for(victim)) == 1  # p2 still holds it
+    assert c.anti_classes_for(victim) == (classes, None)  # p2 still holds it
     c.account_unbind(p2.key)
-    assert c.anti_forbidden_for(victim) == []
+    assert c.anti_classes_for(victim) == ((), None)
+    assert c.anti_class_count() == 0
+
+
+def _anti_term(ns_list, key="kubernetes.io/hostname", labels=None):
+    from minisched_tpu.state.objects import (Affinity, LabelSelector,
+                                             PodAffinityTerm, PodAntiAffinity)
+
+    return Affinity(pod_anti_affinity=PodAntiAffinity(required=[
+        PodAffinityTerm(label_selector=LabelSelector(
+            match_labels=labels or {"color": "green"}),
+            topology_key=key, namespaces=list(ns_list))]))
+
+
+def test_multi_namespace_group_matches_exactly():
+    """A term naming two namespaces is ONE group whose namespace set the
+    device matches exactly (ops.topology.group_assigned_match): pods of
+    either namespace, and no other; the set pads to the pow2 bucket of
+    the batch's widest group, and a single-namespace batch keeps one
+    slot."""
+    import jax.numpy as jnp
+
+    from minisched_tpu.encode.features import _h, empty_assigned_features
+    from minisched_tpu.ops.topology import group_assigned_match
+
+    p = pod("g", ns="sched-1", affinity=_anti_term(["sched-1", "sched-0"]))
+    p.metadata.labels = {"color": "green"}
+    eb = encode_pods([p], 8)
+    g = int(eb.pf.anti_req_group[0, 0])
+    assert g >= 0 and eb.gf.ns_set.shape[1] == 2
+    assert sorted(eb.gf.ns_set[g].tolist()) == sorted(
+        [_h("sched-0"), _h("sched-1")])
+    af = empty_assigned_features(8)
+    green = pair_hash("color", "green")
+    for a, (ns, lab) in enumerate([("sched-0", green), ("sched-1", green),
+                                   ("sched-2", green), ("sched-0", 0),
+                                   ("", green)]):
+        af.valid[a] = True
+        af.ns_hash[a] = _h(ns) if ns else 0
+        af.label_pairs[a, 0] = lab
+    gf = type(eb.gf)(*(jnp.asarray(x) for x in eb.gf))
+    af_d = type(af)(*(jnp.asarray(x) for x in af))
+    match = np.asarray(group_assigned_match(gf, af_d))[g]
+    assert match[:6].tolist() == [True, True, False, False, False, False]
+
+    q = pod("h", ns="sched-1", affinity=_anti_term([]))
+    assert encode_pods([q], 8).gf.ns_set.shape[1] == 1
+
+
+def test_anti_term_beyond_namespace_slots_fails_closed():
+    """An anti term naming more namespaces than max_term_namespaces would
+    under-repel if truncated: the pod fails closed; an affinity term may
+    narrow (it only rejects more) and does not."""
+    from minisched_tpu.encode.features import DEFAULT_ENCODING
+
+    many = [f"ns-{i}" for i in range(DEFAULT_ENCODING.max_term_namespaces + 1)]
+    hard: dict = {}
+    encode_pods([pod("a", affinity=_anti_term(many))], 8, hard_failed=hard)
+    assert [plugin for plugin, _r in hard[0]] == ["InterPodAffinity"]
+    hard = {}
+    encode_pods([pod("b", affinity=_anti_term(many[:4]))], 8,
+                hard_failed=hard)
+    assert hard == {}
+
+
+def test_anti_class_slots_bucket_and_overflow_fail_closed():
+    """Stamped anti classes pad to the pow2 bucket of the most-repelled
+    pod (0 when none is repelled); a pod repelled by more classes than
+    max_anti_classes fails closed with its own reason."""
+    from minisched_tpu.encode.features import DEFAULT_ENCODING
+
+    S = DEFAULT_ENCODING.max_anti_classes
+    p0, p1, p2 = pod("c0"), pod("c1"), pod("c2")
+    classes = {p0.key: [], p1.key: [(0, 101), (0, 102), (0, 103)],
+               p2.key: [(0, 200 + i) for i in range(S + 1)]}
+
+    def fn(p):
+        return tuple(classes[p.key]), None
+
+    # distinct signatures: the class callback runs once per signature
+    for i, p in enumerate((p0, p1, p2)):
+        p.metadata.labels = {"k": str(i)}
+    eb = encode_pods([p0], 8, anti_classes_fn=fn)
+    assert eb.pf.anti_cls_group.shape == (8, 0)
+    hard: dict = {}
+    eb = encode_pods([p0, p1], 8, anti_classes_fn=fn, hard_failed=hard)
+    assert eb.pf.anti_cls_group.shape == (8, 4) and hard == {}
+    gids = eb.pf.anti_cls_group[1, :3]
+    assert (gids >= 0).all() and (eb.gf.sel_pairs[gids, 0] == [101, 102,
+                                                             103]).all()
+    assert (eb.gf.ns_set[gids] == 0).all()  # owners counted anywhere
+    hard = {}
+    eb = encode_pods([p0, p1, p2], 8, anti_classes_fn=fn, hard_failed=hard)
+    assert eb.pf.anti_cls_group.shape == (8, S)
+    assert list(hard) == [2] and hard[2][0][0] == "InterPodAffinity"
+    assert "classes" in hard[2][0][1]
 
 
 def test_step_bucket_geometry():

@@ -673,3 +673,64 @@ def test_service_histogram_provider_surface():
                    for v in svc.metrics().values())
     finally:
         svc.shutdown_scheduler()
+
+
+def test_anti_plane_span_and_counters():
+    """Armed, the per-signature match against running pods' anti
+    classes records an ``encode.anti`` span inside its batch's
+    ``encode.pods`` (same thread, same seq); ``anti_classes`` gauges the
+    live classes and ``anti_revoked_total`` counts a pod revoked for an
+    anti conflict with an earlier pod of its own batch."""
+    green = obj.Affinity(pod_anti_affinity=obj.PodAntiAffinity(required=[
+        obj.PodAffinityTerm(
+            label_selector=obj.LabelSelector(match_labels={"c": "g"}),
+            topology_key="kubernetes.io/hostname")]))
+    obs.configure(True, buf=1 << 15)
+    c = Cluster()
+    try:
+        c.start(profile=Profile(plugins=["NodeUnschedulable",
+                                         "NodeResourcesFit", "NodeName",
+                                         "InterPodAffinity"]),
+                config=SchedulerConfig(max_batch_size=8,
+                                       batch_window_s=0.3,
+                                       backoff_initial_s=0.05,
+                                       backoff_max_s=0.2),
+                with_pv_controller=False)
+        c.create_node("n0", cpu=4000)
+        c.create_node("n1", cpu=4000)
+        c.create_pod("owner", cpu=100, labels={"c": "g"}, affinity=green,
+                     required_node_name="n0")
+        c.wait_for_pod_bound("owner", timeout=30)
+        sched = c.service.scheduler
+        assert sched.metrics()["anti_classes"] == 1
+        # both can only take n1; the later one conflicts in-batch
+        c.create_objects([obj.Pod(
+            metadata=obj.ObjectMeta(name=f"g{i}", namespace="default",
+                                    labels={"c": "g"}),
+            spec=obj.PodSpec(requests={"cpu": 100}, affinity=green))
+            for i in range(2)])
+        from minisched_tpu.scenario import wait_until
+
+        def nodes_of_green():
+            return [p.spec.node_name for p in c.list_pods()
+                    if p.metadata.name in ("g0", "g1") and p.spec.node_name]
+
+        assert wait_until(lambda: nodes_of_green()
+                          and sched.metrics()["anti_revoked_total"], 30)
+        assert nodes_of_green() == ["n1"]
+        m = sched.metrics()
+        assert m["anti_revoked_total"] >= 1
+        assert m["anti_classes"] == 1 and m["anti_class_slots"] >= 1
+    finally:
+        c.shutdown()
+    evs = [e for e in obs.TRACE.events() if e["ph"] == "X"]
+    anti = [e for e in evs if e["name"] == "encode.anti"]
+    assert anti
+    for a in anti:
+        seq = a["args"]["seq"]
+        assert any(e["name"] == "encode.pods" and e["args"]["seq"] == seq
+                   and e["tid"] == a["tid"]
+                   and e["ts_ns"] <= a["ts_ns"]
+                   and a["ts_ns"] + a["dur_ns"] <= e["ts_ns"] + e["dur_ns"]
+                   for e in evs)
+

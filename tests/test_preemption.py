@@ -495,8 +495,8 @@ def test_engine_anti_cure_blocked_by_offnode_domain_matcher():
 
 def test_engine_preemption_cures_symmetric_anti_affinity():
     """A RUNNING low-priority pod whose own required anti term repels
-    the preemptor (existing-pod anti-affinity) is evicted — the
-    anti_forbid_row/_maxpri encode columns carry the owner's location
+    the preemptor (existing-pod anti-affinity) is evicted — the owner's
+    anti class, stamped on the preemptor, carries the owner's location
     and rank to the device op."""
     c = _topo_cluster()
     try:

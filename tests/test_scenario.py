@@ -506,16 +506,17 @@ def test_symmetric_anti_affinity_vs_running_pod():
         c.shutdown()
 
 
-def test_anti_affinity_forbidden_domain_overflow_fails_closed():
-    """A pod repelled by more distinct (topology key, domain) pairs than
-    the encoder has anti_forbid slots must FAIL CLOSED (pend under
-    InterPodAffinity), not schedule against a silently truncated
-    constraint (which would admit the overflowed domains)."""
-    from minisched_tpu.encode.features import DEFAULT_ENCODING
+@pytest.mark.parametrize("free_zone", [False, True])
+def test_anti_affinity_forbidden_domain_overflow_fails_closed(free_zone):
+    """A pod repelled by running pods' required anti terms in many
+    distinct domains (64 zones, far beyond any per-domain slot count) is
+    judged exactly by the anti-class count plane: it must pend under
+    InterPodAffinity while every zone is forbidden, and bind in the one
+    zone left free when there is one."""
     from minisched_tpu.state import objects as obj
 
     zone = "topology.kubernetes.io/zone"
-    n_zones = DEFAULT_ENCODING.max_anti_forbid + 1
+    n_zones = 64
     c = Cluster()
     try:
         c.start(profile=Profile(plugins=["NodeUnschedulable",
@@ -527,18 +528,24 @@ def test_anti_affinity_forbidden_domain_overflow_fails_closed():
             required=[obj.PodAffinityTerm(
                 label_selector=obj.LabelSelector(match_labels={"fc": "1"}),
                 topology_key=zone)]))
-        # One guard pinned per zone: every zone in the cluster becomes a
+        # One guard pinned per zone: every guarded zone becomes a
         # forbidden domain for pods labeled fc=1.
-        for i in range(n_zones):
+        for i in range(n_zones + int(free_zone)):
             c.create_node(f"fc-n{i}", cpu=2000, labels={zone: f"fz{i}"})
+        for i in range(n_zones):
             c.create_pod(f"fc-guard{i}", cpu=100, affinity=anti,
                          required_node_name=f"fc-n{i}")
-            c.wait_for_pod_bound(f"fc-guard{i}", timeout=15)
+        for i in range(n_zones):
+            c.wait_for_pod_bound(f"fc-guard{i}", timeout=30)
 
         c.create_objects([obj.Pod(
             metadata=obj.ObjectMeta(name="fc-victim", namespace="default",
                                     labels={"fc": "1"}),
             spec=obj.PodSpec(requests={"cpu": 100}))])
+        if free_zone:
+            bound = c.wait_for_pod_bound("fc-victim", timeout=20)
+            assert bound.spec.node_name == f"fc-n{n_zones}"
+            return
         p = c.wait_for_pod_pending("fc-victim", timeout=20)
         assert "InterPodAffinity" in p.status.unschedulable_plugins
         # It must stay pending (all domains forbidden, none truncated away).
