@@ -34,7 +34,8 @@ from ..encode.cache import bucket_for, step_bucket
 from ..encode.features import NodeFeatures
 from ..errors import ConflictError, NotFoundError
 from ..faults import FAULTS, FaultWorkerDeath
-from ..obs import (Histogram, batch_step, gc_pause_s_total, instant, span,
+from ..obs import (Histogram, batch_step, gc_collections_total,
+                   gc_full_pause_s_total, gc_pause_s_total, instant, span,
                    watch_gc)
 from ..obs import bundle as bundle_mod
 from ..obs import slo as slo_mod
@@ -5487,7 +5488,8 @@ class Scheduler:
         # spent delivering bursts, seconds callers waited for the store
         # lock and how often they took it while the flight recorder was
         # armed (an in-process store only; a RemoteStore has no such
-        # lock), and the process's collection pauses.
+        # lock), and the process's collection pauses (all of them, and the
+        # full collections' count and pause apart).
         out["informer_busy_s_total"] = (
             self._shared.informer_factory.busy_s_total)
         lock_wait = getattr(self.store, "lock_wait_s_total", None)
@@ -5496,6 +5498,8 @@ class Scheduler:
             out["store_lock_acquisitions_total"] = (
                 self.store.lock_acquisitions_total())
         out["gc_pause_s_total"] = gc_pause_s_total()
+        out["gc_full_total"] = gc_collections_total()[2]
+        out["gc_full_pause_s_total"] = gc_full_pause_s_total()
         # Supervisor state: the ladder rung as a gauge (0 = full fast
         # path; exposed on /metrics via the service provider) plus its
         # name for humans/tests (non-numeric — dropped from exposition).

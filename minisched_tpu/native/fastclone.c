@@ -20,13 +20,26 @@
  *   - str/int/float/bool/None are shared (immutable);
  *   - dict/list/tuple/set rebuild with cloned elements (set elements are
  *     scalars by contract and are shared);
- *   - instances of REGISTERED classes (the dataclass tree) rebuild via
- *     cls.__new__(cls) + a cloned __dict__;
+ *   - instances of REGISTERED classes (the slotted dataclass tree)
+ *     rebuild via cls.__new__(cls) + a cloned value in every slot,
+ *     read and written at the slot's offset (the type's tp_members);
+ *     an unset slot raises AttributeError, as the Python walk's getattr
+ *     does.  No instance __dict__ exists or is built;
  *   - anything else raises TypeError — the Python caller catches it and
  *     falls back to copy.deepcopy, exactly like the fallback path.
+ *
+ * register() accepts only classes this walk copies whole: a direct
+ * subclass of object with no instance __dict__, whose every member is a
+ * writable object slot.  Anything else is refused there, so a registered
+ * instance never raises TypeError here and never falls back.
  */
 #define PY_SSIZE_T_CLEAN
 #include <Python.h>
+#if PY_VERSION_HEX < 0x030C0000
+#include <structmember.h>
+#define Py_T_OBJECT_EX T_OBJECT_EX
+#define Py_READONLY READONLY
+#endif
 
 /* Registered dataclass types (borrowed refs owned by the set below). */
 static PyObject *registered = NULL;  /* a Python set of type objects */
@@ -84,30 +97,23 @@ static PyObject *
 clone_instance(PyObject *v)
 {
     PyTypeObject *tp = Py_TYPE(v);
-    /* dict BEFORE allocating the new object: a missing __dict__ is the
-     * unsupported-type signal (slots-only class). */
-    PyObject *src_dict = PyObject_GetAttrString(v, "__dict__");
-    if (!src_dict) return NULL;
-    if (!PyDict_Check(src_dict)) {
-        Py_DECREF(src_dict);
-        PyErr_Format(PyExc_TypeError,
-                     "fastclone: %s.__dict__ is not a dict", tp->tp_name);
-        return NULL;
-    }
-    PyObject *new_dict = clone_dict(src_dict);
-    Py_DECREF(src_dict);
-    if (!new_dict) return NULL;
-
     /* cls.__new__(cls) without running __init__ — same construction the
-     * Python fallback uses (object.__new__ for plain dataclasses). */
+     * Python fallback uses (object.__new__ for the API dataclasses).
+     * Slots start NULL; the copy owns what is stored in them. */
     PyObject *out = tp->tp_alloc(tp, 0);
-    if (!out) { Py_DECREF(new_dict); return NULL; }
-    if (PyObject_SetAttrString(out, "__dict__", new_dict) < 0) {
-        Py_DECREF(new_dict);
-        Py_DECREF(out);
-        return NULL;
+    if (!out) return NULL;
+    for (PyMemberDef *m = tp->tp_members; m && m->name; m++) {
+        PyObject *x = *(PyObject **)((char *)v + m->offset);
+        if (!x) {
+            PyErr_Format(PyExc_AttributeError,
+                         "fastclone: %s.%s is unset", tp->tp_name, m->name);
+            Py_DECREF(out);
+            return NULL;
+        }
+        PyObject *cx = clone_obj(x);
+        if (!cx) { Py_DECREF(out); return NULL; }
+        *(PyObject **)((char *)out + m->offset) = cx;  /* steals */
     }
-    Py_DECREF(new_dict);
     return out;
 }
 
@@ -163,6 +169,21 @@ py_register(PyObject *self, PyObject *arg)
         PyErr_SetString(PyExc_TypeError, "register() expects a class");
         return NULL;
     }
+    PyTypeObject *tp = (PyTypeObject *)arg;
+    if (tp->tp_base != &PyBaseObject_Type || tp->tp_dictoffset != 0) {
+        PyErr_Format(PyExc_TypeError,
+                     "register(): %s must subclass object directly and "
+                     "define __slots__ without __dict__", tp->tp_name);
+        return NULL;
+    }
+    for (PyMemberDef *m = tp->tp_members; m && m->name; m++) {
+        if (m->type != Py_T_OBJECT_EX || (m->flags & Py_READONLY)) {
+            PyErr_Format(PyExc_TypeError,
+                         "register(): %s.%s is not a writable object slot",
+                         tp->tp_name, m->name);
+            return NULL;
+        }
+    }
     if (PySet_Add(registered, arg) < 0) return NULL;
     Py_RETURN_NONE;
 }
@@ -171,7 +192,7 @@ static PyMethodDef methods[] = {
     {"clone", py_clone, METH_O,
      "Structural clone of a registered-dataclass tree."},
     {"register", py_register, METH_O,
-     "Register a class whose instances clone via __dict__ rebuild."},
+     "Register a slotted class whose instances clone slot by slot."},
     {NULL, NULL, 0, NULL},
 };
 

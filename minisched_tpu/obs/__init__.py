@@ -101,8 +101,10 @@ span/instant stream.
 Collections: :func:`watch_gc` registers one ``gc.callbacks`` hook per
 process (however many engines call it) that sums the pause of every
 collection into :func:`gc_pause_s_total` — always on, two clock reads
-a collection — and, while armed, records each full collection as a
-``gc`` span.
+a collection — counts collections per generation
+(:func:`gc_collections_total`), keeps the full (generation-2) ones'
+pause apart (:func:`gc_full_pause_s_total`) and, while armed, records
+each full collection as a ``gc`` span.
 """
 from __future__ import annotations
 
@@ -117,7 +119,8 @@ from typing import Dict, List, Optional
 
 __all__ = ["TRACE", "TraceRecorder", "Histogram", "LATENCY_BUCKETS",
            "configure", "span", "instant", "traced", "batch_step",
-           "hist_quantile", "ring_tail", "watch_gc", "gc_pause_s_total"]
+           "hist_quantile", "ring_tail", "watch_gc", "gc_pause_s_total",
+           "gc_collections_total", "gc_full_pause_s_total"]
 
 
 def ring_tail(buf: list, n: int, cap: int) -> list:
@@ -427,6 +430,8 @@ class _GcWatch:
 
     def __init__(self):
         self.pause_s = 0.0
+        self.full_pause_s = 0.0
+        self.collections = [0, 0, 0]  # per generation
         self._t0 = 0.0
         self._span: Optional[_Span] = None
         self._installed = False
@@ -447,7 +452,12 @@ class _GcWatch:
                 self._span = sp
             self._t0 = time.perf_counter()
             return
-        self.pause_s += time.perf_counter() - self._t0
+        dt = time.perf_counter() - self._t0
+        self.pause_s += dt
+        gen = info["generation"]
+        self.collections[gen] += 1
+        if gen == 2:
+            self.full_pause_s += dt
         sp, self._span = self._span, None
         if sp is not None:
             sp.set(collected=info.get("collected", 0))
@@ -466,6 +476,17 @@ def gc_pause_s_total() -> float:
     """Seconds every collection since :func:`watch_gc` has paused the
     process, all generations."""
     return _GC.pause_s
+
+
+def gc_collections_total() -> tuple:
+    """Collections since :func:`watch_gc`, per generation (0, 1, 2)."""
+    return tuple(_GC.collections)
+
+
+def gc_full_pause_s_total() -> float:
+    """Seconds the full (generation-2) collections among those of
+    :func:`gc_pause_s_total` have paused the process."""
+    return _GC.full_pause_s
 
 
 # ---------------------------------------------------------------------------

@@ -50,19 +50,19 @@ def _compile_builder(tp: Any) -> _MaybeFn:
         keyset = frozenset(names)
         subs = tuple(_builder(hints[n]) for n in names)
         pairs = tuple(zip(names, subs))
-        new = object.__new__  # none of the API dataclasses define
-        # __post_init__ or __slots__, so the full-dict fast path may
-        # bypass __init__ entirely: no default checks, no default_factory
-        # calls (notably: a wire uid is PRESERVED without burning a local
-        # _next_uid value), just a direct __dict__ fill.
+        new = object.__new__  # no API dataclass defines __post_init__,
+        # so the full-dict fast path may bypass __init__ entirely: no
+        # default checks, no default_factory calls (notably: a wire uid
+        # is PRESERVED without burning a local _next_uid value), just one
+        # store per slot (the classes are slotted: no __dict__ to fill).
 
         def build(v, _tp=tp, _keys=keyset, _pairs=pairs, _new=new):
             if v is None:
                 return None
             if v.keys() == _keys:
                 o = _new(_tp)
-                o.__dict__ = {n: (s(v[n]) if s is not None else v[n])
-                              for n, s in _pairs}
+                for n, s in _pairs:
+                    setattr(o, n, s(v[n]) if s is not None else v[n])
                 return o
             return _tp(**{n: (s(v[n]) if s is not None else v[n])
                           for n, s in _pairs if n in v})
@@ -112,10 +112,11 @@ def _compile_dumper(tp: Any) -> _MaybeFn:
         def dump(o, _pairs=pairs):
             if o is None:
                 return None
-            d = o.__dict__  # plain (non-slots) dataclasses: one dict
-            # read per field beats getattr's descriptor walk
-            return {n: (s(d[n]) if s is not None else d[n])
-                    for n, s in _pairs}
+            d = {}
+            for n, s in _pairs:  # slotted classes: no __dict__ to index
+                x = getattr(o, n)
+                d[n] = s(x) if s is not None else x
+            return d
         return dump
     if origin in (list, set, tuple):
         args = typing.get_args(tp)

@@ -19,6 +19,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import itertools
+import operator
 import threading
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
@@ -91,7 +92,7 @@ def bump_uid_counter(past: int) -> None:
     _uid_counter = itertools.count(max(current, past + 1))
 
 
-@dataclass
+@dataclass(slots=True)
 class OwnerReference:
     """metadata.ownerReferences entry (the subset scheduling reads:
     upstream's NodePreferAvoidPods scopes avoidance to pods whose
@@ -105,7 +106,7 @@ class OwnerReference:
     controller: bool = False
 
 
-@dataclass
+@dataclass(slots=True)
 class ObjectMeta:
     name: str = ""
     namespace: str = ""
@@ -117,14 +118,14 @@ class ObjectMeta:
     creation_timestamp: float = 0.0
 
 
-@dataclass
+@dataclass(slots=True)
 class Taint:
     key: str
     value: str = ""
     effect: str = "NoSchedule"  # NoSchedule | PreferNoSchedule | NoExecute
 
 
-@dataclass
+@dataclass(slots=True)
 class Toleration:
     key: str = ""  # empty key + Exists tolerates everything
     operator: str = "Equal"  # Equal | Exists
@@ -142,7 +143,7 @@ class Toleration:
         return self.value == taint.value
 
 
-@dataclass
+@dataclass(slots=True)
 class NodeSelectorRequirement:
     key: str
     operator: str  # In | NotIn | Exists | DoesNotExist | Gt | Lt
@@ -174,7 +175,7 @@ def _is_int(v: Optional[str]) -> bool:
         return False
 
 
-@dataclass
+@dataclass(slots=True)
 class NodeSelectorTerm:
     match_expressions: List[NodeSelectorRequirement] = field(default_factory=list)
 
@@ -182,7 +183,7 @@ class NodeSelectorTerm:
         return all(r.matches(labels) for r in self.match_expressions)
 
 
-@dataclass
+@dataclass(slots=True)
 class NodeSelector:
     """ORed terms, each term ANDs its expressions (upstream v1.NodeSelector)."""
 
@@ -192,19 +193,19 @@ class NodeSelector:
         return any(t.matches(labels) for t in self.node_selector_terms)
 
 
-@dataclass
+@dataclass(slots=True)
 class PreferredSchedulingTerm:
     weight: int
     preference: NodeSelectorTerm
 
 
-@dataclass
+@dataclass(slots=True)
 class NodeAffinity:
     required: Optional[NodeSelector] = None
     preferred: List[PreferredSchedulingTerm] = field(default_factory=list)
 
 
-@dataclass
+@dataclass(slots=True)
 class LabelSelector:
     match_labels: Dict[str, str] = field(default_factory=dict)
     match_expressions: List[NodeSelectorRequirement] = field(default_factory=list)
@@ -219,39 +220,39 @@ class LabelSelector:
         return not self.match_labels and not self.match_expressions
 
 
-@dataclass
+@dataclass(slots=True)
 class PodAffinityTerm:
     label_selector: LabelSelector = field(default_factory=LabelSelector)
     topology_key: str = "kubernetes.io/hostname"
     namespaces: List[str] = field(default_factory=list)  # empty = pod's own ns
 
 
-@dataclass
+@dataclass(slots=True)
 class WeightedPodAffinityTerm:
     weight: int
     term: PodAffinityTerm
 
 
-@dataclass
+@dataclass(slots=True)
 class PodAffinity:
     required: List[PodAffinityTerm] = field(default_factory=list)
     preferred: List[WeightedPodAffinityTerm] = field(default_factory=list)
 
 
-@dataclass
+@dataclass(slots=True)
 class PodAntiAffinity:
     required: List[PodAffinityTerm] = field(default_factory=list)
     preferred: List[WeightedPodAffinityTerm] = field(default_factory=list)
 
 
-@dataclass
+@dataclass(slots=True)
 class Affinity:
     node_affinity: Optional[NodeAffinity] = None
     pod_affinity: Optional[PodAffinity] = None
     pod_anti_affinity: Optional[PodAntiAffinity] = None
 
 
-@dataclass
+@dataclass(slots=True)
 class TopologySpreadConstraint:
     max_skew: int = 1
     topology_key: str = "topology.kubernetes.io/zone"
@@ -259,7 +260,7 @@ class TopologySpreadConstraint:
     label_selector: LabelSelector = field(default_factory=LabelSelector)
 
 
-@dataclass
+@dataclass(slots=True)
 class ContainerPort:
     host_port: int = 0
     container_port: int = 0
@@ -267,7 +268,7 @@ class ContainerPort:
     host_ip: str = ""
 
 
-@dataclass
+@dataclass(slots=True)
 class VolumeClaim:
     """A pod's reference to a PVC by name (pod.spec.volumes[*].pvc).
 
@@ -287,7 +288,7 @@ class PodPhase:
     FAILED = "Failed"
 
 
-@dataclass
+@dataclass(slots=True)
 class PodSpec:
     node_name: str = ""
     # Upstream spec.nodeName as a *constraint* evaluated by the NodeName
@@ -309,7 +310,7 @@ class PodSpec:
     pod_group_min: int = 0
 
 
-@dataclass
+@dataclass(slots=True)
 class PodStatus:
     phase: str = PodPhase.PENDING
     # Names of plugins that rejected the pod in its last scheduling attempt;
@@ -328,7 +329,7 @@ class PodStatus:
     nominated_node_name: str = ""
 
 
-@dataclass
+@dataclass(slots=True)
 class Pod:
     metadata: ObjectMeta = field(default_factory=ObjectMeta)
     spec: PodSpec = field(default_factory=PodSpec)
@@ -343,20 +344,20 @@ class Pod:
         return bool(self.spec.node_name)
 
 
-@dataclass
+@dataclass(slots=True)
 class NodeSpec:
     unschedulable: bool = False
     taints: List[Taint] = field(default_factory=list)
 
 
-@dataclass
+@dataclass(slots=True)
 class NodeStatus:
     capacity: ResourceList = field(default_factory=dict)
     allocatable: ResourceList = field(default_factory=dict)
     images: List[str] = field(default_factory=list)
 
 
-@dataclass
+@dataclass(slots=True)
 class Node:
     metadata: ObjectMeta = field(default_factory=ObjectMeta)
     spec: NodeSpec = field(default_factory=NodeSpec)
@@ -367,7 +368,7 @@ class Node:
         return self.metadata.name
 
 
-@dataclass
+@dataclass(slots=True)
 class PersistentVolume:
     metadata: ObjectMeta = field(default_factory=ObjectMeta)
     capacity: ResourceList = field(default_factory=dict)
@@ -380,7 +381,7 @@ class PersistentVolume:
         return self.metadata.name
 
 
-@dataclass
+@dataclass(slots=True)
 class PersistentVolumeClaim:
     metadata: ObjectMeta = field(default_factory=ObjectMeta)
     request: ResourceList = field(default_factory=dict)
@@ -399,7 +400,7 @@ class PersistentVolumeClaim:
         return f"{self.metadata.namespace}/{self.metadata.name}"
 
 
-@dataclass
+@dataclass(slots=True)
 class Event:
     """Cluster event record (analog of the k8s Events API the reference's
     broadcaster writes to, reference scheduler/scheduler.go:55-59)."""
@@ -417,7 +418,7 @@ class Event:
         return f"{self.metadata.namespace}/{self.metadata.name}"
 
 
-@dataclass
+@dataclass(slots=True)
 class PDBSpec:
     """upstream policy/v1 PodDisruptionBudgetSpec, the min_available
     form (max_unavailable reduces to it given the matched count; only
@@ -428,7 +429,7 @@ class PDBSpec:
     selector: Optional[LabelSelector] = None
 
 
-@dataclass
+@dataclass(slots=True)
 class PodDisruptionBudget:
     """upstream policy/v1 PodDisruptionBudget: bounds VOLUNTARY
     disruptions (here: preemption evictions) of the matching pods. The
@@ -445,7 +446,7 @@ class PodDisruptionBudget:
         return f"{self.metadata.namespace}/{self.metadata.name}"
 
 
-@dataclass
+@dataclass(slots=True)
 class Lease:
     """Shard-ownership lease (the coordination.k8s.io/v1 Lease shape
     applied to scheduler-fleet shards, fleet/lease.py): ``holder`` is
@@ -478,7 +479,7 @@ class Lease:
         return not self.holder or now - self.renewed_at > self.ttl_s
 
 
-@dataclass
+@dataclass(slots=True)
 class ReplicaStatus:
     """One process-fleet replica's heartbeat record (fleet/procfleet.py):
     the replica CAS-writes it every lease tick so the supervisor's
@@ -508,7 +509,7 @@ class ReplicaStatus:
         return self.metadata.name
 
 
-@dataclass
+@dataclass(slots=True)
 class ShardMove:
     """One elastic-handoff directive (fleet/procfleet.py): the
     supervisor's rebalancer nominates ``shard`` to move from ``donor``
@@ -540,7 +541,7 @@ class ShardMove:
         return self.metadata.name
 
 
-@dataclass
+@dataclass(slots=True)
 class Incarnation:
     """One replica's store-visible incarnation ledger
     (fleet/election.py): the census record the STEWARD role reads and
@@ -602,21 +603,20 @@ def object_key(obj: Any) -> str:
 
 
 _ATOMIC_TYPES = frozenset((str, int, float, bool, type(None)))
-_DATACLASS_TYPES: set = set()  # observed dataclass types (clone fast path)
 
 
 def _clone(v):
     # Branch order matters: ~3/4 of the calls on a pod tree are atomic
-    # leaves and most of the rest are dataclasses — is_dataclass() per
-    # call was the top cost of bulk ingestion (10k-pod create_many).
+    # leaves and most of the rest are API objects (bulk ingestion, a
+    # 10k-pod create_many, is ~300k calls).
     t = v.__class__
     if t in _ATOMIC_TYPES:
         return v
-    if t in _DATACLASS_TYPES:
+    names = _SLOTS.get(t)
+    if names is not None:
         new = t.__new__(t)
-        d = new.__dict__
-        for k, x in v.__dict__.items():
-            d[k] = _clone(x)
+        for n in names:
+            setattr(new, n, _clone(getattr(v, n)))
         return new
     if t is dict:
         return {k: _clone(x) for k, x in v.items()}
@@ -626,30 +626,25 @@ def _clone(v):
         return tuple(_clone(x) for x in v)
     if t is set:
         return set(v)  # sets here only ever hold scalars (plugin names)
-    if dataclasses.is_dataclass(v):
-        _DATACLASS_TYPES.add(t)
-        new = t.__new__(t)
-        d = new.__dict__
-        for k, x in v.__dict__.items():
-            d[k] = _clone(x)
-        return new
     import copy
 
     return copy.deepcopy(v)
 
 
 def shallow_evolve(o: Any, **kw: Any) -> Any:
-    """Fast dataclasses.replace: builds the new object via __dict__ instead
-    of __init__ and SHARES unchanged field values with the original.
+    """Fast dataclasses.replace: builds the new object positionally from
+    the old one's slot values (every field passed, so no default factory
+    runs) and SHARES unchanged field values with the original.
     Safe only under the store's replacement-only convention (stored
     objects are never mutated in place), where structural sharing between
     an object and its superseded version is already the contract —
-    dataclasses.replace costs ~5x more on the bulk-bind hot path (one
-    full __init__ per evolved sub-object × 4 objects × 10k pods)."""
-    new = object.__new__(type(o))
-    d = new.__dict__
-    d.update(o.__dict__)
-    d.update(kw)
+    dataclasses.replace costs ~5x more on the bulk-bind hot path (a
+    keyword __init__ per evolved sub-object × 4 objects × 10k pods). An
+    undeclared keyword raises AttributeError."""
+    t = o.__class__
+    new = t(*_GETTERS[t](o))
+    for n, x in kw.items():
+        setattr(new, n, x)
     return new
 
 
@@ -660,6 +655,23 @@ def shallow_evolve(o: Any, **kw: Any) -> Any:
 _WIRE_TYPES = tuple(
     v for v in list(globals().values())
     if isinstance(v, type) and dataclasses.is_dataclass(v))
+
+# Every API dataclass is slotted: CPython then never builds an instance
+# __dict__, one GC-tracked object fewer per object per snapshot. The
+# clone, shallow_evolve and the codec walk these field names (declaration
+# order, which is also __init__'s) in its place.
+_SLOTS: Dict[type, Tuple[str, ...]] = {c: c.__slots__ for c in _WIRE_TYPES}
+
+
+def _values_getter(names: Tuple[str, ...]):
+    """Callable giving an object's ``names`` values as one tuple (a bare
+    attrgetter returns the value itself for a single name)."""
+    if len(names) == 1:
+        return lambda o, _n=names[0]: (getattr(o, _n),)
+    return operator.attrgetter(*names)
+
+
+_GETTERS = {c: _values_getter(names) for c, names in _SLOTS.items()}
 
 _native_clone = None
 _native_lock = threading.Lock()
@@ -685,7 +697,7 @@ def deepcopy_obj(obj):
     Hand-rolled instead of copy.deepcopy: the store isolates every
     create/update/get behind a copy, so this sits on the ingestion hot
     path (50k-node clusters = 10^5 copies before the first scheduling
-    cycle). Rebuilding via __dict__ skips deepcopy's memo machinery and
+    cycle). Rebuilding slot by slot skips deepcopy's memo machinery and
     __init__, ~10x cheaper on these object trees. When the native
     fastclone extension is available (minisched_tpu/native — the same
     recursion in C; the reference's runtime is compiled Go throughout),

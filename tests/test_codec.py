@@ -4,6 +4,8 @@ objects._build_typed for decode. The wire layer and snapshot/restore are
 exactly as correct as this equivalence."""
 import dataclasses
 
+import pytest
+
 from minisched_tpu.state import codec
 from minisched_tpu.state import objects as obj
 from minisched_tpu.state.objects import _build_typed
@@ -122,3 +124,19 @@ def test_dump_returns_fresh_containers():
     d["spec"]["tolerations"][0]["key"] = "MUTATED"
     assert p.metadata.labels["app"] == "web"
     assert p.spec.tolerations[0].key == "dedicated"
+
+
+@pytest.mark.parametrize("kind", sorted(obj.KIND_OF.values()))
+def test_dump_build_roundtrip_every_kind_keeps_wire_uid(kind):
+    """Every stored kind round-trips through the slotted codec: equal
+    fields, the wire uid kept, no instance __dict__ built on the way."""
+    cls = obj._KIND_CLASS[kind]
+    o = cls(metadata=obj.ObjectMeta(name="r1", namespace="ns",
+                                    uid="uid-777777", labels={"a": "b"}))
+    d = codec.dump(o)
+    assert d == dataclasses.asdict(o)
+    built = codec.build(cls, d)
+    assert built == o and built.metadata.uid == "uid-777777"
+    assert not hasattr(built, "__dict__")
+    assert not hasattr(built.metadata, "__dict__")
+    assert codec.dump(built) == d

@@ -1,5 +1,7 @@
 """Native fastclone extension: build, equivalence with the Python clone
 over the whole object-tree shape space, and graceful fallback."""
+import copy
+import dataclasses
 import os
 import subprocess
 import sys
@@ -153,3 +155,92 @@ def test_native_build_is_named_by_source_hash(tmp_path, monkeypatch):
     assert native._so_path() != first
     src.write_text("/* a */")
     assert native._so_path() == first
+
+
+def _instances(o):
+    """Every API object in the tree under ``o`` (itself included)."""
+    if type(o) in obj._SLOTS:
+        yield o
+        for n in obj._SLOTS[type(o)]:
+            yield from _instances(getattr(o, n))
+    elif isinstance(o, (list, tuple)):
+        for x in o:
+            yield from _instances(x)
+    elif isinstance(o, dict):
+        for x in o.values():
+            yield from _instances(x)
+
+
+@pytest.mark.parametrize("cls", obj._WIRE_TYPES, ids=lambda c: c.__name__)
+def test_every_wire_type_is_slotted(cls):
+    """No API object can carry an instance __dict__ for the cyclic
+    collector to track: every wire type declares its fields as slots."""
+    assert "__slots__" in vars(cls)
+    assert cls.__slots__ == tuple(f.name for f in dataclasses.fields(cls))
+    assert "__dict__" not in dir(cls)
+
+
+@pytest.mark.parametrize("sample", SAMPLES[:2] + SAMPLES[3:],
+                         ids=["Pod", "Node", "Event"])
+@pytest.mark.parametrize("walk", ["native", "python", "deepcopy"])
+def test_clones_are_field_equal_and_dict_free(sample, walk):
+    """The native clone, the Python walk and copy.deepcopy give
+    field-equal trees in which no object has an instance __dict__."""
+    mod = load()
+    if mod is None:
+        pytest.skip("native toolchain unavailable")
+    fn = {"native": mod.clone, "python": _clone,
+          "deepcopy": copy.deepcopy}[walk]
+    got = fn(sample)
+    assert got == sample and got is not sample
+    assert got == _clone(sample) == mod.clone(sample)
+    objs = list(_instances(got))
+    assert len(objs) >= 2  # the object and its metadata at least
+    assert not any(hasattr(o, "__dict__") for o in objs)
+
+
+def test_native_path_is_taken_for_slotted_objects():
+    """Once built, deepcopy_obj resolves to the native clone, and that
+    clone takes every registered type without a TypeError (no silent
+    fall back to the Python walk)."""
+    mod = load()
+    if mod is None:
+        pytest.skip("native toolchain unavailable")
+    deepcopy_obj(SAMPLES[0])
+    assert obj._native_clone is mod.clone
+    for sample in SAMPLES:
+        assert mod.clone(sample) == sample
+
+
+def test_register_refuses_what_the_slot_walk_cannot_copy():
+    mod = load()
+    if mod is None:
+        pytest.skip("native toolchain unavailable")
+
+    @dataclasses.dataclass
+    class WithDict:
+        x: int = 0
+
+    @dataclasses.dataclass(slots=True)
+    class Slotted:
+        x: int = 0
+
+    with pytest.raises(TypeError):
+        mod.register(WithDict)
+    with pytest.raises(TypeError):
+        mod.register(type("Sub", (obj.Taint,), {"__slots__": ()}))
+    mod.register(Slotted)
+    assert mod.clone(Slotted(x=3)) == Slotted(x=3)
+
+
+def test_unset_slot_raises_in_both_walks():
+    """An object missing a field value is a malformed object, and both
+    walks say so the same way instead of copying it short."""
+    mod = load()
+    if mod is None:
+        pytest.skip("native toolchain unavailable")
+    bare = object.__new__(obj.Taint)
+    with pytest.raises(AttributeError):
+        mod.clone(bare)
+    with pytest.raises(AttributeError):
+        _clone(bare)

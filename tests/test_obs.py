@@ -321,6 +321,37 @@ def test_gc_pause_total_and_full_collection_span():
     assert evs[0]["args"]["collected"] >= 0
 
 
+def test_full_collections_counted_apart_and_in_metrics():
+    """A forced full collection adds one to the generation-2 count and
+    its pause to both ``gc_full_pause_s_total`` and ``gc_pause_s_total``;
+    ``Scheduler.metrics()`` carries ``gc_full_total`` and
+    ``gc_full_pause_s_total`` beside ``gc_pause_s_total``."""
+    obs.watch_gc()
+    was = gc.isenabled()
+    gc.disable()  # only the forced collection runs in between
+    try:
+        n0 = obs.gc_collections_total()
+        full0, all0 = obs.gc_full_pause_s_total(), obs.gc_pause_s_total()
+        gc.collect()
+        n1 = obs.gc_collections_total()
+        full1, all1 = obs.gc_full_pause_s_total(), obs.gc_pause_s_total()
+    finally:
+        if was:
+            gc.enable()
+    assert n1[2] == n0[2] + 1 and n1[:2] == n0[:2]
+    assert full1 > full0
+    assert all1 - all0 == pytest.approx(full1 - full0)
+    c = Cluster()
+    try:
+        c.start(profile=Profile(plugins=list(PLUGINS)), config=_config(),
+                with_pv_controller=False)
+        m = c.service.scheduler.metrics()
+    finally:
+        c.shutdown()
+    assert m["gc_full_total"] >= n1[2]
+    assert full1 <= m["gc_full_pause_s_total"] <= m["gc_pause_s_total"]
+
+
 def test_recorder_and_profiler_copies_share_one_clock(tmp_path):
     """The join rule of the obs docstring: each span's profiler copy
     starts one constant offset after its recorder stamp, so any recorder

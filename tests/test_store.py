@@ -279,3 +279,36 @@ def test_lock_wait_sum_loses_no_update_under_contention(monkeypatch):
         obs.configure(False)
         sys.setswitchinterval(old)
     assert store.lock_wait_s_total() == float(n_threads * per_thread)
+
+
+def test_shallow_evolve_shares_fields_and_refuses_undeclared():
+    """shallow_evolve copies every slot (a one-field class included),
+    shares unchanged values, and an undeclared field raises instead of
+    hanging a stray attribute on a slotted object."""
+    from minisched_tpu.state import objects as obj
+
+    p = make_pod("e")
+    spec = obj.shallow_evolve(p.spec, node_name="n1")
+    assert spec.node_name == "n1" and p.spec.node_name == ""
+    assert spec.requests is p.spec.requests
+    sel = obj.NodeSelector(node_selector_terms=[obj.NodeSelectorTerm()])
+    assert obj.shallow_evolve(sel) == sel
+    with pytest.raises(AttributeError):
+        obj.shallow_evolve(p.spec, nodename="typo")
+
+
+def test_bound_snapshots_carry_no_instance_dict():
+    """The store's bind and watch snapshots are slotted all the way down:
+    no ADDED or MODIFIED snapshot builds an instance __dict__."""
+    s = ClusterStore()
+    s.create(make_node("n1"))
+    s.create(make_pod("p1"))
+    w = s.watch(kinds=["Pod"], from_version=0)
+    assert s.bind_pods([("default/p1", "n1")]) == ["default/p1"]
+    evs = w.next_events(10, timeout=1.0)
+    assert [e.type for e in evs] == [EventType.ADDED, EventType.MODIFIED]
+    for e in evs:
+        o = e.object
+        for x in (o, o.metadata, o.spec, o.status):
+            assert not hasattr(x, "__dict__")
+    w.stop()
