@@ -11,16 +11,16 @@ CPU_MESH := XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu
 SHELL := /bin/bash
 
 .PHONY: test tier1 fault-smoke shortlist-smoke trace-smoke slo-smoke \
-        churn-smoke overload-smoke loop-smoke index-smoke journal-smoke \
+        churn-smoke overload-smoke loop-smoke journal-smoke \
         fleet-smoke fleet-proc-smoke election-smoke tenant-smoke \
-        tenant-index-smoke auction-smoke profile-smoke start \
+        auction-smoke profile-smoke start \
         start-remote \
         start-client-engine \
         demo docs \
         bench bench_sharded bench-cpu bench-pipeline bench-residency \
         bench-shortlist bench-trace bench-slo bench-churn bench-overload \
-        bench-deviceloop bench-index bench-coldstart bench-journal \
-        bench-fleet bench-tenants bench-tenant-index bench-auction \
+        bench-deviceloop bench-coldstart bench-journal \
+        bench-fleet bench-tenants bench-auction \
         bench-check dryrun dryrun-dcn soak soak-faults soak-churn \
         soak-overload
 
@@ -93,28 +93,15 @@ loop-smoke:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_device_loop.py -x -q \
 	  -p no:cacheprovider -p no:randomly
 
-# Fast deterministic maintained-index suite (~30 s): bit-identity of
-# the device-resident class-row index vs the per-batch full step in
-# every engine mode (sync/pipelined/upload/shortlist-off/device-loop),
-# raw-op build/refresh/assign exactness incl. plateau inputs, the
-# steady-state refresh-not-rebuild ledger, adversarial contention
-# repairing in-scan, unassigned-row fallback with real attribution,
-# residency-resync rebuilds, narrowing-vs-widening node updates, the
-# K-dial, and registry-overflow containment. A tier-1 prerequisite
-# after loop-smoke: the index must never change a decision.
-index-smoke:
-	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_index.py -x -q \
-	  -p no:cacheprovider -p no:randomly
-
 # Fast deterministic decision-journal suite (~60 s): journal unarmed is
 # a bit-identical no-op per engine mode (sync/pipelined/resident/
-# shortlist/loop/index), seq monotonicity holds under the two-deep
+# shortlist/loop), seq monotonicity holds under the two-deep
 # pipeline + commit-worker threads, the JSONL sink and incident bundles
 # validate against the postmortem schema (empty/unarmed included),
 # provenance records match store truth for every bound pod in a faulted
 # churn run, the journal fault gate never touches decisions, and the
 # /journal + /provenance + /timeline?since cursors hold. A tier-1
-# prerequisite after index-smoke: the black-box recorder every incident
+# prerequisite after loop-smoke: the black-box recorder every incident
 # postmortem leans on must itself be pinned.
 journal-smoke:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_journal.py -x -q \
@@ -133,10 +120,11 @@ fleet-smoke:
 
 # Fast deterministic fused multi-tenant suite (~60 s): per-tenant
 # placements bit-identical between the fused coordinator and the
-# sequential baseline in every engine config (sync/pipelined/upload/
-# index), ragged tenant batches harmonized by masked-row padding,
-# mid-tranche delta races falling back solo and counted, fair-share
-# slot apportionment never starving a tenant, provenance/journal
+# sequential baseline in every engine config (sync/pipelined/upload),
+# ragged tenant batches harmonized by masked-row padding, mid-tranche
+# delta races falling back solo and counted, fair-share and
+# bucket-major slot apportionment never starving a tenant, mixed-size
+# tenants fusing one dispatch per pod-pad bucket, provenance/journal
 # attribution never crossing tenants, and the profile-scoped shed
 # budget holding under a one-tenant overload burst. A tier-1
 # prerequisite after fleet-smoke: the mux rides the same dispatch seam
@@ -188,19 +176,6 @@ election-smoke:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_election.py -x -q \
 	  -p no:cacheprovider -p no:randomly
 
-# Indexed fused-tenant arbitration (ISSUE 20): per-tenant (C,N) slabs
-# stacked and served through ONE vmapped gather+certified-scan dispatch
-# (ops/pipeline.build_tenant_index_step), bucket-major lane grouping,
-# slab repair routing, widening ejection, and the mid-tranche race
-# gate — all pinned bit-identical to sequential per-tenant stepping
-# AND to the fused-full path per engine mode. A tier-1 prerequisite
-# after election-smoke: it composes the maintained index (index-smoke)
-# with the fused-tenant mux (tenant-smoke), so both layers must
-# already hold.
-tenant-index-smoke:
-	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_tenant_index.py -x -q \
-	  -p no:cacheprovider -p no:randomly
-
 # The EXACT ROADMAP tier-1 verify command (dots count + exit code
 # preserved) — what the driver runs after every PR; run it locally
 # before shipping. shortlist-smoke runs first: the arbitration
@@ -209,9 +184,7 @@ tenant-index-smoke:
 # slo-smoke (the actuator rides the sentinel); churn-smoke last: the
 # lifecycle oracle rides on all of them; loop-smoke after
 # overload-smoke (the ring composes with the tuner's dials and must
-# never change a decision); index-smoke after loop-smoke (the
-# maintained index composes with ring, residency, and the K-dial and
-# must never change a decision either); journal-smoke after index-smoke
+# never change a decision); journal-smoke after loop-smoke
 # (the black-box recorder hooks every layer above and must never change
 # a decision); fleet-smoke after journal-smoke (lease takeovers journal
 # their provenance through the recorder); tenant-smoke after
@@ -222,13 +195,10 @@ tenant-index-smoke:
 # supervision is the outermost layer — replicas run the full engine
 # stack, so every seam below must already hold); election-smoke after
 # fleet-proc-smoke (the elected steward replaces the parent supervisor,
-# so the supervised fleet layer must already hold); tenant-index-smoke
-# after election-smoke (the indexed fused tranche composes the
-# maintained index with the tenant mux, so index-smoke and
-# tenant-smoke must both already hold).
+# so the supervised fleet layer must already hold).
 tier1: shortlist-smoke trace-smoke slo-smoke overload-smoke loop-smoke \
-       index-smoke journal-smoke fleet-smoke tenant-smoke auction-smoke \
-       fleet-proc-smoke election-smoke tenant-index-smoke churn-smoke
+       journal-smoke fleet-smoke tenant-smoke auction-smoke \
+       fleet-proc-smoke election-smoke churn-smoke
 	set -o pipefail; rm -f /tmp/_t1.log; \
 	timeout -k 10 870 env JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q \
 	  -m 'not slow' --continue-on-collection-errors -p no:cacheprovider \
@@ -365,14 +335,12 @@ bench-check:
 	JAX_PLATFORMS=cpu $(PY) tools/bench_compare.py --capture
 	JAX_PLATFORMS=cpu $(PY) tools/bench_overload.py --check
 	JAX_PLATFORMS=cpu $(PY) tools/bench_deviceloop.py --check
-	JAX_PLATFORMS=cpu $(PY) tools/bench_index.py --check
 	JAX_PLATFORMS=cpu $(PY) tools/bench_coldstart.py --check
 	JAX_PLATFORMS=cpu $(PY) tools/bench_journal.py --check
 	JAX_PLATFORMS=cpu $(PY) tools/bench_fleet.py --check
 	JAX_PLATFORMS=cpu $(PY) tools/bench_fleet_proc.py --check
 	JAX_PLATFORMS=cpu $(PY) tools/bench_election.py --check
 	JAX_PLATFORMS=cpu $(PY) tools/bench_tenants.py --check
-	JAX_PLATFORMS=cpu $(PY) tools/bench_tenant_index.py --check
 	JAX_PLATFORMS=cpu $(PY) tools/bench_auction.py --check
 
 # Persistent device-loop before/after (the committed
@@ -386,17 +354,6 @@ bench-check:
 # (source bench-deviceloop) so `make bench-check` gates them.
 bench-deviceloop:
 	JAX_PLATFORMS=cpu $(PY) tools/bench_deviceloop.py
-
-# Maintained-index before/after (the committed BENCH_INDEX.json):
-# interleaved off/on min-of-4 rounds of the streaming phase —
-# steady-state scored rows per batch (the plugin-evaluation ledger)
-# down ≥10× at 2000 × 1000 (full P_pad·N vs the warm registry's delta
-# refresh), a paired identical-workload run diffing every placement
-# (zero divergence), hit/fallback/repair/rebuild rates reported, zero
-# certification desyncs. Stable stream keys append to BENCH_LEDGER.json
-# (source bench-index) so `make bench-check` gates them.
-bench-index:
-	JAX_PLATFORMS=cpu $(PY) tools/bench_index.py
 
 # Decision-journal contract bench (the committed BENCH_JOURNAL.json):
 # interleaved journal-off/on min-of-4 rounds — armed overhead ≤5% on
@@ -435,20 +392,6 @@ bench-fleet:
 # bench-tenants) so `make bench-check` gates them.
 bench-tenants:
 	JAX_PLATFORMS=cpu $(PY) tools/bench_tenants.py
-
-# Indexed fused-tenant before/after (the committed
-# BENCH_TENANT_INDEX.json): interleaved sequential-indexed /
-# fused-full / fused-indexed min-of-4 rounds at T=8 × 256 nodes —
-# steady-state scored rows per batch down ≥10× inside the fused
-# tranche (the slab serve scores zero rows; only the delta repair is
-# booked), the ≥5× dispatch fusion bar kept vs sequential stepping, a
-# wave-stepped replay proving every placement bit-identical PER TENANT
-# across all three modes, and a mixed-bucket round fusing ≥2 pad
-# groups with zero solo regressions. Stable keys append to
-# BENCH_LEDGER.json (source bench-tenant-index) so `make bench-check`
-# gates them.
-bench-tenant-index:
-	JAX_PLATFORMS=cpu $(PY) tools/bench_tenant_index.py
 
 # Auction-mode unification before/after (the committed
 # BENCH_AUCTION.json): interleaved split/unified min-of-4 rounds of the

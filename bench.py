@@ -1080,15 +1080,7 @@ def engine_bench(n_nodes, n_pods, make_nodes, make_pods, plugins,
                               assignment=os.environ.get(
                                   "MINISCHED_ASSIGNMENT", "greedy"),
                               loop_depth=int(os.environ.get(
-                                  "MINISCHED_LOOP_DEPTH", "8")),
-                              # maintained-index knobs likewise
-                              # (tools/bench_index.py toggles them)
-                              index=os.environ.get(
-                                  "MINISCHED_INDEX", "0") == "1",
-                              index_k=int(os.environ.get(
-                                  "MINISCHED_INDEX_K", "128")),
-                              index_classes=int(os.environ.get(
-                                  "MINISCHED_INDEX_CLASSES", "64")))
+                                  "MINISCHED_LOOP_DEPTH", "8")))
         if backoff_s is not None:
             # Skew-style convergence workloads retry revoked pods across
             # cycles; the reference's 1 s initial backoff would dominate
@@ -1329,30 +1321,9 @@ def engine_bench(n_nodes, n_pods, make_nodes, make_pods, plugins,
                     int(m.get("decision_fetches", 0)),
                 f"{prefix}_loop_depth_effective":
                     int(m.get("loop_depth_effective", 0)),
-                # Maintained arbitration index (MINISCHED_INDEX): the
-                # scored-rows ledger (pod-row × node-row plugin
-                # evaluations — the dataflow-inversion claim is the
-                # per-batch series collapsing from P_pad·N to the
-                # repair cost) plus the hit/fallback/repair/rebuild
-                # counters and the effective scan width.
+                # The scored-rows ledger: pod-row × node-row plugin
+                # evaluations over the run.
                 f"{prefix}_scored_rows": int(m.get("scored_rows_total", 0)),
-                f"{prefix}_batch_scored_rows":
-                    m.get("batch_series", {}).get("scored_rows", []),
-                f"{prefix}_index_width": int(m.get("index_width", 0)),
-                f"{prefix}_index_hits": int(m.get("index_hits", 0)),
-                f"{prefix}_index_fallbacks":
-                    int(m.get("index_fallbacks", 0)),
-                f"{prefix}_index_repair_rows":
-                    int(m.get("index_repair_rows", 0)),
-                f"{prefix}_index_rebuilds":
-                    int(m.get("index_rebuilds", 0)),
-                f"{prefix}_index_uncertified":
-                    int(m.get("index_uncertified", 0)),
-                f"{prefix}_index_races": int(m.get("index_races", 0)),
-                f"{prefix}_index_checks": int(m.get("index_checks", 0)),
-                f"{prefix}_index_cooldowns":
-                    int(m.get("index_cooldowns", 0)),
-                f"{prefix}_index_desyncs": int(m.get("index_desyncs", 0)),
                 f"{prefix}_bind_conflicts": int(m["bind_conflicts"]),
                 # revocations + terminal failures summed over cycles —
                 # the skew-convergence diagnostic (how much work the

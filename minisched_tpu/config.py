@@ -177,40 +177,6 @@ class SchedulerConfig:
     # (MINISCHED_LOOP_DEPTH). The overload tuner steps the effective
     # depth down (halved per tune step) under the ``tuned`` rung.
     loop_depth: int = 8
-    # Maintained arbitration index (MINISCHED_INDEX; ops/index.py +
-    # engine/scheduler._ArbIndex): per-pod-class score rows live on
-    # device ACROSS batches in a (C,N) matrix and the sparse delta
-    # protocol repairs them in place — steady-state batches skip the
-    # full (P,N) filter+score pass entirely (plugin-evaluated rows drop
-    # from P·N to C·changed-columns) and run only a device gather + the
-    # PR 4 certified K-compressed scan over the cached rows. Any
-    # UNASSIGNED live row discards the speculative result and
-    # re-dispatches the original full step with the same PRNG draw, so
-    # decisions are bit-identical index on/off in every engine mode
-    # (tests/test_index.py). Engages only for eligible profiles
-    # (column-local plugins, identity-normalize scorers — see
-    # ops/index.index_eligible) and index-safe batches (the loop-safe
-    # pod family). False (the default) keeps the per-batch dataflow
-    # exactly; opt-in until the TPU capture validates the win.
-    index: bool = False
-    # Indexed-scan width K (MINISCHED_INDEX_K): the per-batch top-K
-    # compression applied over the gathered class rows (the PR 4
-    # shortlist machinery — exact at ANY width, in-scan repairs absorb
-    # a narrow one). The overload tuner's K-dial retunes it live in
-    # both directions with no rebuild.
-    index_k: int = 128
-    # Max registered pod classes (MINISCHED_INDEX_CLASSES): the (C,N)
-    # matrix's class axis, pow2-bucketed. A batch whose pods exceed the
-    # registry takes the full step (counted fallback).
-    index_classes: int = 64
-    # Index certification cross-check (MINISCHED_INDEX_CHECK_EVERY):
-    # every N index-served batches, re-run the batch's exact inputs
-    # through the full step and compare decisions — catches defects
-    # OUTSIDE the certificate's proof (a scribbled index entry, broken
-    # backend gather). Divergence counts an index_desync, permanently
-    # disables the index, and aborts into the supervised replay.
-    # 0 disables.
-    index_check_every: int = 0
     # Residency carry cross-check (ROADMAP follow-up (b)): every N
     # device-resident batches, fetch the device-carried free array and
     # compare it to the host mirror BEFORE the step consumes it; a
@@ -272,10 +238,6 @@ def config_from_env() -> SchedulerConfig:
             _req("MINISCHED_SHORTLIST_CHECK_EVERY", "0")),
         device_loop=_req("MINISCHED_DEVICE_LOOP", "0") == "1",
         loop_depth=int(_req("MINISCHED_LOOP_DEPTH", "8")),
-        index=_req("MINISCHED_INDEX", "0") == "1",
-        index_k=int(_req("MINISCHED_INDEX_K", "128")),
-        index_classes=int(_req("MINISCHED_INDEX_CLASSES", "64")),
-        index_check_every=int(_req("MINISCHED_INDEX_CHECK_EVERY", "0")),
         watchdog_s=float(_req("MINISCHED_WATCHDOG", "0.0")),
         probation_batches=int(_req("MINISCHED_PROBATION_BATCHES", "8")),
         resident_check_every=int(

@@ -100,36 +100,6 @@ class DynDeltaListener:
         self.valid = False
 
 
-class IndexDeltaListener(DynDeltaListener):
-    """The maintained arbitration index's registration in the delta
-    fan-in (ops/index.py; engine/scheduler._ArbIndex): beyond the
-    dynamic-leaf ``rows`` every DynDeltaListener receives, the cache
-    classifies STATIC node mutations for it —
-
-      * a NARROWING change (cordon, taints grown, allocatable shrunk,
-        node removed — ``state.events.node_update_narrows_only``) can
-        only LOWER the changed row's scores, so it lands in
-        ``static_rows`` and the index repairs that row in place exactly
-        like a capacity debit;
-      * a WIDENING change (new node, uncordon, labels/images/capacity
-        moved, topology-domain refresh) bumps the ``inval`` epoch: the
-        consumer compares the epoch at drain time and REBUILDS — the
-        conservative rung of the index's repair ladder (a widened node
-        may rise anywhere, and a fresh node may even grow the pad past
-        the columns the index ever evaluated).
-
-    Drained together with ``rows`` by ``drain_index_rows``; the dyn
-    epoch protocol of the base class is untouched (this listener is
-    never handed to snapshot_resident)."""
-
-    __slots__ = ("static_rows", "inval")
-
-    def __init__(self):
-        super().__init__()
-        self.static_rows: set = set()
-        self.inval = 0
-
-
 def step_bucket(n: int, minimum: int = 16) -> int:
     """Padding bucket for the STEP's array shapes: power-of-two up to
     2048, then eighth-steps between octaves (2^k · (8+j)/8, j = 1..8).
@@ -308,10 +278,6 @@ class NodeFeatureCache:
         # every mutator of free/used_ports marks the touched rows into
         # each registered listener's set (see DynDeltaListener).
         self._dyn_listeners: List[DynDeltaListener] = []
-        # Maintained-index consumers (subset of _dyn_listeners): static
-        # node mutations additionally classify into narrowing row marks
-        # vs widening invalidation epochs (see IndexDeltaListener).
-        self._index_listeners: List[IndexDeltaListener] = []
 
     def register_dyn_listener(self) -> DynDeltaListener:
         """Register a consumer of the dynamic-leaf elision protocol (one
@@ -335,59 +301,6 @@ class NodeFeatureCache:
             rows = rows.tolist()
         for lst in self._dyn_listeners:
             lst.rows.update(rows)
-
-    def register_index_listener(self) -> IndexDeltaListener:
-        """Register a maintained-index consumer: receives dynamic-leaf
-        row marks like every DynDeltaListener PLUS the static-mutation
-        classification (narrowing rows vs widening invalidation epochs
-        — see IndexDeltaListener). Never unregistered."""
-        lst = IndexDeltaListener()
-        with self._lock:
-            self._dyn_listeners.append(lst)
-            self._index_listeners.append(lst)
-        return lst
-
-    def _mark_index_static_locked(self, row: int) -> None:
-        """A NARROWING static mutation touched ``row`` (caller holds
-        the lock): index consumers repair the row in place."""
-        for lst in self._index_listeners:
-            lst.static_rows.add(int(row))
-
-    def _inval_index_locked(self, cause: str = "widening") -> None:
-        """A WIDENING (or non-row-attributable) static mutation landed
-        (caller holds the lock): index consumers must rebuild. The
-        journal event names the cause so a postmortem can tell a fresh
-        node from a topology refresh when attributing a rebuild."""
-        if not self._index_listeners:
-            return
-        for lst in self._index_listeners:
-            lst.inval += 1
-        from ..obs.journal import note as _jnote
-
-        _jnote("cache.index_inval", cause=cause)
-
-    def drain_index_rows(self, lst: IndexDeltaListener):
-        """Drain an index listener's accumulated repair rows — dynamic
-        marks ∪ narrowing static marks — plus its invalidation epoch and
-        the cache ``version`` observed under the same lock hold, WITHOUT
-        touching the dyn epoch protocol. The caller must drain BEFORE
-        taking the snapshot it refreshes against (the tranche
-        validator's baseline-drain discipline) and must NOT serve
-        decisions from the index if the version moved between this
-        drain and its snapshot: a mutation in that window is marked for
-        the NEXT refresh but already inside THIS snapshot's truth, so
-        the cached score for its row would be stale exactly for the
-        batch about to consume it (the engine falls back to the full
-        step for that batch — a counted race, not a desync)."""
-        with self._lock:
-            rows = lst.rows | lst.static_rows
-            lst.rows.clear()
-            lst.static_rows.clear()
-            if not rows:
-                return np.zeros(0, dtype=np.int32), lst.inval, self.version
-            out = np.fromiter(rows, dtype=np.int32, count=len(rows))
-            out.sort()
-            return out, lst.inval, self.version
 
     def drain_dyn_rows(self, lst: DynDeltaListener):
         """Drain a listener's marked rows WITHOUT advancing its epoch or
@@ -427,21 +340,14 @@ class NodeFeatureCache:
 
     # ---- node lifecycle -------------------------------------------------
 
-    def upsert_node(self, node: Node, bound_pods=(), *,
-                    narrows_only: Optional[bool] = None) -> None:
+    def upsert_node(self, node: Node, bound_pods=()) -> None:
         """Encode (or re-encode) a node row. ``bound_pods``: pods to
         account onto the row INSIDE the same lock hold — for node
         re-creation, where pods of the previous incarnation are still
         bound to the name in the store. Accounting them after a separate
         upsert would leave a window in which a concurrent snapshot sees
         the recreated node at full free capacity and a batch over-commits
-        it; snapshot takes this lock, so atomicity follows.
-
-        ``narrows_only``: the informer path's
-        ``state.events.node_update_narrows_only`` verdict for an UPDATE
-        — True routes the static change to the index listeners as an
-        in-place row repair; False/None (unknown, or a fresh node) is
-        a widening invalidation (IndexDeltaListener contract)."""
+        it; snapshot takes this lock, so atomicity follows."""
         with self._lock:
             i = self._index.get(node.metadata.name)
             fresh_row = i is None
@@ -464,11 +370,6 @@ class NodeFeatureCache:
             self._recompute_free_row(i)
             for pod in bound_pods:
                 self._account_bind_locked(pod, node.metadata.name)
-            if narrows_only and not fresh_row:
-                self._mark_index_static_locked(i)
-            else:
-                self._inval_index_locked("fresh-node" if fresh_row
-                                         else "widening-update")
             self.version += 1
             self.static_version += 1
 
@@ -593,7 +494,6 @@ class NodeFeatureCache:
                     feats.topo_domains[:, i] = tcol
                 feats.topo_domains[0, i] = i
             if fresh:
-                self._inval_index_locked("fresh-nodes-bulk")
                 self.version += 1
                 self.static_version += 1
         for node in existing:
@@ -629,10 +529,6 @@ class NodeFeatureCache:
                     self._a_free.append(a)
                 self._drop_gang_member(k)
                 self._anti_drop_locked(k)
-            # Node removal is NARROWING for the index: the cleared row
-            # re-evaluates to statically-infeasible (valid=False → NEG)
-            # at the next refresh — an in-place repair, no rebuild.
-            self._mark_index_static_locked(i)
             self.version += 1
             self.static_version += 1
             return gone
@@ -1408,10 +1304,6 @@ class NodeFeatureCache:
             F.compute_topo_domains_row(self._feats, i, self.registry,
                                        self.cfg, keys=keys)
         self._topo_version = v
-        # Not row-attributable (every row's domain columns moved) —
-        # index-eligible plugins read no topology state, but the
-        # conservative rung is an invalidation, not a guess.
-        self._inval_index_locked("topology-refresh")
         self.static_version += 1
 
     def _recompute_free_row(self, i: int) -> None:
@@ -1451,13 +1343,10 @@ class _TenantLane:
     """One tenant engine's submitted batch inside a fusion round: the
     fully-staged step inputs (exactly what the solo dispatch would have
     consumed), the cache version recorded at submit (the race gate),
-    and the engine/_InflightBatch to hand the decision planes back to.
-    An INDEXED lane additionally carries its engine's repaired (C,N)
-    score slab + this batch's class-gather rows (idx_slab/idx_cls/
-    idx_k) — the fused-indexed serve's per-tenant payload."""
+    and the engine/_InflightBatch to hand the decision planes back to."""
 
     __slots__ = ("engine", "inf", "eb", "nf", "af", "key", "version",
-                 "w_vec", "group_key", "idx_slab", "idx_cls", "idx_k")
+                 "w_vec", "group_key")
 
 
 class TenantCacheMux:
@@ -1492,7 +1381,7 @@ class TenantCacheMux:
         step broadcasts lane 0's statics, which is the whole point:
         T tenants, one static node encoding on device.
       * per-tenant sparse deltas keep routing through each tenant's
-        own DynDeltaListener/IndexDeltaListener — every lane's engine
+        own DynDeltaListener — every lane's engine
         registered its listeners on ITS OWN cache; the mux multiplexes
         dispatch, never the delta slabs, so repairs land in the owning
         tenant's arrays by construction.
@@ -1516,13 +1405,9 @@ class TenantCacheMux:
             "tenant_fetches": 0, "tenant_fetch_bytes": 0.0,
             "tenant_groups": 0, "tenant_lanes_fused": 0,
             "tenant_races": 0, "tenant_solo_fallbacks": 0,
-            # Indexed fused-tenant serving: fused tranches that went
-            # through build_tenant_index_step (a subset of
-            # tenant_dispatches) and the lanes they carried.
             # tenant_groups_round_max is the widest single round by
             # fused-group count — the bucket-major mixed-size claim
             # ("a round fuses >=2 groups") reads it directly.
-            "tenant_index_dispatches": 0, "tenant_index_lanes": 0,
             "tenant_groups_round_max": 0,
         }
         self._static_memo: Dict[tuple, str] = {}
@@ -1579,19 +1464,12 @@ class TenantCacheMux:
 
     # ---- the round ------------------------------------------------------
 
-    def submit(self, engine, inf, eb, nf, af, key,
-               index=None) -> _TenantLane:
+    def submit(self, engine, inf, eb, nf, af, key) -> _TenantLane:
         """Stage one tenant engine's prepared batch for the round's
         fused dispatch (called from Scheduler._prepare_batch at the
         dispatch seam). Returns the lane ticket the engine parks on
         ``inf.tenant_ticket``; ``dispatch()`` fills the decision planes
-        and clears it. ``index`` is the engine's staged maintained-index
-        payload ``(score_slab, cls_pad, k_eff)`` (Scheduler.
-        _tenant_index_stage) — indexed lanes group separately from
-        full-step lanes (the group-key mode suffix: slab class-pad and
-        scan width join the compatibility contract), so a group is
-        homogeneous by construction and dispatches through
-        ops/pipeline.build_tenant_index_step."""
+        and clears it."""
         pset = engine.plugin_set
         lane = _TenantLane()
         lane.engine, lane.inf = engine, inf
@@ -1600,13 +1478,7 @@ class TenantCacheMux:
         lane.w_vec = np.asarray(
             [pset.weight_of(p) for p in pset.score_plugins],
             dtype=np.float32)
-        if index is not None:
-            lane.idx_slab, lane.idx_cls, lane.idx_k = index
-            mode = ("idx", int(lane.idx_slab.shape[0]), int(lane.idx_k))
-        else:
-            lane.idx_slab = lane.idx_cls = lane.idx_k = None
-            mode = ("full",)
-        lane.group_key = self._compat_key(engine, eb, nf, af) + mode
+        lane.group_key = self._compat_key(engine, eb, nf, af)
         self.lanes.append(lane)
         return lane
 
@@ -1626,9 +1498,9 @@ class TenantCacheMux:
             if lane.engine.cache.version != lane.version:
                 # Mid-round mutation raced the collect window. The
                 # staged inputs are immutable (the fused result would
-                # still be bit-identical), but serving speculation past
-                # a moved version is the index's race posture too —
-                # fall back solo, counted, never wrong.
+                # still be bit-identical), but a lane never serves past
+                # a moved version — fall back solo, counted, never
+                # wrong.
                 self._dispatch_solo(lane)
             else:
                 groups.setdefault(lane.group_key, []).append(lane)
@@ -1638,11 +1510,7 @@ class TenantCacheMux:
             # wider than the cap splits into consecutive fused tranches.
             cap = self.max_lanes if self.max_lanes > 0 else len(group)
             for i in range(0, len(group), cap):
-                tranche = group[i:i + cap]
-                if tranche[0].idx_slab is not None:
-                    self._dispatch_index_group(tranche)
-                else:
-                    self._dispatch_group(tranche)
+                self._dispatch_group(group[i:i + cap])
                 fused_this_round += 1
         self.counters["tenant_groups_round_max"] = max(
             self.counters["tenant_groups_round_max"], fused_this_round)
@@ -1700,63 +1568,8 @@ class TenantCacheMux:
             lane.inf.packed_dev = (
                 b[0], b[1].astype(bool), b[2].astype(bool),
                 b[3], b[4], b[6:], b[5].astype(bool))
-            lane.inf.index_free_after = free_stack[i]
+            lane.inf.free_after = free_stack[i]
             lane.inf.scored_rows += (int(lane.eb.pf.valid.shape[0])
                                      * int(lane.nf.valid.shape[0]))
             lane.inf.tenant_ticket = None
             lane.engine._sup_count("tenant_fused_lanes")
-
-    def _dispatch_index_group(self, group: List[_TenantLane]) -> None:
-        """ONE fused INDEXED dispatch: stack the group's per-tenant
-        repaired (C,N) score slabs into a (T,C,N) device buffer and run
-        the vmapped class-row gather + certified K-compressed scan
-        (ops/pipeline.build_tenant_index_step) — zero plugin
-        evaluations, one (T,·) packed fetch. Each lane's row lands on
-        ``inf.index_packed_dev`` as a HOST slice: the engine's resolve
-        settles it through the same _settle_index ladder as the solo
-        indexed dispatch (serve = fused-hit; any unassigned live row
-        discards and re-dispatches the full step with the lane's own
-        PRNG draw — bit-identity is the settle contract, not a fused
-        special case)."""
-        import jax
-        import jax.numpy as jnp
-
-        from ..faults import FAULTS
-        from ..ops.index import corrupt_slab
-        from ..ops.pipeline import build_tenant_index_step
-
-        fused_fn = build_tenant_index_step(int(group[0].idx_k))
-        slab_stack = jnp.stack([ln.idx_slab for ln in group])
-        # Fault gate: fused-indexed dispatch seam. ``corrupt``
-        # scribbles ONE tenant's stacked slab slice pre-dispatch
-        # (ops/index.corrupt_slab — the solo index gate's scheme):
-        # range-sane, invisible to the in-scan certificate, caught only
-        # by that lane's MINISCHED_INDEX_CHECK_EVERY cross-check. The
-        # maintained slab itself is untouched — the scribble poisons
-        # this round's stacked COPY, exactly a transient device defect.
-        if FAULTS.hit("tenant_index") == "corrupt":
-            n_pad = int(group[0].nf.valid.shape[0])
-            slab_stack = slab_stack.at[0].set(
-                corrupt_slab(slab_stack[0], n_pad))
-        cls_stack = jnp.stack([jnp.asarray(ln.idx_cls) for ln in group])
-        valid_stack = jnp.stack([ln.eb.pf.valid for ln in group])
-        req_stack = jnp.stack([ln.eb.pf.requests for ln in group])
-        free_stack = jnp.stack([ln.nf.free for ln in group])
-        keys = jnp.stack([ln.key for ln in group])
-        packed_stack, free_after = fused_fn(
-            slab_stack, cls_stack, valid_stack, req_stack, free_stack,
-            keys)
-        self.counters["tenant_dispatches"] += 1
-        self.counters["tenant_groups"] += 1
-        self.counters["tenant_lanes_fused"] += len(group)
-        self.counters["tenant_index_dispatches"] += 1
-        self.counters["tenant_index_lanes"] += len(group)
-        buf = np.array(packed_stack)  # ONE (T, 4P+2ceil(P/8)) fetch
-        self.counters["tenant_fetches"] += 1
-        self.counters["tenant_fetch_bytes"] += buf.nbytes
-        for i, lane in enumerate(group):
-            lane.inf.index_packed_dev = buf[i]
-            lane.inf.index_free_after = free_after[i]
-            lane.inf.tenant_ticket = None
-            lane.engine._sup_count("tenant_fused_lanes")
-            lane.engine._sup_count("tenant_index_lanes")

@@ -264,13 +264,7 @@ def _add_all_event_handlers(state: SharedClusterState,
         move_all(ClusterEvent(GVK.NODE, ActionType.ADD))
 
     def node_update(old, new):
-        # The narrowing verdict feeds TWO consumers: the requeue
-        # suppression below, and the cache's index-listener fan-in —
-        # a narrowing update repairs the maintained arbitration index
-        # in place (scores can only drop on that row), anything else
-        # is a widening invalidation (encode/cache.IndexDeltaListener).
-        narrows = node_update_narrows_only(old, new)
-        state.cache.upsert_node(new, narrows_only=narrows)
+        state.cache.upsert_node(new)
         # Drain/cordon-aware requeue (lifecycle churn): a purely
         # NARROWING update — cordon, taints grown, allocatable shrunk,
         # nothing else changed — cannot make any parked pod schedulable;
@@ -278,7 +272,7 @@ def _add_all_event_handlers(state: SharedClusterState,
         # cordon and bump every engine's move cycle (in-flight batches
         # would then route terminal verdicts to backoff, thrashing
         # forever under sustained churn). The cache still observes it.
-        if narrows:
+        if node_update_narrows_only(old, new):
             return
         move_all(watch_to_cluster_event(
             WatchEvent(EventType.MODIFIED, GVK.NODE, new, old)))

@@ -42,8 +42,6 @@ from ..obs import slo as slo_mod
 from ..obs.journal import JOURNAL, ProvenanceStore
 from ..obs.journal import note as jnote
 from ..obs.timeseries import TIMELINE, TimelineTracker
-from ..ops.index import (build_index_ops, corrupt_slab, index_eligible,
-                         unpack_index_decision)
 from ..ops.pipeline import (Decision, arm_compile_cache, build_loop_step,
                             build_step)
 from ..ops.residency import (I16_SAT, apply_rows, apply_rows_bytes,
@@ -214,10 +212,8 @@ class _InflightBatch:
                  "shapes", "seq", "t0", "t_encode", "t_dispatch",
                  "t_fetch_start", "t_step", "t_resolved", "commit_t0",
                  "commit_t1", "res_carried", "assumed", "detached",
-                 "sl_repairs", "step_share", "index_packed_dev",
-                 "index_free_after", "index_served", "scored_rows",
-                 "loop_slot", "index_mode", "tenant_ticket",
-                 "nom_reserved")
+                 "sl_repairs", "step_share", "free_after", "scored_rows",
+                 "loop_slot", "tenant_ticket", "nom_reserved")
 
     def __init__(self):
         self.failures: List[tuple] = []  # (qpi, plugins, message, retryable)
@@ -248,20 +244,11 @@ class _InflightBatch:
         # carried free INPUT; note_debits adds it back before adopting
         # free_after so the chain keeps un-nominated cache truth.
         self.nom_reserved = None
-        # Maintained-index batch (engine._ArbIndex): the fused
-        # [chosen|assigned|repaired] device buffer the resolve phase
-        # settles, and the indexed scan's carried free_after (adopted by
-        # residency only when every live row is assigned).
-        self.index_packed_dev = None
-        self.index_free_after = None
-        # True once the resolve phase settled this batch FROM the index
-        # (every row certified + assigned; no full step ran).
-        self.index_served = False
+        # A fused tenant lane's carried free_after (the batch has no
+        # Decision; the mux's dispatch fills it).
+        self.free_after = None
         # Plugin-evaluation work this batch paid, in pod-row × node-row
-        # units (the scored-rows ledger the index claims ride on): a
-        # full step books P_pad·N_pad (or P_pad·K sampled), an index
-        # refresh C_pad·R_bucket, a rebuild C_pad·N_pad, a fallback
-        # both.
+        # units: a full step books P_pad·N_pad (or P_pad·K sampled).
         self.scored_rows = 0
         # Loop-mode slot: this batch's share of its tranche's fused
         # device window (tranche window / slots). Non-None overrides
@@ -269,14 +256,12 @@ class _InflightBatch:
         # accounting — a depth-8 tranche must not book (or trip) an
         # 8-batch window against one batch's deadline.
         self.step_share: Optional[float] = None
-        # Provenance tags (obs/journal.ProvenanceStore): which ring
-        # slot served this batch (None = per-batch dispatch) and how
-        # the maintained index treated it ("off" | "hit" | "fallback").
+        # Provenance tag (obs/journal.ProvenanceStore): which ring slot
+        # served this batch (None = per-batch dispatch).
         self.loop_slot: Optional[int] = None
-        self.index_mode = "off"
         # Fused multi-tenant lane ticket (encode/cache.TenantCacheMux):
         # non-None between the prepare-phase submit and the mux's fused
-        # dispatch, which fills packed_dev/index_free_after and clears
+        # dispatch, which fills packed_dev/free_after and clears
         # it. A lane must never reach resolve with the ticket still
         # armed — the resolve phase guards it.
         self.tenant_ticket = None
@@ -548,142 +533,6 @@ class _DeviceResidency:
         self.pending_rows = self.pending_pre = None
         self.pending_prows = self.pending_ppre = None
         self.listener.invalidate()
-
-
-class _ArbIndex:
-    """Engine-side lifecycle of the maintained arbitration index
-    (ops/index.py): the pod-class registry, the pending repair-row set,
-    the device IndexState, and the rebuild ladder counters.
-
-    Invariants (asserted end to end by tests/test_index.py):
-
-      I1. every cached candidate score equals the masked_total the full
-          step would compute at that column for that class, as of the
-          snapshot of the last build/refresh. Rows whose truth moved
-          since then are in ``pending`` (the cache marks EVERY
-          free/used_ports mutation — assume, unbind, revocation,
-          informer churn — plus narrowing static changes into the
-          IndexDeltaListener; the drain happens BEFORE the snapshot a
-          refresh evaluates against, so a drained row's new truth is
-          always inside that snapshot).
-      I2. every node column NOT in ``pending`` kept exactly its
-          build/refresh-time value in the maintained (C,N) matrix —
-          its truth never moved (I1's marking completeness) — while
-          widened/unknown static changes (fresh nodes, uncordons,
-          topology refreshes) bumped the listener's ``inval`` epoch and
-          force a full rebuild before the index serves again.
-      I3. decisions are bit-identical to the index-off engine: a served
-          batch's scan is the PR 4 certified machinery over gathered
-          class rows (bit-equal inputs ⇒ bit-equal outputs, in-scan
-          repairs included); any UNASSIGNED live row discards the
-          speculative result and re-dispatches the original full step
-          with the batch's original PRNG draw.
-    """
-
-    __slots__ = ("listener", "k_base", "k_target", "n_built",
-                 "c_max", "registry", "rows", "reg_version", "state",
-                 "pending", "fresh_rows", "pending_inval", "inval_seen",
-                 "needs_rebuild", "rebuild_streak", "drain_version",
-                 "_stack_memo")
-
-    def __init__(self, listener, k: int, c_max: int):
-        self.listener = listener
-        self.k_base = k          # configured width (MINISCHED_INDEX_K)
-        self.k_target = k        # tuner-desired scan width (K-dial)
-        self.n_built = -1        # node pad the live state was built at
-        self.c_max = c_max
-        self.registry: Dict[bytes, int] = {}   # class key → class row
-        self.rows: List[dict] = []             # captured pf leaf rows
-        self.reg_version = 0
-        self.state = None                      # ops.index.IndexState
-        self.pending: Set[int] = set()         # node rows awaiting rescore
-        self.fresh_rows: List[int] = []        # class rows awaiting append
-        self.pending_inval = 0   # listener.inval at the LAST drain
-        self.inval_seen = -1     # listener.inval the live state covers
-        self.needs_rebuild = True
-        self.rebuild_streak = 0  # consecutive fallback batches (no hit)
-        self.drain_version = -1  # cache.version at the last drain
-        self._stack_memo = None  # (reg_version, stacked class_pf)
-
-    @property
-    def k_eff(self) -> int:
-        """Indexed-scan width: the tuner's live target. Any width is
-        exact (the certified scan's in-scan repairs absorb a narrow
-        one), so dial moves in either direction cost no rebuild — the
-        maintained state is the full class row, not a K-truncation."""
-        return max(1, self.k_target)
-
-    def drain(self, cache) -> None:
-        """Collect the listener's accumulated repair rows + inval epoch.
-        MUST run before the snapshot the next refresh evaluates against
-        (encode/cache.drain_index_rows discipline); the recorded cache
-        version gates serving — see _index_dispatch."""
-        rows, inval, version = cache.drain_index_rows(self.listener)
-        self.pending.update(int(r) for r in rows)
-        self.pending_inval = inval
-        self.drain_version = version
-
-    def classify(self, pf, length: int):
-        """Map batch pods → class rows, registering unseen classes.
-        The class key is the pod's FULL feature-row byte image: two pods
-        with equal rows behave identically under every column-local
-        plugin, and the engine's index-safety walk keeps batch-relative
-        leaves (gang/claim/group ids) at sentinels so keys never alias
-        across batches. Returns (cls (L,) i32, fresh: bool) or None when
-        the registry is full (the batch takes the full step)."""
-        mats = [np.ascontiguousarray(
-            getattr(pf, f)[:length]).reshape(length, -1).view(np.uint8)
-            for f in pf._fields]
-        blob = np.concatenate(mats, axis=1)
-        cls = np.empty(length, dtype=np.int32)
-        for i in range(length):
-            key = blob[i].tobytes()
-            row = self.registry.get(key)
-            if row is None:
-                if len(self.rows) >= self.c_max:
-                    return None
-                row = len(self.rows)
-                self.registry[key] = row
-                self.rows.append({f: np.copy(getattr(pf, f)[i])
-                                  for f in pf._fields})
-                self.reg_version += 1
-                # A fresh class no longer forces the O(C·N) rebuild:
-                # its row is APPENDED incrementally (ops/index.append)
-                # unless the registry crossed the class-pad bucket —
-                # _index_dispatch decides, this just records the debt.
-                self.fresh_rows.append(row)
-            cls[i] = row
-        return cls
-
-    def class_pf(self, template):
-        """The class-representative PodFeatures batch (C_pad rows, pow2
-        bucket), memoized per registry version. Pad rows are all-zero:
-        valid=False → NEG everywhere, never chosen, never bounding."""
-        if self._stack_memo and self._stack_memo[0] == self.reg_version:
-            return self._stack_memo[1]
-        c_pad = bucket_for(max(len(self.rows), 1), 16)
-        leaves = {}
-        for f in template._fields:
-            proto = self.rows[0][f]
-            arr = np.zeros((c_pad,) + proto.shape, dtype=proto.dtype)
-            for c, row in enumerate(self.rows):
-                arr[c] = row[f]
-            leaves[f] = arr
-        stacked = type(template)(**leaves)
-        self._stack_memo = (self.reg_version, stacked)
-        return stacked
-
-    def invalidate(self, reason: str) -> None:
-        """Drop the device state; the next index batch rebuilds
-        (counted). Used when the inputs a refresh consumed are no
-        longer trusted — a residency-carry desync means the attached
-        ``free`` the last refresh scored against may have been
-        corrupt."""
-        log.info("arbitration index invalidated (%s); next index batch "
-                 "rebuilds", reason)
-        jnote("index.invalidate", reason=reason)
-        self.state = None
-        self.needs_rebuild = True
 
 
 def arbitrate_rwo(batch: List[QueuedPodInfo], assigned, chosen,
@@ -1333,38 +1182,6 @@ class Scheduler:
         self._loop_listener = (self.cache.register_dyn_listener()
                                if self._loop_enabled else None)
         self._loop_cooldown = 0
-        # Maintained arbitration index (MINISCHED_INDEX; ops/index.py +
-        # _ArbIndex): per-pod-class score rows kept device-resident
-        # across batches, repaired by the cache's delta fan-in
-        # (encode/cache.register_index_listener). Gated to the greedy
-        # single-device non-explain engine — the same family as
-        # residency/loop — AND to index-eligible profiles: every active
-        # plugin column-local, no topology/affinity state, scorer
-        # normalizes row-local — identity or a declared
-        # normalize_row_local override; the maintained-max split stores
-        # pre-normalize planes and re-derives row reductions from them
-        # (ops/index.index_eligible). Decisions
-        # are bit-identical index on/off: an unassigned live row
-        # discards the whole batch's speculative result and the
-        # original full step re-runs with the same PRNG draw.
-        self._index = None
-        if (self.config.index and self.config.assignment == "greedy"
-                and self._mesh is None and not self.config.explain):
-            if index_eligible(plugin_set):
-                self._index = _ArbIndex(
-                    self.cache.register_index_listener(),
-                    self.config.index_k, self.config.index_classes)
-            else:
-                log.info("MINISCHED_INDEX=1 but profile %s is not "
-                         "index-eligible (topology/affinity state, a "
-                         "non-column-local plugin, or an undeclared "
-                         "normalize override); keeping the per-batch "
-                         "dataflow", [p.name for p in plugin_set.plugins])
-        # Rebuild-ladder cooldown (the index→rebuild→full-rescore rung
-        # composed with the PR 3 ladder): a rebuild storm parks the
-        # index for probation_batches resolved batches.
-        self._index_cooldown = 0
-        self._idx_check_tick = 0
         # Fused multi-tenant arbitration (MINISCHED_TENANTS_FUSE;
         # encode/cache.TenantCacheMux): installed by the service's
         # fusion coordinator on each tenant engine it serves. When
@@ -1531,33 +1348,9 @@ class Scheduler:
             "steps_dispatched": 0, "loop_tranches": 0,
             "loop_iterations": 0, "loop_breaks": 0,
             "decision_fetches": 0,
-            # Maintained arbitration index (MINISCHED_INDEX):
-            # index_hits counts batches served entirely from the index
-            # (no full filter+score pass ran); index_fallbacks counts
-            # index-attempted batches that re-dispatched the full step
-            # (an unassigned live row, registry overflow);
-            # index_repair_rows counts node columns rescored IN PLACE
-            # by delta refreshes; index_rebuilds counts full (C,N)
-            # rebuilds (new classes, widening invalidation, node-pad
-            # growth, post-desync); index_uncertified counts per-pod
-            # certificate failures repaired IN-SCAN by the indexed
-            # scan's exact full-row body (counted, never a fallback);
-            # index_races counts serve declines because a cache
-            # mutation raced the drain→snapshot window; the
-            # check/desync pair rides MINISCHED_INDEX_CHECK_EVERY;
-            # index_cooldowns counts fallback-storm parks (the
-            # full-rescore rung). scored_rows_total is the engine-wide
-            # plugin-evaluation ledger in pod-row × node-row units —
-            # last_scored_rows is the newest batch's share.
-            "index_hits": 0, "index_fallbacks": 0,
-            "index_repair_rows": 0, "index_rebuilds": 0,
-            "index_uncertified": 0, "index_checks": 0,
-            "index_desyncs": 0, "index_cooldowns": 0,
-            "index_races": 0,
-            # index_appends counts fresh CLASS ROWS evaluated by the
-            # incremental per-class ADD (ops/index.append) — each one
-            # an O(N) row insert that replaces an O(C·N) rebuild.
-            "index_appends": 0,
+            # scored_rows_total is the engine-wide plugin-evaluation
+            # ledger in pod-row × node-row units — last_scored_rows is
+            # the newest batch's share.
             "scored_rows_total": 0, "last_scored_rows": 0,
             # Fused multi-tenant arbitration (MINISCHED_TENANTS_FUSE):
             # tenant_fused_lanes counts batches this engine served as
@@ -1714,361 +1507,6 @@ class Scheduler:
                                     explain=self.config.explain,
                                     assignment=self.config.assignment,
                                     shortlist=None)
-
-    # ---- maintained arbitration index (MINISCHED_INDEX) ------------------
-
-    def _index_dispatch(self, inf: "_InflightBatch", batch, eb, nf, af,
-                        key, fail_closed) -> bool:
-        """Try to serve this batch from the maintained device-resident
-        index instead of the full (P,N) filter+score pass: repair the
-        (C,N) class-row state from the drained deltas (in-place rescore
-        of exactly the changed node columns; full rebuild on a widening
-        invalidation, fresh classes, or a node-pad change), then
-        dispatch the certified K-compressed scan over gathered class
-        rows speculatively. Returns True with ``inf.index_packed_dev``
-        staged (the resolve phase settles it — serve, or discard +
-        full-step re-dispatch with the same PRNG draw), False = the
-        caller dispatches the full step.
-
-        Engagement gates mirror the device loop's posture: fast-path
-        rung only (a degraded engine drops speculation first), no
-        nominations (their debits modify the step's ``free`` input
-        outside the delta protocol), no explain recorder (it needs the
-        full Decision), no armed shortlist cross-check (its attribution
-        must not be conflated with the index's own), no fail-closed
-        verdicts, and the shared per-pod safety walk. The serving gate
-        additionally requires that NO cache mutation landed between this
-        batch's delta drain and its snapshot (cache.version unchanged;
-        a raced mutation is marked for the NEXT refresh but already
-        inside THIS snapshot's truth — encode/cache.drain_index_rows) —
-        a counted race, not a desync."""
-        idx = self._index
-        if (idx is None or self._index_cooldown > 0
-                or self._sup.level != 0 or self._nominations
-                or self.recorder is not None or fail_closed
-                or self.config.shortlist_check_every
-                or not self._ring_safe_pods(batch)):
-            return False
-        if (self.cache.version != idx.drain_version
-                or idx.listener.inval != idx.pending_inval):
-            self._sup_count("index_races")
-            return False
-        cls = idx.classify(eb.pf, len(batch))
-        if cls is None:
-            # Class registry full — counted fallback, never an error.
-            self._sup_count("index_fallbacks")
-            return False
-        # Fault gate: maintained-index dispatch seam. ``corrupt``
-        # scribbles one index entry AFTER the refresh below — a defect
-        # the in-scan certificate cannot see (the scribbled score IS the
-        # certificate's input); only the MINISCHED_INDEX_CHECK_EVERY
-        # full-step cross-check can catch it (tests/test_faults.py).
-        act = FAULTS.hit("index")
-        n_pad = int(nf.valid.shape[0])
-        k_eff = idx.k_eff
-        rebuild = (idx.state is None or idx.needs_rebuild
-                   or idx.pending_inval != idx.inval_seen
-                   or idx.n_built != n_pad)
-        build_fn, refresh_fn, append_fn, assign_fn = build_index_ops(
-            self.plugin_set, k_eff, cfg=self.cache.cfg)
-        class_pf = idx.class_pf(eb.pf)
-        c_pad = int(class_pf.valid.shape[0])
-        if (not rebuild and idx.fresh_rows
-                and c_pad != int(idx.state.score.shape[0])):
-            # Fresh classes crossed the class-pad bucket: the maintained
-            # (C,N) matrix cannot hold the appended rows in place — the
-            # ONE fresh-class case that still pays the full rebuild.
-            rebuild = True
-        if rebuild:
-            # Cause precedence: a moved inval epoch wins (the widening
-            # mutation forced this rebuild regardless of what else is
-            # pending); a never-built index (n_built sentinel) is cold;
-            # a dropped state with a prior build is an explicit
-            # invalidate() (residency desync / attach error); then
-            # node-pad growth; else the class-pad growth above (an
-            # IN-BUCKET fresh class appends instead — index_appends).
-            cause = ("widening-invalidation"
-                     if idx.pending_inval != idx.inval_seen
-                     else "cold" if idx.n_built == -1
-                     else "invalidated" if idx.state is None
-                     else "node-pad" if idx.n_built != n_pad
-                     else "class-pad")
-            with span("index.build", classes=len(idx.rows), n=n_pad):
-                idx.state = build_fn(class_pf, nf, af)
-            idx.n_built = n_pad
-            idx.inval_seen = idx.pending_inval
-            idx.pending.clear()
-            idx.fresh_rows.clear()
-            idx.needs_rebuild = False
-            self._sup_count("index_rebuilds")
-            jnote("index.rebuild", profile=self.profile, replica=self.replica, cause=cause,
-                  classes=len(idx.rows), n=n_pad, batch=self._batch_seq)
-            inf.scored_rows += c_pad * n_pad
-        else:
-            self._index_repair_slab(idx, inf, class_pf, nf, af,
-                                    refresh_fn, append_fn, c_pad, n_pad)
-        if act == "corrupt" and idx.state is not None:
-            # Scribbled index entries (ops/index.corrupt_slab — the
-            # scheme the tenant_index gate shares): range-sane, a
-            # perfectly ordinary score to the scan's certificate,
-            # decision-wrong.
-            st = idx.state
-            idx.state = st._replace(
-                score=corrupt_slab(st.score, n_pad))
-        cls_pad = np.zeros((int(eb.pf.valid.shape[0]),), dtype=np.int32)
-        cls_pad[:len(batch)] = cls
-        with span("index.assign", pods=len(batch), k=k_eff):
-            packed, free_after = assign_fn(
-                idx.state, cls_pad, eb.pf.valid, eb.pf.requests,
-                nf.free, key)
-        self._sup_count("steps_dispatched")
-        inf.index_packed_dev = packed
-        inf.index_free_after = free_after
-        return True
-
-    def _index_repair_slab(self, idx: "_ArbIndex", inf: "_InflightBatch",
-                           class_pf, nf, af, refresh_fn, append_fn,
-                           c_pad: int, n_pad: int, *,
-                           fused: bool = False) -> None:
-        """Bring a live (C,N) slab to THIS snapshot's truth without a
-        rebuild: in-place rescore of exactly the drained changed node
-        columns (narrowing repairs), then scatter-in any fresh class
-        rows still inside the class-pad bucket. Shared by the solo
-        indexed dispatch and the fused-lane staging — the fused path
-        journals ``index.slab_repair`` so the repair's routing to the
-        owning tenant's slab slice stays attributable."""
-        if idx.pending:
-            rows = np.fromiter(idx.pending, dtype=np.int64,
-                               count=len(idx.pending))
-            rows.sort()
-            rows = rows[rows < n_pad]  # pad growth forces rebuild
-            idx.pending.clear()
-            if rows.size:
-                rb = bucket_for(int(rows.size), 16)
-                rows_pad = np.full((rb,), n_pad, dtype=np.int32)
-                rows_pad[:rows.size] = rows
-                with span("index.refresh", rows=int(rows.size)):
-                    idx.state = refresh_fn(idx.state, class_pf, nf,
-                                           af, rows_pad)
-                self._sup_count("index_repair_rows", int(rows.size))
-                jnote("index.slab_repair" if fused else "index.repair",
-                      profile=self.profile, replica=self.replica,
-                      rows=int(rows.size), batch=self._batch_seq)
-                inf.scored_rows += c_pad * rb
-        if idx.fresh_rows:
-            # Incremental per-class ADD (the ROADMAP's named cheap
-            # win): evaluate only the fresh class rows over the
-            # full node axis and scatter them in — the refresh
-            # above (if any) already brought every PRE-EXISTING
-            # row's changed columns to current truth, and a fresh
-            # row's full-axis evaluation against THIS snapshot
-            # matches what the rebuild would have computed for it.
-            n_fresh = len(idx.fresh_rows)
-            rb = bucket_for(n_fresh, 16)
-            rows_pad = np.full((rb,), c_pad, dtype=np.int32)
-            rows_pad[:n_fresh] = np.asarray(idx.fresh_rows,
-                                            dtype=np.int32)
-            idx.fresh_rows.clear()
-            with span("index.append", rows=n_fresh):
-                idx.state = append_fn(idx.state, class_pf, nf, af,
-                                      rows_pad)
-            self._sup_count("index_appends", n_fresh)
-            jnote("index.append", profile=self.profile, replica=self.replica,
-                  rows=n_fresh, batch=self._batch_seq)
-            inf.scored_rows += rb * n_pad
-
-    def _tenant_index_stage(self, inf: "_InflightBatch", batch, eb, nf,
-                            af):
-        """Stage this fused lane's maintained-index serve: bring the
-        engine's OWN (C,N) slab to current truth — narrowing repairs
-        column-patch the owning slab slice in place, in-bucket fresh
-        classes append — and hand the mux the slab plus this batch's
-        class-gather rows, so the lane rides ONE fused indexed dispatch
-        (ops/pipeline.build_tenant_index_step) instead of the vmapped
-        full O(P·N) pass. Three outcomes: a ``(score_slab, cls_pad,
-        k_eff)`` payload (serve fused-indexed); None (ride fused-FULL —
-        no live/cooling index, a counted delta-protocol race, or a full
-        class registry; never a stale serve); or ``"eject"`` — a repair
-        that cannot be expressed as a slab patch (widening
-        invalidation, cold/invalidated state, node-pad growth,
-        class-pad crossing) drops the lane from the fused group THIS
-        round, counted + journaled, and it rebuilds through its own
-        solo indexed dispatch below the tenant seam."""
-        idx = self._index
-        if idx is None or self._index_cooldown > 0:
-            return None
-        if (self.cache.version != idx.drain_version
-                or idx.listener.inval != idx.pending_inval):
-            self._sup_count("index_races")
-            return None
-        cls = idx.classify(eb.pf, len(batch))
-        if cls is None:
-            # Class registry full — counted fallback, never an error.
-            self._sup_count("index_fallbacks")
-            return None
-        n_pad = int(nf.valid.shape[0])
-        rebuild = (idx.state is None or idx.needs_rebuild
-                   or idx.pending_inval != idx.inval_seen
-                   or idx.n_built != n_pad)
-        _build_fn, refresh_fn, append_fn, _assign_fn = build_index_ops(
-            self.plugin_set, idx.k_eff, cfg=self.cache.cfg)
-        class_pf = idx.class_pf(eb.pf)
-        c_pad = int(class_pf.valid.shape[0])
-        if (not rebuild and idx.fresh_rows
-                and c_pad != int(idx.state.score.shape[0])):
-            rebuild = True
-        if rebuild:
-            # Same cause precedence as the solo dispatch; the rebuild
-            # itself happens there (this lane leaves the fused group).
-            cause = ("widening-invalidation"
-                     if idx.pending_inval != idx.inval_seen
-                     else "cold" if idx.n_built == -1
-                     else "invalidated" if idx.state is None
-                     else "node-pad" if idx.n_built != n_pad
-                     else "class-pad")
-            self._sup_count("index_lane_ejects")
-            jnote("index.lane_eject", profile=self.profile,
-                  replica=self.replica, cause=cause,
-                  batch=self._batch_seq)
-            return "eject"
-        self._index_repair_slab(idx, inf, class_pf, nf, af, refresh_fn,
-                                append_fn, c_pad, n_pad, fused=True)
-        cls_pad = np.zeros((int(eb.pf.valid.shape[0]),), dtype=np.int32)
-        cls_pad[:len(batch)] = cls
-        return (idx.state.score, cls_pad, idx.k_eff)
-
-    def _settle_index(self, inf: "_InflightBatch") -> None:
-        """Settle a speculatively index-dispatched batch (resolve phase,
-        BEFORE anything consumes a decision): fetch the fused
-        [chosen | assigned | repaired] buffer in ONE transfer. Every
-        live row assigned ⇒ serve the batch from the indexed scan
-        (index hit: no full filter+score pass ran; in-scan certificate
-        repairs are EXACT and merely counted — index_uncertified). An
-        UNASSIGNED live row — the failure path needs the per-plugin
-        reject attribution the index doesn't compute — discards the
-        speculative result wholesale and re-dispatches the ORIGINAL
-        full step with the batch's original PRNG draw, so decisions are
-        bit-identical to the index-off engine in every case (I3)."""
-        idx = self._index
-        p_pad = int(inf.eb.pf.valid.shape[0])
-        # A fused-indexed lane arrives with its row of the mux's ONE
-        # stacked (T,·) fetch already on the host (a numpy slice) — the
-        # group fetch was counted once at the mux, not per lane.
-        fused = isinstance(inf.index_packed_dev, np.ndarray)
-        with span("fetch.index"):
-            buf = np.array(inf.index_packed_dev)
-        inf.index_packed_dev = None
-        if not fused:
-            self._count_fetch(buf.nbytes)
-            self._sup_count("decision_fetches")
-        chosen, assigned, repaired = unpack_index_decision(buf, p_pad)
-        L = len(inf.batch)
-        if bool(assigned[:L].all()):
-            n_f = len(self.filter_names)
-            # Synthesized decision tuple: gang/feasibility/reject planes
-            # are never consulted for a batch whose every row is
-            # assigned (the resolve failure paths read them only for
-            # unassigned rows, and index-safe batches carry no gangs).
-            # The repaired plane rides in the shortlist slot — the
-            # indexed scan's repairs ARE PR 4 repair rescans.
-            inf.packed_dev = (
-                chosen.astype(np.int32), assigned,
-                np.zeros((p_pad,), dtype=bool),
-                np.ones((p_pad,), dtype=np.int32),
-                np.ones((p_pad,), dtype=np.int32),
-                np.zeros((n_f, p_pad), dtype=np.int32),
-                repaired)
-            inf.index_served = True
-            inf.index_mode = "fused-hit" if fused else "hit"
-            if idx is not None:
-                idx.rebuild_streak = 0
-            self._sup_count("index_hits")
-            if fused:
-                self._sup_count("index_fused_hits")
-                jnote("index.fused_serve", profile=self.profile,
-                      replica=self.replica, pods=L, batch=inf.seq)
-            self._sup_count("index_uncertified", int(repaired[:L].sum()))
-            self._check_index(inf, chosen, assigned)
-            return
-        # Fallback: the original full-row body applied to the whole
-        # batch — the engine-level repair rung of the ladder.
-        self._sup_count("index_fallbacks")
-        inf.index_mode = "fallback"
-        jnote("index.fallback", profile=self.profile, replica=self.replica, batch=inf.seq)
-        inf.index_free_after = None
-        if idx is not None:
-            idx.rebuild_streak += 1
-            if idx.rebuild_streak >= max(2, self.config.probation_batches):
-                # Rebuild/fallback storm: park the index for a probation
-                # of resolved batches (the ladder's full-rescore rung) —
-                # sustained contention past K is cheaper served by the
-                # plain full step than by paying speculation + fallback
-                # per batch.
-                idx.rebuild_streak = 0
-                self._index_cooldown = max(1, self.config.probation_batches)
-                self._sup_count("index_cooldowns")
-                instant("index.cooldown",
-                        batches=self._index_cooldown)
-                jnote("index.cooldown", profile=self.profile, replica=self.replica,
-                      batches=self._index_cooldown, batch=inf.seq)
-        with batch_step(inf.seq), span("step.dispatch", seq=inf.seq):
-            decision = self._step(inf.eb, inf.nf, inf.af, inf.key)
-        self._sup_count("steps_dispatched")
-        inf.decision = decision
-        inf.packed_dev = self._pack_dec(decision)
-        inf.scored_rows += p_pad * int(inf.nf.valid.shape[0])
-
-    def _check_index(self, inf: "_InflightBatch", chosen,
-                     assigned) -> None:
-        """Every ``index_check_every`` index-SERVED batches, re-run this
-        batch's exact inputs through the full step and compare decisions
-        — the maintained-index twin of _check_shortlist, covering
-        defects OUTSIDE the certificate's proof (a scribbled index entry
-        — the ``index:corrupt`` gate — or a broken backend gather).
-        Divergence counts an index_desync, permanently disables the
-        index, and aborts into the supervised replay, which re-runs the
-        batch bit-identically on the index-off path."""
-        if not self.config.index_check_every:
-            return
-        self._idx_check_tick += 1
-        if self._idx_check_tick % self.config.index_check_every:
-            return
-        self._sup_count("index_checks")
-        check_step = build_step(self.plugin_set,
-                                explain=self.config.explain,
-                                assignment=self.config.assignment,
-                                shortlist=self._shortlist_k)
-        d = check_step(inf.eb, inf.nf, inf.af, inf.key)
-        ref_c = np.asarray(d.chosen)
-        ref_a = np.asarray(d.assigned)
-        self._count_fetch(ref_c.nbytes + ref_a.nbytes)
-        L = len(inf.batch)
-        if (np.array_equal(chosen[:L], ref_c[:L])
-                and np.array_equal(assigned[:L], ref_a[:L])):
-            return
-        bad = int(np.sum((chosen[:L] != ref_c[:L])
-                         | (assigned[:L] != ref_a[:L])))
-        self._sup_count("index_desyncs")
-        instant("index.desync", pods=bad)
-        jnote("index.desync", profile=self.profile, replica=self.replica, pods=bad,
-              batch=inf.seq)
-        self._disable_index(
-            f"decisions diverged from the full step on {bad} pod(s)")
-        raise EngineDesync(
-            "maintained-index certification cross-check failed: "
-            f"decisions diverged from the full step on {bad} pod(s)")
-
-    def _disable_index(self, reason: str) -> None:
-        """Permanently revert to the per-batch dataflow (the shortlist
-        revert idiom): the registered listener keeps accumulating marks
-        harmlessly; nothing ever consumes them again."""
-        log.error("disabling the maintained arbitration index (%s); "
-                  "reverting to the per-batch full step", reason)
-        jnote("index.disable", profile=self.profile, replica=self.replica, reason=reason,
-              batch=self._batch_seq)
-        bundle_mod.capture("index_revert", scheduler=self,
-                           reason=reason)
-        self._index = None
 
     def _count_h2d(self, nbytes: int) -> None:
         with self._metrics_lock:
@@ -2329,8 +1767,7 @@ class Scheduler:
               mode="pipelined" if self.config.pipeline else "sync",
               resident=bool(self._residency is not None),
               shortlist_k=int(self._shortlist_k or 0),
-              loop=bool(self._loop_enabled),
-              index=bool(self._index is not None))
+              loop=bool(self._loop_enabled))
         self._thread = threading.Thread(target=self.run, daemon=True,
                                         name="scheduling-loop")
         self._thread.start()
@@ -2820,12 +2257,11 @@ class Scheduler:
         when SelectorSpread runs (owner groups read the corpus too), and
         no pod a running pod's anti class repels (its class slots read
         the corpus and set the pod rows' width batch by batch).
-        The per-pod walk is shared with the maintained arbitration
-        index (_ring_safe_pods — the same host-state-independence
-        property gates both fast paths). A batch the per-batch path
-        would node-SAMPLE is unsafe as well — the ring runs the full
-        axis and sampling draws a different key path, so fusing it
-        would change decisions."""
+        The per-pod walk is shared with tenant fusion (_ring_safe_pods —
+        the same host-state-independence property gates both fast
+        paths). A batch the per-batch path would node-SAMPLE is unsafe
+        as well — the ring runs the full axis and sampling draws a
+        different key path, so fusing it would change decisions."""
         if not self._ring_safe_pods(batch):
             return False
         n_pad = self._node_pad(self.cache.rows_high_water())
@@ -2835,7 +2271,7 @@ class Scheduler:
 
     def _ring_safe_pods(self, batch: List[QueuedPodInfo]) -> bool:
         """The per-pod half of the fast-path safety walk, shared by the
-        device loop's work ring and the maintained arbitration index."""
+        device loop's work ring and tenant fusion (_tenant_fusable)."""
         for q in batch:
             pod = q.pod
             s = pod.spec
@@ -2861,14 +2297,12 @@ class Scheduler:
     def _tenant_fusable(self, batch: List[QueuedPodInfo], hard_spread: bool,
                         fail_closed) -> bool:
         """Per-batch fusion gates for the multi-tenant vmapped step —
-        the index/loop posture: fast rung only (a degraded engine drops
+        the loop posture: fast rung only (a degraded engine drops
         speculation first), no nominations (their debits modify the
         step's free input outside the fused staging), no explain
         recorder, no armed shortlist cross-check (its attribution must
-        stay per-batch; the INDEX cross-check is allowed when the
-        index is live — it certifies the fused-indexed serve exactly
-        as it certifies the solo one), no fail-closed verdicts, no
-        hard-spread host arbitration, and the shared per-pod safety
+        stay per-batch), no fail-closed verdicts, no hard-spread host
+        arbitration, and the shared per-pod safety
         walk (no gangs / topology / volumes / ports / pod-affinity /
         owner groups — which also keeps spread_dev None, matching the
         sequential engine). Gated-out batches dispatch solo inside
@@ -2877,9 +2311,7 @@ class Scheduler:
                 or self.config.explain or self.recorder is not None):
             return False
         if (self._sup.level != 0 or self._nominations or fail_closed
-                or hard_spread or self.config.shortlist_check_every
-                or (self.config.index_check_every
-                    and self._index is None)):
+                or hard_spread or self.config.shortlist_check_every):
             return False
         return self._ring_safe_pods(batch)
 
@@ -3436,14 +2868,6 @@ class Scheduler:
             p_req = max(p_req, self._tenant_mux.round_pods)
         vol_memo, fail_closed, eb = self._encode_batch(batch, pods, p_req,
                                                        seq=seq)
-        if self._index is not None:
-            # Baseline-drain the index listener BEFORE the snapshot the
-            # refresh evaluates against (encode/cache.drain_index_rows
-            # discipline): a mutation landing between this drain and the
-            # snapshot is caught by the version gate in _index_dispatch
-            # and costs one counted full-step fallback, never a stale
-            # serve.
-            self._index.drain(self.cache)
         # Versioned snapshot: the static version is observed under the
         # snapshot lock (the snapshot's own topology refresh can bump it),
         # and the cache skips host copies of static leaves we already hold
@@ -3507,11 +2931,6 @@ class Scheduler:
                 self._sup.escalate("resident carry desync")
                 carried = False
                 res.drop("carry cross-check mismatch")
-                if self._index is not None:
-                    # The index's last refresh scored against the
-                    # now-distrusted carried free — rebuild (counted)
-                    # before the index serves again.
-                    self._index.invalidate("resident carry desync")
                 cached = self._nf_static_device
                 nf, names, static_v, row_incs = (
                     self.cache.snapshot_versioned(
@@ -3524,8 +2943,6 @@ class Scheduler:
                               "through a full snapshot")
                 carried = False
                 res.drop("attach error")
-                if self._index is not None:
-                    self._index.invalidate("residency attach error")
                 cached = self._nf_static_device
                 nf, names, static_v, row_incs = (
                     self.cache.snapshot_versioned(
@@ -3604,42 +3021,11 @@ class Scheduler:
         # inputs instead of dispatching — the mux issues ONE vmapped
         # step over every submitted lane (encode/cache.TenantCacheMux.
         # dispatch) and fills this lane's decision planes before the
-        # coordinator resolves it. Checked BEFORE the index seam: the
-        # fused full step is bit-identical to the indexed serve
-        # (invariant I3), so decisions match the sequential engine in
-        # index mode too — and the index listener keeps draining above,
-        # so its protocol is untouched for batches that fall back.
-        fuse_lane = (self._tenant_mux is not None and sample_k is None
-                     and self._tenant_fusable(batch, hard_spread,
-                                              fail_closed))
-        idx_payload = None
-        if fuse_lane and self._index is not None:
-            # Indexed fused-tenant arbitration: stage this lane's OWN
-            # repaired (C,N) slab for the mux's stacked (T,C,N) indexed
-            # dispatch. A rebuild-class repair ejects the lane from the
-            # fused group this round (counted) and routes it to its
-            # solo indexed dispatch below.
-            idx_payload = self._tenant_index_stage(inf, batch, eb, nf,
-                                                   af)
-            if idx_payload == "eject":
-                fuse_lane = False
-                idx_payload = None
-        if fuse_lane:
+        # coordinator resolves it.
+        if (self._tenant_mux is not None and sample_k is None
+                and self._tenant_fusable(batch, hard_spread, fail_closed)):
             inf.tenant_ticket = self._tenant_mux.submit(
-                self, inf, eb, nf, af, key, index=idx_payload)
-            decision = None
-            packed_dev = None
-            spread_dev = None
-        # Maintained arbitration index (MINISCHED_INDEX): serve the
-        # batch's arbitration from the device-resident (C,N) class rows
-        # — repaired from this prepare's drained deltas — instead of
-        # dispatching the full (P,N) filter+score pass. Speculative: the
-        # resolve phase settles it and re-dispatches the full step with
-        # the SAME PRNG draw on any unassigned live row.
-        elif (self._index is not None and sample_k is None
-              and self._mesh is None
-              and self._index_dispatch(inf, batch, eb, nf, af, key,
-                                       fail_closed)):
+                self, inf, eb, nf, af, key)
             decision = None
             packed_dev = None
             spread_dev = None
@@ -3724,10 +3110,6 @@ class Scheduler:
             # The loop→pipelined rung's probation: one clean resolved
             # batch pays one cooldown tick (scheduling thread only).
             self._loop_cooldown -= 1
-        if self._index_cooldown > 0:
-            # The index ladder's full-rescore rung pays down the same
-            # way: one clean resolved batch per cooldown tick.
-            self._index_cooldown -= 1
         if TIMELINE.enabled:
             self._timeline_tick()
 
@@ -3791,11 +3173,6 @@ class Scheduler:
                                     explain=self.config.explain,
                                     assignment=self.config.assignment,
                                     shortlist=want)
-        idx = self._index
-        if idx is not None and idx.k_target != idx.k_base:
-            # Restore the configured indexed-scan width (free — exact
-            # at any width, no state rebuild involved).
-            idx.k_target = idx.k_base
         n = self.queue.release_shed()
         log.info("overload controller disarmed at runtime; actuation "
                  "neutralized (%d shed pod(s) released)", n)
@@ -3840,19 +3217,6 @@ class Scheduler:
                                     explain=self.config.explain,
                                     assignment=self.config.assignment,
                                     shortlist=want)
-        # Maintained-index K-dial (same tuner verdicts, applied to the
-        # INDEXED-SCAN width): live and free in both directions — the
-        # maintained state is the full class row, so any width is exact
-        # (in-scan certificate repairs absorb a narrow one) and
-        # ops/index.build_index_ops memoizes per width, so dial
-        # revisits recompile nothing.
-        idx = self._index
-        if idx is not None:
-            want_k = ov.shortlist_target(idx.k_base)
-            if want_k is not None and want_k != idx.k_target:
-                log.warning("overload tuner: index scan K %d -> %d",
-                            idx.k_target, want_k)
-                idx.k_target = want_k
         # Brownout quality shed: stretch the timeline cadence while
         # level 3 holds (restored on recovery).
         self._timeline.stretch = ov.timeline_stretch
@@ -3900,8 +3264,8 @@ class Scheduler:
 
     def _prov_path(self, inf: "_InflightBatch") -> dict:
         """The batch-scoped half of a provenance record: the path that
-        served this batch — engine mode, ring slot, ladder rungs, index
-        posture, shortlist width, residency posture — computed once per
+        served this batch — engine mode, ring slot, ladder rungs,
+        shortlist width, residency posture — computed once per
         resolved batch (journal armed only) and shared by every pod the
         batch settles."""
         return {
@@ -3915,7 +3279,6 @@ class Scheduler:
             "loop_slot": inf.loop_slot,
             "rung": DEGRADATION_LADDER[self._sup.level],
             "resident": bool(inf.res_carried),
-            "index": inf.index_mode,
             "shortlist_k": int(self._shortlist_k or 0),
             "overload_level": self._overload.level,
             "decided_unix": round(time.time(), 3),
@@ -4053,11 +3416,6 @@ class Scheduler:
             raise EngineDesync(
                 "fused tenant lane reached resolve with its ticket "
                 "still armed (mux.dispatch did not run)")
-        if inf.index_packed_dev is not None:
-            # Settle the speculative indexed scan: serve (index hit — no
-            # full pass ran this batch) or discard + full-step
-            # re-dispatch with the original PRNG draw (_settle_index).
-            self._settle_index(inf)
         decision, row_incs = inf.decision, inf.row_incs
         # decision is None for a loop-mode slot (the tranche resolver
         # pre-unpacked the stacked fetch); the filter count is a static
@@ -4070,7 +3428,7 @@ class Scheduler:
         # Supervisor fetch-sanity detector — BEFORE the residency replay
         # trusts ``chosen``: a corrupted readback (defective transport,
         # injected fetch:corrupt) must abort the batch, not poison the
-        # carried mirror or index past the name table.
+        # carried mirror past the name table.
         L0 = len(batch)
         if assigned[:L0].any():
             ch = chosen[:L0][assigned[:L0]]
@@ -4093,8 +3451,8 @@ class Scheduler:
             self._check_shortlist(inf, chosen, assigned)
             inf.sl_repairs += int(sl_repaired[:L0].sum())
         sp = self._fetch_spread(spread_dev)
-        # Provenance path (journal armed only): computed AFTER the index
-        # settle (index_mode is final) and before any placement stamp.
+        # Provenance path (journal armed only): computed before any
+        # placement stamp.
         self._prov_batch = (self._prov_path(inf) if JOURNAL.enabled
                             else None)
         if inf.res_carried:
@@ -4103,13 +3461,12 @@ class Scheduler:
             # before the residual merge mutates chosen/assigned (the
             # carried array is the main step's output; residual/repair
             # placements reach the device as next-batch corrections).
-            # An index-SERVED batch has no Decision — its carried array
-            # is the indexed scan's free_after, bit-equal to the full
-            # scan's (identical debit op sequence over the same carry).
+            # A fused tenant lane has no Decision — its carried array
+            # is its slice of the fused step's free_after.
             res = self._residency
             res.note_debits(chosen, assigned, eb.pf.requests,
                             decision.free_after if decision is not None
-                            else inf.index_free_after,
+                            else inf.free_after,
                             add_back=inf.nom_reserved)
             # ROADMAP residency follow-up (d): model the batch's
             # host-port insertions on the device-resident used_ports
@@ -4582,10 +3939,8 @@ class Scheduler:
             m["shortlist_repairs"] += inf.sl_repairs
             m["shortlist_certified"] += max(0,
                                             len(batch) - inf.sl_repairs)
-            # Maintained-index scored-rows ledger: plugin-evaluation
-            # work this batch paid (pod-row × node-row units) — the
-            # full step's P_pad·N, a refresh's C_pad·R_bucket, a
-            # rebuild's C_pad·N, a fallback's sum of both.
+            # Scored-rows ledger: plugin-evaluation work this batch
+            # paid (pod-row × node-row units) — the full step's P_pad·N.
             m["scored_rows_total"] += inf.scored_rows
             if inf.failures:
                 # Encode-vs-flush overlap, booked HERE where the flush
@@ -5467,16 +4822,6 @@ class Scheduler:
         # steps it down under ``tuned``).
         out["loop_depth_effective"] = (self._effective_loop_depth()
                                        if self._loop_enabled else 0)
-        # Maintained arbitration index gauges: the effective scan width
-        # (0 = off — knob, profile ineligibility, or a certification
-        # desync disabled it), the registered pod-class count, and the
-        # batches left on the full-rescore cooldown rung.
-        idx = self._index
-        out["index_width"] = (int(idx.k_eff) if idx is not None
-                              and idx.state is not None else 0)
-        out["index_classes_registered"] = (len(idx.rows)
-                                           if idx is not None else 0)
-        out["index_cooldown_left"] = int(self._index_cooldown)
         # Existing-pod anti-affinity plane: live anti classes in the
         # cache, and the padded class-slot width of the last batch
         # encoded (0 = no pod of it was repelled: the filter compiled

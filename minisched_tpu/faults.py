@@ -79,15 +79,6 @@ production scheduler's failure domain spans:
                 overload level; the rebalancer's plausibility clamp +
                 the no-flap hysteresis detect and discard it — counted,
                 zero moves minted from a scribble).
-    tenant_index  fused-indexed tenant dispatch seam (encode/cache.
-                TenantCacheMux._dispatch_index_group) — ``corrupt``
-                scribbles ONE tenant's slice of the stacked (T,C,N)
-                score slab pre-dispatch (ops/index.corrupt_slab, the
-                solo ``index`` gate's scheme): range-sane, invisible
-                to the in-scan certificate, caught only by that lane's
-                MINISCHED_INDEX_CHECK_EVERY full-step cross-check —
-                which parks ONLY that tenant's index and replays the
-                batch bit-identically through the supervised ladder.
 
 Configured once per process from ``MINISCHED_FAULTS`` (tests reconfigure
 via :func:`configure`), a comma-separated list of ``gate:action@trigger``
@@ -146,25 +137,27 @@ from .obs.journal import note as _journal_note
 
 log = logging.getLogger(__name__)
 
-#: The gate catalog; hit() rejects unknown names so a typo in a rule or a
-#: call site cannot silently never fire.
-# New gates append LAST: per-gate PRNG streams seed by catalog index,
-# so appending (never inserting) keeps every existing gate's firing
-# pattern stable under a fixed seed. auction_mirror sits inside
-# _DeviceResidency.note_debits: corrupt scribbles one node's aggregate
-# debit — certificate-invisible by construction (the decision already
-# left the device), so only the MINISCHED_RESIDENT_CHECK_EVERY
-# cross-check can catch it. proc sits on the process-fleet lifecycle
-# seams (fleet/procfleet.py): spawn, replica heartbeat, and the
-# replica-side batch seam where ``die`` becomes a real SIGKILL.
-# election sits on the steward-election seams (fleet/election.py):
-# the CAS claim/renew call and the burn-signal heartbeat publication.
-# tenant_index sits on the fused-indexed tenant dispatch seam
-# (encode/cache.py): the stacked (T,C,N) slab, pre-dispatch.
-GATES = ("step", "fetch", "residency", "shortlist_repair", "commit",
-         "bind", "informer", "http", "checkpoint", "lifecycle",
-         "admission", "index", "journal", "lease", "auction_mirror",
-         "proc", "election", "tenant_index")
+#: The gate catalog, each gate with the offset that seeds its PRNG
+#: stream; hit() rejects unknown names so a typo in a rule or a call site
+#: cannot silently never fire. Offsets are fixed per gate, never derived
+#: from catalog position: a new gate takes the next unused offset, and a
+#: retired gate's offset stays unused (11 and 17 are retired), so every
+#: remaining gate's firing pattern under a fixed seed never moves.
+#: auction_mirror sits inside _DeviceResidency.note_debits: corrupt
+#: scribbles one node's aggregate debit — certificate-invisible by
+#: construction (the decision already left the device), so only the
+#: MINISCHED_RESIDENT_CHECK_EVERY cross-check can catch it. proc sits on
+#: the process-fleet lifecycle seams (fleet/procfleet.py): spawn, replica
+#: heartbeat, and the replica-side batch seam where ``die`` becomes a
+#: real SIGKILL. election sits on the steward-election seams
+#: (fleet/election.py): the CAS claim/renew call and the burn-signal
+#: heartbeat publication.
+_GATE_SEED = {"step": 0, "fetch": 1, "residency": 2, "shortlist_repair": 3,
+              "commit": 4, "bind": 5, "informer": 6, "http": 7,
+              "checkpoint": 8, "lifecycle": 9, "admission": 10,
+              "journal": 12, "lease": 13, "auction_mirror": 14, "proc": 15,
+              "election": 16}
+GATES = tuple(_GATE_SEED)
 
 _ACTIONS = ("err", "die", "corrupt", "stall")
 
@@ -291,8 +284,8 @@ class FaultRegistry:
             # Per-gate PRNG streams: one gate's firing pattern must not
             # shift when another gate's rule is added/removed, or a
             # "same seed" soak re-run stops being a re-run.
-            self._rng = {g: random.Random((seed << 8) ^ i)
-                         for i, g in enumerate(GATES)}
+            self._rng = {g: random.Random((seed << 8) ^ _GATE_SEED[g])
+                         for g in GATES}
             self._calls = {g: 0 for g in GATES}
             self._fires = {g: 0 for g in GATES}
             self.enabled = bool(self._rules)

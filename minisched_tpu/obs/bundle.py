@@ -1,9 +1,9 @@
 """Auto-captured incident bundles — the black-box recorder's crash dump.
 
 When the engine crosses one of its terminal containment transitions —
-quarantine (the fault ladder's bottom rung), a permanent index or
-shortlist certification revert, brownout entry (the overload ladder's
-deepest rung), or a lifecycle invariant violation — the state that
+quarantine (the fault ladder's bottom rung), a permanent shortlist
+certification revert, brownout entry (the overload ladder's deepest
+rung), or a lifecycle invariant violation — the state that
 explains it is spread across four live surfaces (journal ring, timeline
 ring, trace rings, metrics dict) that keep moving after the incident.
 This module freezes all of them into one atomically-renamed bundle

@@ -2,11 +2,10 @@
 per-pod decision provenance.
 
 The engine's control machinery — the supervisor ladder (PR 3), the
-overload ladder (PR 10), the maintained index's repair ladder (PR 12),
-the device loop's break-out path (PR 11), the residency protocol
-(PR 2) — already *detects* every state transition it takes, but only
-*counts* them: after an incident the metrics say ``index_fallbacks=3,
-loop_breaks=1, escalations=2`` and nothing says which batch rode which
+overload ladder (PR 10), the device loop's break-out path (PR 11), the
+residency protocol (PR 2) — already *detects* every state transition it
+takes, but only *counts* them: after an incident the metrics say
+``loop_breaks=1, escalations=2`` and nothing says which batch rode which
 path or what caused what. This module is the black-box recorder real
 control planes carry: a lock-light, bounded, process-wide **journal**
 receiving one typed, monotonic-seq event at every transition the engine
@@ -39,8 +38,8 @@ authoritative catalog):
              observable, never able to reorder history)
     t / unix monotonic seconds since arming / wall clock
     kind     e.g. ``supervisor.escalate``, ``overload.recover``,
-             ``index.fallback``, ``loop.break``, ``fault.step``,
-             ``slo.burn``, ``queue.shed``, ``invariant.violation``
+             ``loop.break``, ``fault.step``, ``slo.burn``,
+             ``queue.shed``, ``invariant.violation``
     thread   recording thread's name
     ...      per-kind causal tags (profile, batch, step, from/to rung,
              reason, gate, slot, pods, ...)
@@ -54,9 +53,9 @@ journal event per fire; gating that one would recurse).
 
 Per-pod provenance: :class:`ProvenanceStore` is the bounded LRU beside
 the explain resultstore — each bound/failed pod's compact record of the
-path that served it (engine mode, loop slot or per-batch, index
-hit/fallback, shortlist certified/repaired, residency posture,
-attempts, shed stamps, overload/degradation level at decision time),
+path that served it (engine mode, loop slot or per-batch, shortlist
+certified/repaired, residency posture, attempts, shed stamps,
+overload/degradation level at decision time),
 recorded by the engine only while the journal is armed and served via
 ``GET /provenance/<pod>``.
 """

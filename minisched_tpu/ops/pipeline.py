@@ -783,63 +783,6 @@ def build_tenant_step(plugin_set: PluginSet, *,
     return fused
 
 
-_TENANT_INDEX_CACHE: dict = {}
-
-
-def build_tenant_index_step(k_eff: int):
-    """Compile the FUSED INDEXED tenant step (ISSUE 20 tentpole): one
-    jitted program that vmaps the maintained-index serve — per-pod
-    class-row gather out of a stacked (T, C, N) slab buffer + the PR 4
-    certified K-compressed scan — over a leading tenant axis, so one
-    dispatch serves T index-eligible tenant lanes with ZERO plugin
-    evaluations (the slabs already hold every lane's finalized scores;
-    weights were folded in by each lane's own build/refresh).
-
-    Returns ``tenant_index_step(slab_stack, cls_stack, valid_stack,
-    req_stack, free_stack, keys) -> (packed_stack, free_after_stack)``
-    where ``slab_stack`` is the (T, C, N) stack of per-tenant
-    ``IndexState.score`` matrices (every lane in a compat group shares
-    C/N/K — the mux's index group key pins it), ``cls_stack`` the
-    (T, P) per-batch class-gather rows, and the rest the per-lane scan
-    inputs the solo ``ops/index.assign`` consumes. Each lane's u8
-    output row is the EXACT solo assign pack
-    ([chosen i32 × P | assigned bits | repaired bits] —
-    ``unpack_index_decision`` unpacks a (T, ·) fetch row-by-row), and
-    per-lane values are bit-identical to the solo assign on the same
-    inputs/key: the body is the same trace (vmap of gather / scan /
-    elementwise ops preserves per-lane values on CPU and TPU alike).
-
-    Plugin-free by construction, so the memo keys on ``k_eff`` alone:
-    every profile whose slabs were built at the same K shares this one
-    compile across all its shape buckets."""
-    if k_eff < 1:
-        raise ValueError(f"index scan width {k_eff} must be >= 1")
-    cache_key = (k_eff, "tenant_index_step")
-    cached = _TENANT_INDEX_CACHE.get(cache_key)
-    if cached is not None:
-        return cached
-
-    def lane(score_slab, cls, valid, requests, free0, key):
-        # The solo assign body verbatim (ops/index.build_index_ops):
-        # identical gather, identical certified scan, identical pack —
-        # bit-identity per lane is inherited, not re-proved.
-        scores_p = jnp.where(valid[:, None], score_slab[cls], NEG)
-        n = free0.shape[0]
-        r = greedy_assign_shortlist(scores_p, requests, free0, key,
-                                    k=min(k_eff, n))
-        packed = jnp.concatenate([
-            jax.lax.bitcast_convert_type(r.chosen.astype(jnp.int32),
-                                         jnp.uint8).reshape(-1),
-            jnp.packbits(r.assigned.astype(jnp.uint8)),
-            jnp.packbits(r.repaired.astype(jnp.uint8)),
-        ])
-        return packed, r.free_after
-
-    fused = jax.jit(jax.vmap(lane))
-    _TENANT_INDEX_CACHE[cache_key] = fused
-    return fused
-
-
 #: Where the persistent compilation cache lives when
 #: JAX_COMPILATION_CACHE_DIR is unset: one fixed path inside the checkout
 #: (listed in .gitignore). The path is part of each entry's key, so a

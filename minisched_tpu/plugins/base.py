@@ -50,26 +50,6 @@ class BatchedPlugin:
     # math may assume such rejections are curable by evicting victims;
     # every other filter stays a hard blocker for the preemptor.
     capacity_only: bool = False
-    # filter/score at node column n read ONLY that node's feature column
-    # (no reduction or gather over the node axis, no ctx state derived
-    # from other nodes). The maintained arbitration index (ops/index.py)
-    # may then re-evaluate a changed column in isolation and get the
-    # full-matrix value bitwise. FAIL-CLOSED default: a plugin must
-    # explicitly declare True to unlock the index for its profile — a
-    # new plugin that couples columns and forgets the declaration must
-    # degrade to the per-batch dataflow, never to stale certified
-    # decisions.
-    column_local: bool = False
-    # ``normalize`` row i reads ONLY row i of (scores, feasible) — any
-    # in-row reduction (max/min/sum) is fine, coupling ACROSS pod rows
-    # is not. The maintained index (ops/index.py) recomputes normalize
-    # from its stored raw planes, so row-local overrides stay
-    # index-eligible; a cross-row normalize would make one class row's
-    # cached value depend on which OTHER classes share the matrix.
-    # FAIL-CLOSED like column_local: the flag only matters for plugins
-    # that OVERRIDE normalize (the inherited identity is trivially
-    # row-local), and such a plugin must explicitly declare True.
-    normalize_row_local: bool = False
 
     # -- event interest (drives requeue gating, reference
     #    minisched/initialize.go:140-157 + nodenumber.go:66-70)

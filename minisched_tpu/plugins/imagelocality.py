@@ -9,7 +9,6 @@ from .base import BatchedPlugin
 
 class ImageLocality(BatchedPlugin):
     name = "ImageLocality"
-    column_local = True  # reduces over IMAGE axes only, per node column
 
     def score(self, pf, nf, ctx) -> jnp.ndarray:
         want = pf.images[:, :, None, None]       # (P,I,1,1)

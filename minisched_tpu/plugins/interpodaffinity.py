@@ -39,8 +39,6 @@ class InterPodAffinity(BatchedPlugin):
     name = "InterPodAffinity"
     default_weight = 2.0  # upstream default
     needs_topology = True
-    column_local = False  # reads corpus-derived domain counts
-    normalize_row_local = True  # per-row min/max shift-and-scale
 
     def events_to_register(self):
         return [ClusterEvent(GVK.POD, ActionType.ALL),

@@ -41,8 +41,6 @@ def group_preferred_score(naf, nf) -> jnp.ndarray:
 class NodeAffinity(BatchedPlugin):
     name = "NodeAffinity"
     needs_node_affinity = True
-    column_local = False  # group-match state + max-normalized score
-    normalize_row_local = True  # max_normalize_100 reads its own row
 
     def events_to_register(self):
         return [ClusterEvent(GVK.NODE, ActionType.ADD | ActionType.UPDATE_NODE_LABEL)]

@@ -10,7 +10,6 @@ from .base import BatchedPlugin
 
 class NodeName(BatchedPlugin):
     name = "NodeName"
-    column_local = True  # per-column name-hash equality
 
     def filter(self, pf, nf, ctx) -> jnp.ndarray:
         wanted = pf.required_node[:, None]

@@ -24,7 +24,6 @@ from .base import BatchedPlugin
 
 class NodeNumber(BatchedPlugin):
     name = "NodeNumber"
-    column_local = True  # per-column suffix equality, identity normalize
 
     def __init__(self, permit_delay: bool = True, timeout_s: float = 10.0):
         self._permit_delay = permit_delay

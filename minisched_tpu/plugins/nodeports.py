@@ -10,7 +10,6 @@ from .base import BatchedPlugin
 
 class NodePorts(BatchedPlugin):
     name = "NodePorts"
-    column_local = True  # reads only nf.used_ports per column
 
     def events_to_register(self):
         return [ClusterEvent(GVK.POD, ActionType.DELETE),

@@ -22,7 +22,6 @@ from .base import BatchedPlugin
 
 class NodePreferAvoidPods(BatchedPlugin):
     name = "NodePreferAvoidPods"
-    column_local = True  # reads only nf.avoid_pods per column
     default_weight = 10000.0
 
     def events_to_register(self):

@@ -35,7 +35,6 @@ class NodeResourcesFit(BatchedPlugin):
     default), "MostAllocated", or None to disable the score point."""
 
     name = "NodeResourcesFit"
-    column_local = True  # per-column free/allocatable math only
     # Rejections are purely free-vs-request on the accounted axes —
     # exactly what evicting victims credits back (preemption-curable).
     capacity_only = True
@@ -76,7 +75,6 @@ class _AllocationScorer(BatchedPlugin):
     configurable scored-resource set (upstream's `resources` plugin arg;
     defaults to cpu+memory like upstream)."""
 
-    column_local = True  # per-column utilization math only
 
     def __init__(self, resources=DEFAULT_SCORED_RESOURCES):
         self._resources = tuple(resources)

@@ -24,7 +24,6 @@ _UNSCHED_PAIR_HASH = F.pair_hash(_UNSCHED_KEY, "")
 
 class NodeUnschedulable(BatchedPlugin):
     name = "NodeUnschedulable"
-    column_local = True  # reads only nf.unschedulable per column
 
     def events_to_register(self):
         # Upstream registers {Node, Add | UpdateNodeTaint}.

@@ -28,9 +28,6 @@ _VOL = RESOURCE_INDEX["attachable-volumes"]
 
 class NodeVolumeLimits(BatchedPlugin):
     name = "NodeVolumeLimits"
-    # NOT column-local: the pinned-claim surcharge compares against the
-    # node AXIS POSITION (arange over N) — see VolumeRestrictions.
-    column_local = False
 
     def events_to_register(self):
         # Freed attachments (pod delete) or raised limits (node update).
@@ -70,7 +67,6 @@ class CloudVolumeLimits(BatchedPlugin):
     per-claim-per-node like the generic axis) — two pods sharing one typed
     claim on a node consume two slots, a documented simplification."""
 
-    column_local = True  # per-column axis compare only
 
     def __init__(self):
         self._axis = RESOURCE_INDEX[self.axis_name]

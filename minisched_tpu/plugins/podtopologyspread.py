@@ -31,8 +31,6 @@ class PodTopologySpread(BatchedPlugin):
     name = "PodTopologySpread"
     default_weight = 2.0  # upstream default
     needs_topology = True
-    column_local = False  # reads corpus-derived domain counts
-    normalize_row_local = True  # max_normalize_100 reads its own row
 
     def events_to_register(self):
         return [ClusterEvent(GVK.POD, ActionType.ALL),

@@ -42,8 +42,6 @@ _NODE_WEIGHT = 1.0 - _ZONE_WEIGHT
 class SelectorSpread(BatchedPlugin):
     name = "SelectorSpread"
     needs_topology = True
-    column_local = False  # reads corpus-derived domain counts
-    normalize_row_local = True  # max_normalize_100 reads its own row
 
     def events_to_register(self):
         # Population changes on any pod lifecycle event; zone/hostname

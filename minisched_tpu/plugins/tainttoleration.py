@@ -14,12 +14,6 @@ from .base import BatchedPlugin
 
 class TaintToleration(BatchedPlugin):
     name = "TaintToleration"
-    # Per-column taint matching; the min-shift normalize below reads
-    # only its own row, so the maintained index can recompute it from
-    # stored raw counts — profiles running this plugin are
-    # index-eligible since the maintained-max split (ops/index.py).
-    column_local = True
-    normalize_row_local = True
     default_weight = 3.0  # upstream default weight
 
     def events_to_register(self):

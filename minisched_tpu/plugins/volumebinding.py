@@ -13,7 +13,6 @@ from .base import BatchedPlugin
 
 class VolumeBinding(BatchedPlugin):
     name = "VolumeBinding"
-    column_local = True  # column-uniform broadcast of pf.volumes_ready
 
     def events_to_register(self):
         return [ClusterEvent(GVK.PERSISTENT_VOLUME_CLAIM,

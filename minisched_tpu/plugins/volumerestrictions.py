@@ -21,11 +21,6 @@ from .base import BatchedPlugin
 
 class VolumeRestrictions(BatchedPlugin):
     name = "VolumeRestrictions"
-    # NOT column-local: the filter compares claim rows against the node
-    # AXIS POSITION (arange over N), which a gathered re-evaluation does
-    # not preserve (the sampling path remaps claim_rows for this; the
-    # index does not).
-    column_local = False
 
     def events_to_register(self):
         # A pod deletion can release a claim; a PVC update can rebind it.

@@ -21,7 +21,6 @@ from .base import BatchedPlugin
 
 class VolumeZone(BatchedPlugin):
     name = "VolumeZone"
-    column_local = True  # reads nf.topo_domains per column (gather-safe)
     needs_topology = False  # uses the raw domain table, not group counts
 
     def events_to_register(self):

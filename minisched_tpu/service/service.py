@@ -496,15 +496,11 @@ class TenantFusionCoordinator:
          invariant: pad rows are invalid and change no real row's
          decision).
       3. Each engine's prepare runs — a fusable batch SUBMITS its
-         staged step inputs to the mux (an index-armed engine stages
-         its repaired (C,N) slab alongside — the fused-INDEXED lane);
-         anything gated out (gangs, nominations, degraded rungs,
-         sampling, explain, mesh, spread) dispatches solo inside
-         prepare exactly as before.
+         staged step inputs to the mux; anything gated out (gangs,
+         nominations, degraded rungs, sampling, explain, mesh, spread)
+         dispatches solo inside prepare exactly as before.
       4. ``mux.dispatch()`` fires one vmapped step per compatibility
-         group — the full vmapped pass for full lanes, the stacked
-         (T,C,N) indexed gather+scan for indexed lanes — and hands
-         every lane its decision planes.
+         group and hands every lane its decision planes.
       5. Resolve + commit per tenant, in tenant order — each engine's
          own settlement machinery, journal/provenance attribution
          riding the engine's profile label as always; a lane's resolve
@@ -656,8 +652,8 @@ class TenantFusionCoordinator:
                 eng._resolve_batch(inf)
             except Exception:
                 # Per-lane containment, the engine's own resolve-guard
-                # idiom: a resolve fault (e.g. the index cross-check's
-                # EngineDesync on a scribbled fused slab) engages THAT
+                # idiom: a resolve fault (e.g. a fetch-sanity
+                # EngineDesync on a corrupted readback) engages THAT
                 # engine's supervised replay — rewound, escalated,
                 # re-run bit-identically on its degraded solo rung —
                 # while the round's other tenants settle undisturbed.

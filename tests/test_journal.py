@@ -4,7 +4,7 @@ tools/postmortem).
 The acceptance bar this file pins: with ``MINISCHED_JOURNAL`` unset the
 journal, provenance, and bundle hooks are no-ops (decisions
 bit-identical armed-vs-unarmed across sync/pipelined/resident/
-shortlist/device-loop/index engine modes; the hot path pays one
+shortlist/device-loop engine modes; the hot path pays one
 attribute test); armed, every control-machinery transition lands as a
 typed, monotonic-seq event (monotonic across the pipelined scheduling +
 commit-worker + binder threads), every bound pod's provenance record
@@ -214,7 +214,6 @@ def _run_burst(config, n_pods=N_PODS, settle_s=60):
     {"device_resident": False},      # upload-every-batch + i32 fetch
     {"shortlist": False},            # full-width scan
     {"device_loop": True, "loop_depth": 4},   # fused work ring
-    {"index": True, "index_classes": 64},     # maintained index
 ])
 def test_decisions_bit_identical_journal_on_off(mode):
     """MINISCHED_JOURNAL armed vs unarmed must not move a single

@@ -3,7 +3,7 @@
 The acceptance bar this file pins: a TenantFusionCoordinator serving T
 virtual clusters from ONE vmapped dispatch per round makes decisions
 BIT-IDENTICAL to stepping each tenant sequentially — in every engine
-config (sync/pipelined/upload/index), for ragged tenant batch sizes
+config (sync/pipelined/upload), for ragged tenant batch sizes
 (masked-row padding), and across mid-tranche delta races (counted solo
 fallbacks). Attribution never crosses tenants (provenance/journal rows
 carry the owning tenant's profile), fair-share slot apportionment never
@@ -25,7 +25,8 @@ import pytest
 
 from minisched_tpu.config import SchedulerConfig
 from minisched_tpu.engine import overload
-from minisched_tpu.engine.queue import weighted_gather
+from minisched_tpu.encode.cache import step_bucket
+from minisched_tpu.engine.queue import bucket_major_quotas, weighted_gather
 from minisched_tpu.service.service import (Tenant, TenantFusionCoordinator,
                                            tenants_fuse_from_env)
 from minisched_tpu.state import objects as obj
@@ -140,6 +141,36 @@ def test_zero_weight_tenant_gets_only_leftovers():
     assert weighted_gather([20, 10], [1, 0], 12) == [12, 0]
 
 
+# ---- bucket-major slot apportionment (engine/queue.bucket_major_quotas) ---
+
+
+def test_bucket_major_quotas_groups_and_apportions():
+    """Tenants group by their pod-pad bucket in ascending-bucket order;
+    each group runs the full weighted_gather discipline over the round
+    capacity; zero-demand tenants are absent."""
+    demands = [5, 0, 40, 8, 30]
+    weights = [1.0, 1.0, 2.0, 1.0, 1.0]
+    buckets = [16, 0, 48, 16, 48]
+    out = bucket_major_quotas(demands, weights, 24, buckets)
+    assert [b for b, _i, _q in out] == [16, 48]
+    b16, b48 = out
+    assert b16[1] == [0, 3] and b16[2] == [5, 8]      # demand-capped
+    assert b48[1] == [2, 4]
+    assert b48[2] == weighted_gather([40, 30], [2.0, 1.0], 24)
+    assert sum(b48[2]) == 24                           # work-conserving
+    for _b, idxs, quotas in out:
+        assert all(q <= demands[i] for i, q in zip(idxs, quotas))
+
+
+def test_bucket_major_quotas_single_bucket_matches_global_gather():
+    """Homogeneous demand degenerates to the ISSUE 16 global gather —
+    the backward-compatibility property the bit-identity tests lean
+    on."""
+    demands, weights = [10, 10, 10], [1.0, 1.0, 1.0]
+    out = bucket_major_quotas(demands, weights, 12, [16, 16, 16])
+    assert out == [(16, [0, 1, 2], weighted_gather(demands, weights, 12))]
+
+
 # ---- fused vs sequential bit-identity -------------------------------------
 
 
@@ -147,7 +178,6 @@ def test_zero_weight_tenant_gets_only_leftovers():
     ("sync", dict(pipeline=False)),
     ("pipelined", dict(pipeline=True)),
     ("upload", dict(device_resident=False)),
-    ("index", dict(index=True, index_classes=32)),
 ])
 def test_fused_matches_sequential_per_mode(mode, kw):
     """The tentpole claim: per tenant, the fused coordinator's
@@ -257,57 +287,82 @@ def test_provenance_and_journal_attribution_stay_per_tenant():
         journal_mod.configure("")
 
 
-def test_fused_indexed_provenance_and_journal_stay_per_tenant():
-    """ISSUE 20 leakage probe: with the maintained index armed, warm
-    batches serve fused-INDEXED — provenance records carry index
-    posture ``fused-hit`` under the OWNING tenant's profile only, and
-    the new index.fused_serve / index.slab_repair / index.lane_eject
-    journal events are tagged with the owning tenant's profile, never a
-    peer's."""
-    from minisched_tpu.obs import journal as journal_mod
+# ---- bucket-major grouping: mixed-size tenants fuse per bucket ------------
 
-    journal_mod.configure("1")
-    names = ["t0", "t1"]
-    tenants = [Tenant(name=nm, store=_mk_store()) for nm in names]
-    coord = TenantFusionCoordinator(
-        tenants, _config(index=True, index_classes=32), fuse=8)
-    try:
-        coord.start()
-        # Wave 1 pays each lane's cold rebuild (a counted lane
-        # ejection to the solo indexed path); wave 2 serves from the
-        # warm stacked slabs.
-        for nm in names:
-            coord.store(nm).create_many(_pods(5, nm))
-        _wait_bound(coord, names, 10)
-        for nm in names:
-            coord.store(nm).create_many(_pods(5, f"{nm}-w2"))
-        _wait_bound(coord, names, 20)
-        m = coord.metrics()
-        assert m["tenant_index_dispatches"] >= 1, m
-        fused_hits = 0
-        for nm, other in (("t0", "t1"), ("t1", "t0")):
-            assert m[f"{nm}_index_fused_hits"] >= 1, m
-            for i in range(5):
-                key = f"default/{nm}-w2-p{i}"
-                rec = coord.engine(nm).provenance(key)
-                assert rec is not None, key
-                assert rec["profile"] == nm, rec
-                assert rec["index"] in ("fused-hit", "hit", None), rec
-                fused_hits += rec["index"] == "fused-hit"
-                assert coord.engine(other).provenance(key) is None, key
-        assert fused_hits >= 1
-        entries = journal_mod.JOURNAL.entries()
-        for kind, required in (("index.fused_serve", True),
-                               ("index.lane_eject", True),
-                               ("index.slab_repair", False)):
-            profs = {e.get("profile") for e in entries
-                     if e["kind"] == kind}
-            if required:
-                assert profs, kind
-            assert profs <= set(names), (kind, profs)
-    finally:
-        coord.shutdown()
-        journal_mod.configure("")
+
+def _wait_pending(coord, names, counts, timeout=30.0):
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        got = [coord.engine(nm).queue.pending_count() for nm in names]
+        if got == list(counts):
+            return
+        time.sleep(0.02)
+    raise AssertionError(f"pending {got}, wanted {counts}")
+
+
+def _flat_pods(n, tag, *, cpu0=100):
+    """Pods of 8 cycled request shapes: constant priority and a
+    non-digit name tail (name_suffix stays -1), so only the request
+    shape distinguishes them."""
+    return [obj.Pod(
+        metadata=obj.ObjectMeta(name=f"{tag}-{i}x", namespace="default"),
+        spec=obj.PodSpec(requests={"cpu": float(cpu0 + 17 * (i % 8))},
+                         priority=0))
+        for i in range(n)]
+
+
+def _drain_rounds(coord):
+    while any(eng.queue.pending_count()
+              for eng in coord.engines.values()):
+        if not coord.serve_round():
+            time.sleep(0.02)
+
+
+def test_mixed_bucket_round_fuses_two_groups():
+    """Heterogeneous tenant sizes (two tenants at a small pod bucket,
+    two at a large one) no longer pad to one global bucket: one serve
+    round issues one fused dispatch PER bucket group — >=2 groups, zero
+    solo regressions — and placements equal the sequential
+    coordinator's. A warm-up wave binds first, so the mixed round
+    meets nodes that already carry load."""
+    names = [f"t{i}" for i in range(4)]
+    counts = (3, 3, 20, 20)   # buckets: step_bucket(3)=16, step_bucket(20)=24
+    warm = 8                  # one pod per class row
+    assert step_bucket(3, 16) != step_bucket(20, 16)
+
+    def scenario(fuse):
+        tenants = [Tenant(name=nm, store=_mk_store()) for nm in names]
+        # Capacity >= the widest bucket group's total demand (20+20), so
+        # the large tenants pop their FULL backlog in the mixed round
+        # and genuinely pad to the 24-bucket while the small tenants pad
+        # to 16 — two shape groups in one round.
+        coord = TenantFusionCoordinator(
+            tenants, _config(max_batch_size=48), fuse=fuse)
+        try:
+            for eng in coord.engines.values():
+                eng._shared.ensure_started()
+            for nm in names:
+                coord.store(nm).create_many(_flat_pods(warm, f"{nm}-warm"))
+            _wait_pending(coord, names, (warm,) * len(names))
+            _drain_rounds(coord)
+            _wait_bound(coord, names, warm * len(names))
+            for nm, n in zip(names, counts):
+                coord.store(nm).create_many(_flat_pods(n, nm))
+            _wait_pending(coord, names, counts)
+            assert coord.serve_round()
+            _drain_rounds(coord)
+            return (_wait_bound(coord, names,
+                                warm * len(names) + sum(counts)),
+                    coord.metrics())
+        finally:
+            coord.shutdown()
+
+    seq, _ = scenario(0)
+    fused, m = scenario(8)
+    assert fused == seq
+    assert m["tenant_groups_round_max"] >= 2, m
+    assert m["tenant_solo_fallbacks"] == 0, m
+    assert m["tenant_lanes_fused"] >= 4, m
 
 
 # ---- per-tenant shed budgets (MINISCHED_OVERLOAD profile overrides) -------

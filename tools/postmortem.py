@@ -47,7 +47,7 @@ _RECOVERY_KINDS = ("supervisor.recover", "overload.recover",
                    "slo.clear", "steward.respawn", "store.reattach")
 
 #: Kinds that belong to a chain between its fault root and recovery.
-_CHAIN_PREFIXES = ("supervisor.", "overload.", "index.", "shortlist.",
+_CHAIN_PREFIXES = ("supervisor.", "overload.", "shortlist.",
                    "residency.", "loop.", "watchdog.", "slo.",
                    "queue.", "bundle.", "invariant.", "lease.",
                    "fleet.", "proc.", "engine.", "steward.",

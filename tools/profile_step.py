@@ -118,35 +118,6 @@ def main() -> None:
           f"{min(sl_k, n_pad) if sl_k else n_pad} per step; "
           "MINISCHED_SHORTLIST / MINISCHED_SHORTLIST_K)", flush=True)
 
-    # Maintained arbitration index (MINISCHED_INDEX, ops/index.py):
-    # posture + the scored-rows model at THIS shape — the raw-op twin
-    # of the engine's live health counters (metrics(): index_hits /
-    # index_fallbacks = hit fraction, index_repair_rows = in-place
-    # repairs, index_rebuilds = certified-stale rebuilds, and the
-    # scored-rows ledger scored_rows_total).
-    from minisched_tpu.ops.index import build_index_ops, index_eligible
-    idx_eligible = index_eligible(pset)
-    if not cfg_env.index:
-        print("index: off (MINISCHED_INDEX unset — every batch pays the "
-              f"full P*N filter+score pass: {p_pad * n_pad} scored "
-              "rows/batch at this shape)", flush=True)
-    elif not idx_eligible:
-        print("index: MINISCHED_INDEX=1 but this profile is not "
-              "index-eligible (topology/affinity state or a "
-              "row-normalizing scorer) — per-batch dataflow kept",
-              flush=True)
-    else:
-        from minisched_tpu.encode.cache import bucket_for
-        c_pad = bucket_for(min(len(pods), cfg_env.index_classes), 16)
-        r_b = bucket_for(min(p_pad, n_pad), 16)
-        print(f"index: ON k={cfg_env.index_k} classes<= "
-              f"{cfg_env.index_classes} — steady-state scored rows/batch "
-              f"{c_pad}x{r_b}={c_pad * r_b} (refresh of <= {r_b} changed "
-              f"columns over {c_pad} class rows) vs full "
-              f"{p_pad}x{n_pad}={p_pad * n_pad} "
-              f"({p_pad * n_pad / (c_pad * r_b):.1f}x; rebuild batches "
-              f"pay {c_pad}x{n_pad}={c_pad * n_pad})", flush=True)
-
     # Overload-control posture (MINISCHED_OVERLOAD, engine/overload.py):
     # the actuation each ladder rung would apply AT THIS SHAPE — the
     # attribution row for a run whose /metrics shows overload_level > 0.
@@ -223,28 +194,6 @@ def main() -> None:
     slim = timed("slim_fetch_s", lambda: np.array(pack_decision_slim(
         d.chosen, d.assigned, d.gang_rejected, d.feasible_counts,
         d.feasible_static, d.reject_counts, d.shortlist_repaired)))
-    if cfg_env.index and idx_eligible:
-        # Maintained-index raw-op phases at a 64-class registry: one
-        # full (C,N) build, one 64-column delta refresh (the
-        # steady-state batch cost), and the indexed scan (gather + the
-        # certified K-compressed scan — zero plugin evaluations).
-        c_model = min(64, p_pad)
-        class_pf = type(eb.pf)(*[np.asarray(getattr(eb.pf, f))[:c_model]
-                                 for f in eb.pf._fields])
-        b_fn, r_fn, ap_fn, a_fn = build_index_ops(pset, cfg_env.index_k)
-        state = timed("index_build_s", lambda: b_fn(class_pf, nf, af))
-        rb = min(64, n_pad)
-        rows_pad = np.arange(rb, dtype=np.int32)
-        timed("index_refresh_s",
-              lambda: r_fn(state, class_pf, nf, af, rows_pad))
-        cls = (np.arange(p_pad) % c_model).astype(np.int32)
-        ap_rows = np.arange(min(16, c_model), dtype=np.int32)
-        timed("index_append_s",
-              lambda: ap_fn(state, class_pf, nf, af, ap_rows))
-        timed("index_assign_s",
-              lambda: a_fn(state, cls, eb.pf.valid, eb.pf.requests,
-                           nf.free, key)[0])
-
     # Per-batch transfer budget, both residency modes (engine counters
     # measure the same quantities live; this is the shape-exact model):
     dyn_h2d = nf.free.nbytes + nf.used_ports.nbytes
@@ -360,59 +309,6 @@ def main() -> None:
         print(f"tenants: wall {fused_s:.4f} s fused vs {seq_s:.4f} s "
               f"sequential ({seq_s / max(fused_s, 1e-9):.2f}x; a CPU run "
               "proves the dispatch ledger, not a device time)", flush=True)
-
-        if idx_eligible:
-            # Indexed-fused raw op (ISSUE 20): T per-tenant (C,N) score
-            # slabs stacked into ONE (T,C,N) device buffer, served by
-            # one vmapped class-row gather + certified K-compressed
-            # scan (ops/pipeline.build_tenant_index_step) — zero plugin
-            # evaluations, one stacked packed fetch. The engine twin is
-            # TenantCacheMux._dispatch_index_group; its live counters
-            # are tenant_index_dispatches / index_fused_hits.
-            from minisched_tpu.ops.pipeline import build_tenant_index_step
-
-            c_model = min(64, p_pad)
-            ti_class_pf = type(eb.pf)(
-                *[np.asarray(getattr(eb.pf, f))[:c_model]
-                  for f in eb.pf._fields])
-            ti_build, _r, _a, _as = build_index_ops(pset, cfg_env.index_k)
-            ti_state = ti_build(ti_class_pf, nf, af)
-            jax.block_until_ready(ti_state.score)
-            slab_stack = np.broadcast_to(
-                np.asarray(ti_state.score),
-                (t,) + ti_state.score.shape).copy()
-            cls_row = (np.arange(p_pad) % c_model).astype(np.int32)
-            cls_stack = np.broadcast_to(cls_row, (t, p_pad)).copy()
-            valid_stack = np.broadcast_to(
-                np.asarray(eb.pf.valid), (t, p_pad)).copy()
-            req_stack = np.broadcast_to(
-                np.asarray(eb.pf.requests),
-                (t,) + eb.pf.requests.shape).copy()
-            free_stack = np.broadcast_to(
-                np.asarray(nf.free), (t,) + nf.free.shape).copy()
-            ti_fn = build_tenant_index_step(cfg_env.index_k)
-
-            def fused_indexed():
-                packs, _fa = ti_fn(slab_stack, cls_stack, valid_stack,
-                                   req_stack, free_stack, keys)
-                return np.array(packs)   # ONE stacked (T,·) d2h
-
-            stack_i = timed(f"tenants_indexed_s[{t}]", fused_indexed)
-            fi_s = stages[f"tenants_indexed_s[{t}]"]
-            rb = min(64, n_pad)
-            print(f"tenants_indexed: T={t} stacked gather+scan "
-                  f"{fi_s:.4f} s (1 dispatch, 1 fetch {stack_i.nbytes} "
-                  f"B) vs fused-full {fused_s:.4f} s "
-                  f"({fused_s / max(fi_s, 1e-9):.2f}x)", flush=True)
-            print(f"tenants_indexed: scored rows/batch/lane model — "
-                  f"full {p_pad}x{n_pad}={p_pad * n_pad}; indexed "
-                  f"steady state {c_model}x{rb}={c_model * rb} repair "
-                  f"rows worst-case "
-                  f"({p_pad * n_pad / max(c_model * rb, 1):.1f}x fewer; "
-                  "the serve itself scores 0 rows)", flush=True)
-        else:
-            print("tenants_indexed skipped: profile not index-eligible",
-                  flush=True)
 
     if d.spread_pre.shape[0]:
         timed("sp_fetch_s", lambda: np.array(_pack_spread(
